@@ -47,8 +47,7 @@ STATS_BACKENDS = {"deltatree", "forest"}
 def exec_meta() -> dict:
     """Execution-mode stamp merged into every emitted row: numbers from a
     CPU-interpret run and a TPU-compiled run must never be comparable
-    silently.  Cached per process — the serve bench's x64 subprocess
-    stamps its own rows with its own (x64=True) view."""
+    silently.  Cached per process."""
     from repro.kernels.ops import default_interpret
 
     return {

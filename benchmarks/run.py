@@ -8,44 +8,18 @@
 # consolidated into BENCH_<timestamp>.json at the repo root — every row
 # stamped with its suite, backend, engine and maintenance policy — so the
 # perf trajectory stays recorded across PRs.
+#
+# Every suite runs in this one process, under JAX_ENABLE_X64 (the serve
+# suites' map-mode pager needs it): a chip belongs to one process, so no
+# suite may run in a child once this process has touched JAX.
 from __future__ import annotations
 
 import argparse
 import json
 import os
-import subprocess
-import sys
 import time
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _in_x64_subprocess(module: str, quick: bool, seed: int,
-                       backend: str | None, engine: str | None,
-                       smoke: bool = False):
-    """serve bench needs JAX_ENABLE_X64; run isolated.  Returns the rows
-    parsed back off the child's stdout (one JSON object per line)."""
-    env = dict(os.environ)
-    env["JAX_ENABLE_X64"] = "1"
-    env.setdefault("PYTHONPATH", "src")
-    code = (f"from {module} import main; "
-            f"main(quick={quick}, seed={seed}, backend={backend!r}, "
-            f"engine={engine!r}, smoke={smoke})")
-    out = subprocess.run([sys.executable, "-c", code], env=env,
-                         capture_output=True, text=True)
-    sys.stdout.write(out.stdout)
-    if out.returncode != 0:
-        sys.stderr.write(out.stderr)
-        raise RuntimeError(f"{module} failed")
-    rows = []
-    for line in out.stdout.splitlines():
-        line = line.strip()
-        if line.startswith("{"):
-            try:
-                rows.append(json.loads(line))
-            except json.JSONDecodeError:
-                pass
-    return rows
 
 
 def _consolidate(rows: list, args: dict) -> str:
@@ -55,8 +29,7 @@ def _consolidate(rows: list, args: dict) -> str:
     meaningless and must not pollute the committed perf trajectory.
 
     The top-level ``meta`` block is this process's execution stamp
-    (`benchmarks.common.exec_meta`); per-row stamps still win — the serve
-    suite's rows come from an x64 subprocess whose mode differs."""
+    (`benchmarks.common.exec_meta`); per-row stamps still win."""
     from benchmarks.common import exec_meta
 
     stamped = []
@@ -85,7 +58,7 @@ def main() -> None:
                     help="paper-scale sizes (slow on CPU)")
     ap.add_argument("--compiled", action="store_true",
                     help="force compiled kernels (REPRO_PALLAS_INTERPRET=0 "
-                         "for this process and every benchmark subprocess): "
+                         "for this process): "
                          "Pallas lowered on TPU, the XLA-compiled fused "
                          "mirrors elsewhere — no interpreter tax. Rows "
                          "stamp meta interpret=false; run_compiled.sh is "
@@ -102,16 +75,21 @@ def main() -> None:
     add_common_args(ap)
     args, _ = ap.parse_known_args()
     if args.compiled:
-        # before any kernel-mode resolution or exec_meta stamp; inherited
-        # by the serve/serve_trace x64 subprocesses via their env copy
+        # before any kernel-mode resolution or exec_meta stamp
         os.environ["REPRO_PALLAS_INTERPRET"] = "0"
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    jax.config.update("jax_enable_x64", True)
+    enable_compile_cache()
     quick = not args.full
     seed, backend, engine = args.seed, args.backend, args.engine
     smoke = args.smoke
 
     from benchmarks import engine_compare, fig11_small_tree, fig12_big_tree
     from benchmarks import forest_scale, maint_sweep, scan_sweep
-    from benchmarks import table1_transfers
+    from benchmarks import serve_paged, serve_trace, table1_transfers
     from benchmarks import ub_sweep
 
     todo = args.only.split(",") if args.only else [
@@ -133,9 +111,8 @@ def main() -> None:
         from repro.obs import trace as OT
 
         # asking for a trace dir IS the span opt-in: turn REPRO_TRACE on
-        # for this process and every benchmark subprocess so the chrome-
-        # trace timeline below has events even off-TPU (where the xprof
-        # capture may have little to sample)
+        # so the chrome-trace timeline below has events even off-TPU
+        # (where the xprof capture may have little to sample)
         os.environ.setdefault(OT.ENV, "1")
         cm = OT.capture(args.trace_dir)
     else:
@@ -155,12 +132,9 @@ def main() -> None:
         if "fig12" in todo:
             add("fig12", fig12_big_tree.main(**common))
         if "serve" in todo:
-            add("serve", _in_x64_subprocess("benchmarks.serve_paged", quick,
-                                            seed, backend, engine, smoke))
+            add("serve", serve_paged.main(**common))
         if "serve_trace" in todo:
-            add("serve_trace", _in_x64_subprocess("benchmarks.serve_trace",
-                                                  quick, seed, backend,
-                                                  engine, smoke))
+            add("serve_trace", serve_trace.main(**common))
         if "forest" in todo:
             add("forest", forest_scale.main(quick=quick, seed=seed,
                                             engine=engine, smoke=smoke))

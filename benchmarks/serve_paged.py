@@ -4,8 +4,8 @@
 ``--backend`` picks the pager's Index backend (``deltatree`` single arena
 or ``forest`` sharded) through the same factory path the engine uses.
 
-Run under JAX_ENABLE_X64=1 (map-mode packed values); benchmarks.run spawns
-it so.
+Run under JAX_ENABLE_X64=1 (map-mode packed values); benchmarks.run turns
+it on for its whole process.
 """
 
 from __future__ import annotations

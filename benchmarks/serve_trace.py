@@ -20,7 +20,7 @@ and asserts the acceptance invariant that the decode path ran ZERO
 inline structural maintenance (the worker owns every drain).
 
 Run under JAX_ENABLE_X64=1 (packed map-mode values); benchmarks.run
-spawns it so.
+turns it on for its whole process.
 """
 
 from __future__ import annotations

@@ -17,8 +17,8 @@
 #     (benchmark rows are parsed off stdout line by line).
 #   * XLA_FLAGS --xla_force_host_platform_device_count=$REPRO_DEVICES —
 #     opt-in fake-device mesh for sharded (forest) runs on one host.
-#   * JAX_ENABLE_X64 passes through untouched: benchmarks/run.py spawns
-#     its own x64 subprocesses for the suites that need it.
+#   * JAX_ENABLE_X64 passes through untouched: benchmarks/run.py turns
+#     x64 on for its own process, which runs every suite.
 set -euo pipefail
 
 cd "$(dirname "$0")"

@@ -91,8 +91,7 @@ class TreeConfig:
                   single-launch walk (`kernels.ops.delta_walk_fused` —
                   all rounds inside one kernel/program); False = the
                   per-round pallas_call-in-while_loop driver (parity
-                  oracle / VMEM-overflow fallback).  Bit-identical
-                  results either way.
+                  oracle).  Bit-identical results either way.
     walk_rounds:  walk round cap; 0 (default) derives it from the arena
                   geometry at trace time (`kernels.ops.walk_round_cap`)
                   instead of the historical fixed 64 — see the

@@ -44,6 +44,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core import (
     DeltaTree,
@@ -106,8 +108,21 @@ class Forest(NamedTuple):
     epoch: jax.Array      # () int32 — arena-mutation counter (view cache key)
 
 
-def _stack(trees: list[DeltaTree]) -> DeltaTree:
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+def _new_forest(fcfg: ForestConfig, trees: list[DeltaTree],
+                splits) -> Forest:
+    """A fresh forest laid out over the "shards" mesh its dispatch runs
+    on: each device holds its own stacked shard arenas, the small leaves
+    are replicated — the layout `update_batch` returns, so neither the
+    first read nor the second update reshards or recompiles."""
+    mesh = R.forest_mesh(fcfg.num_shards)
+    f = Forest(trees=jax.tree.map(lambda *xs: jnp.stack(xs), *trees),
+               splits=_as_splits(fcfg, splits),
+               reads=_zero_counters(fcfg), updates=_zero_counters(fcfg),
+               epoch=jnp.int32(0))
+    shard, rep = NamedSharding(mesh, P("shards")), NamedSharding(mesh, P())
+    return jax.device_put(f, f._replace(
+        trees=jax.tree.map(lambda _: shard, f.trees), splits=rep, reads=rep,
+        updates=rep, epoch=rep))
 
 
 def shard_tree(forest: Forest, s: int) -> DeltaTree:
@@ -134,10 +149,8 @@ def _zero_counters(fcfg: ForestConfig) -> jax.Array:
 
 
 def empty(fcfg: ForestConfig, splits=None) -> Forest:
-    trees = _stack([DT.empty(fcfg.tree) for _ in range(fcfg.num_shards)])
-    return Forest(trees=trees, splits=_as_splits(fcfg, splits),
-                  reads=_zero_counters(fcfg), updates=_zero_counters(fcfg),
-                  epoch=jnp.int32(0))
+    return _new_forest(fcfg, [DT.empty(fcfg.tree)
+                              for _ in range(fcfg.num_shards)], splits)
 
 
 def bulk_build(fcfg: ForestConfig, values: np.ndarray,
@@ -163,9 +176,7 @@ def bulk_build(fcfg: ForestConfig, values: np.ndarray,
         trees.append(DT.bulk_build(
             fcfg.tree, values[mask],
             payloads[mask] if payloads is not None else None))
-    return Forest(trees=_stack(trees), splits=_as_splits(fcfg, splits),
-                  reads=_zero_counters(fcfg), updates=_zero_counters(fcfg),
-                  epoch=jnp.int32(0))
+    return _new_forest(fcfg, trees, splits)
 
 
 # --------------------------------------------------------------------------

@@ -35,7 +35,6 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import layout
@@ -143,11 +142,11 @@ def dispatch(num_shards: int, fn, trees, *dense_args, sequential=False):
 
     nargs = 1 + len(dense_args)
     with TR.annotate("router.dispatch"):
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P("shards"),) * nargs,
             out_specs=P("shards"),
-            check_rep=False,
+            check_vma=False,
         )(trees, *dense_args)
 
 
@@ -173,11 +172,11 @@ def build_fused_view(num_shards: int, make_view, trees):
         return jax.tree.map(lambda x: x[None], make_view(trees_loc))
 
     with TR.annotate("router.fuse_view"):
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P("shards"),),
             out_specs=P("shards"),
-            check_rep=False,
+            check_vma=False,
         )(trees)
 
 
@@ -236,11 +235,11 @@ def fused_dispatch(num_shards: int, fn, trees, sid, keys, view=None):
 
     extra = () if view is None else (view,)
     with TR.annotate("router.fused"):
-        lane, per_shard = shard_map(
+        lane, per_shard = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P("shards"),) * (3 + len(extra)),
             out_specs=P("shards"),
-            check_rep=False,
+            check_vma=False,
         )(trees, dlid, dkeys, *extra)
     return r, lane, per_shard
 

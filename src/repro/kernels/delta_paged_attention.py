@@ -18,10 +18,16 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+# Typed constants: a Python float (or an index map's Python 0) traces as a
+# 64-bit value under JAX_ENABLE_X64 — which the serving pager runs under —
+# and Mosaic refuses it.
+NEG_INF = np.float32(-1e30)
+_TINY = np.float32(1e-30)
+_Z = np.int32(0)
 
 
 def _kernel(maxp: int, page_size: int, scale: float,
@@ -46,8 +52,8 @@ def _kernel(maxp: int, page_size: int, scale: float,
     @pl.when(p * page_size < seq_len)
     def _step():
         q = q_ref[0, 0].astype(jnp.float32)         # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)   # (PS, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)   # (PS, D)
+        k = k_ref[0, 0].astype(jnp.float32)         # (PS, D)
+        v = v_ref[0, 0].astype(jnp.float32)         # (PS, D)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                    # (G, PS)
@@ -66,7 +72,7 @@ def _kernel(maxp: int, page_size: int, scale: float,
 
     @pl.when(p == maxp - 1)
     def _fin():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[:, 0], 1e-30)[:, None]).astype(
+        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[:, 0], _TINY)[:, None]).astype(
             o_ref.dtype
         )
 
@@ -77,7 +83,8 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """ΔTree-paged GQA decode attention.
 
     q:            (B, QH, D)
-    k/v_pages:    (NP, PS, KVH, D)
+    k/v_pages:    (NP, KVH, PS, D) — head-major pages, so one (PS, D)
+                  page of one kv head is a tiled block
     block_tables: (B, MAXP) int32 (-1 = unused; clamped for DMA, masked in
                   compute via seq_lens)
     seq_lens:     (B,) int32
@@ -100,11 +107,11 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 def _paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
                             *, interpret: bool):
     b, qh, d = q.shape
-    np_, ps, kvh, _ = k_pages.shape
+    np_, kvh, ps, _ = k_pages.shape
     maxp = block_tables.shape[1]
     g = qh // kvh
     assert g * kvh == qh
-    scale = 1.0 / (d**0.5)
+    scale = np.float32(1.0 / (d**0.5))
 
     bt_flat = jnp.maximum(block_tables, 0).reshape(-1)
     q4 = q.reshape(b, kvh, g, d)
@@ -113,18 +120,18 @@ def _paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
         num_scalar_prefetch=2,
         grid=(b, kvh, maxp),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bi, hi, pi, bt, sl: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, 1, g, d), lambda bi, hi, pi, bt, sl: (bi, hi, _Z, _Z)),
             pl.BlockSpec(
-                (1, ps, 1, d),
-                lambda bi, hi, pi, bt, sl: (bt[bi * maxp + pi], 0, hi, 0),
+                (1, 1, ps, d),
+                lambda bi, hi, pi, bt, sl: (bt[bi * maxp + pi], hi, _Z, _Z),
             ),
             pl.BlockSpec(
-                (1, ps, 1, d),
-                lambda bi, hi, pi, bt, sl: (bt[bi * maxp + pi], 0, hi, 0),
+                (1, 1, ps, d),
+                lambda bi, hi, pi, bt, sl: (bt[bi * maxp + pi], hi, _Z, _Z),
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, g, d), lambda bi, hi, pi, bt, sl: (bi, hi, 0, 0)
+            (1, 1, g, d), lambda bi, hi, pi, bt, sl: (bi, hi, _Z, _Z)
         ),
         scratch_shapes=[
             pltpu.VMEM((g, 128), jnp.float32),

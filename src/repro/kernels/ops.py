@@ -11,12 +11,14 @@
   contract bit for bit:
     * fused (default): ALL rounds inside one launch —
       `veb_search.veb_walk_fused` (persistent Pallas kernel, arena
-      resident per q_tile grid cell) where Pallas can lower it, else the
-      XLA-compiled `kernels.ref.ref_delta_walk_fused`;
+      resident in VMEM) where the rule in `_fused_pallas_ok` admits it,
+      else the XLA-compiled `kernels.ref.ref_delta_walk_fused` — which is
+      what every arena past `FUSED_VMEM_BUDGET_BYTES` runs on TPU;
+      `walk_impl` / `scan_impl` name the one a given arena gets;
     * per-round (``fused=False``): the original
       pallas_call-inside-``lax.while_loop`` — one `veb_walk_rows` launch
-      per frontier round; retained as the parity oracle and the TPU
-      fallback when the arena outgrows the fused kernel's VMEM budget.
+      per frontier round over XLA-gathered rows; retained as the parity
+      oracle (no VMEM budget: it never holds the arena).
 - `delta_search`       — legacy 3-tuple contract on top of `delta_walk`.
 - `delta_contains`     — paper SEARCHNODE set semantics on top (mark bit +
   overflow buffer check).
@@ -45,7 +47,8 @@ import jax.numpy as jnp
 from repro.core import layout
 from repro.kernels.delta_paged_attention import paged_decode_attention  # noqa: F401
 from repro.kernels.veb_search import (
-    pad_arena, veb_scan_fused, veb_walk_fused, veb_walk_rows, walk_big,
+    _round_up, pad_arena, veb_scan_fused, veb_walk_fused, veb_walk_rows,
+    walk_big,
 )
 from repro.obs import trace as TR
 
@@ -166,21 +169,67 @@ def _pallas_lowers(dtype, interpret: bool) -> bool:
     return jax.default_backend() == "tpu" and jnp.dtype(dtype) != jnp.int64
 
 
-# Compiled fused kernel budget: the padded arena is resident per grid
-# cell, so it must fit VMEM (~16 MB/core) next to the query tile and the
-# round state.  Conservative by design — past it the per-round driver
-# (streaming row gathers) takes over on TPU.
-FUSED_VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+# Compiled fused-kernel VMEM budget, found by compiling `veb_walk_fused`
+# and `veb_scan_fused` for a TPU v5e (128 MiB of VMEM per core) under the
+# kernels' explicit scoped limit (`veb_search.FUSED_VMEM_LIMIT_BYTES`,
+# 120 MiB).  The arena planes a kernel keeps resident, plus a working set
+# of `_TILE_ROWS` row tiles (q_tile x the padded row bytes, the scan's
+# output row included), must fit in it.  Heights 5-9 at q_tile 256-1024
+# compile at this edge, and the working-set term is what keeps the wide
+# tiles inside the limit (tests/test_tpu_compile.py compiles the edge).
+# Past it the fused driver runs the XLA mirror (`ref.ref_delta_walk_fused`
+# / `ref.ref_delta_scan_fused`): at height 7 that is every arena over
+# ~92k ΔNodes (walk) or ~60k (scan) — every deployment-sized index.
+FUSED_VMEM_BUDGET_BYTES = 96 * 1024 * 1024
+_TILE_ROWS = 16
 
 
-def _fused_pallas_ok(value_p, child_p, interpret: bool) -> bool:
-    if not _pallas_lowers(value_p.dtype, interpret):
+def fused_arena_cap(widths, q_tile: int, out_width: int = 0) -> int:
+    """Most arena rows (ΔNodes) a compiled fused kernel may keep resident:
+    planes of the given ``widths`` (lane-padded int32 — int64 never
+    lowers) must fit the budget next to `_TILE_ROWS` (q_tile, row) tiles,
+    a row of the tile also holding ``out_width`` output lanes."""
+    row = 4 * sum(_round_up(w, 128) for w in widths)
+    tile_row = row + 4 * _round_up(out_width, 128)
+    return (FUSED_VMEM_BUDGET_BYTES - _TILE_ROWS * q_tile * tile_row) // row
+
+
+def _fused_pallas_ok(value, child, q_tile: int, interpret: bool,
+                     max_out: int | None = None) -> bool:
+    """The one rule choosing a fused Pallas kernel over its XLA mirror — a
+    rule on dtype and arena size, decided at trace time.  The walk keeps
+    the value and child planes resident; a scan (``max_out`` given) adds
+    the mark plane, as wide as the value plane, and an output tile."""
+    if not _pallas_lowers(value.dtype, interpret):
         return False
-    if interpret:
-        return True
-    arena_bytes = (value_p.size * value_p.dtype.itemsize
-                   + child_p.size * child_p.dtype.itemsize)
-    return arena_bytes <= FUSED_VMEM_BUDGET_BYTES
+    widths = (value.shape[1], child.shape[1])
+    if max_out is not None:
+        widths += (value.shape[1],)
+    return interpret or value.shape[0] <= fused_arena_cap(
+        widths, q_tile, max_out or 0)
+
+
+def walk_impl(value, child, *, height: int, q_tile: int | None = None,
+              interpret: bool | None = None) -> str:
+    """Which fused walk `delta_walk` runs over this arena under the same
+    resolution rules: ``"veb_walk_fused"`` (Pallas) or
+    ``"ref_delta_walk_fused"`` (the XLA mirror)."""
+    q_tile = _resolve_q_tile(q_tile, height,
+                             0 if value.dtype == jnp.int32 else 1)
+    ok = _fused_pallas_ok(value, child, q_tile, _resolve_interpret(interpret))
+    return "veb_walk_fused" if ok else "ref_delta_walk_fused"
+
+
+def scan_impl(value, child, *, height: int, max_out: int,
+              q_tile: int | None = None,
+              interpret: bool | None = None) -> str:
+    """Which fused scan `delta_scan` runs over this arena:
+    ``"veb_scan_fused"`` (Pallas) or ``"ref_delta_scan_fused"`` (XLA)."""
+    q_tile = _resolve_q_tile(q_tile, height,
+                             0 if value.dtype == jnp.int32 else 1)
+    ok = _fused_pallas_ok(value, child, q_tile, _resolve_interpret(interpret),
+                          max_out)
+    return "veb_scan_fused" if ok else "ref_delta_scan_fused"
 
 
 def _row_walk(rows, childrows, queries, *, height, q_tile, interpret):
@@ -281,14 +330,14 @@ def _delta_walk_fused(value, child, root, queries, *, height, q_tile,
     queries = queries.astype(value.dtype)
     k = queries.shape[0]
     dn0 = jnp.broadcast_to(jnp.asarray(root, jnp.int32), (k,))
-    value_p, child_p = pad_arena(value, child)
-    if not _fused_pallas_ok(value_p, child_p, interpret):
+    if not _fused_pallas_ok(value, child, q_tile, interpret):
         from repro.kernels.ref import ref_delta_walk_fused
 
         # big-sentinel lanes are born resolved inside the mirror; no
         # q_tile padding — XLA has no tile-shape constraint to satisfy
         return ref_delta_walk_fused(value, child, dn0, queries,
                                     height=height, max_rounds=max_rounds)
+    value_p, child_p = pad_arena(value, child)
     kp = (k + q_tile - 1) // q_tile * q_tile
     qpad = jnp.pad(queries, (0, kp - k),
                    constant_values=walk_big(value.dtype))
@@ -426,14 +475,15 @@ def _delta_scan(value, mark, child, root, starts, his, *, height, max_out,
     his = his.astype(value.dtype)
     k = starts.shape[0]
     dn0 = jnp.broadcast_to(jnp.asarray(root, jnp.int32), (k,))
-    value_p, child_p = pad_arena(value, child)
-    if not _fused_pallas_ok(value_p, child_p, interpret):
+    if not _fused_pallas_ok(value, child, q_tile, interpret, max_out):
         from repro.kernels.ref import ref_delta_scan_fused
 
         return ref_delta_scan_fused(value, mark, child, dn0, starts, his,
                                     height=height, max_rounds=max_rounds,
                                     max_out=max_out, pmask=pmask)
-    mark_p = jnp.pad(mark, ((0, 0), (0, value_p.shape[1] - mark.shape[1])))
+    value_p, child_p = pad_arena(value, child)
+    mark_p = jnp.pad(mark.astype(jnp.int32),
+                     ((0, 0), (0, value_p.shape[1] - mark.shape[1])))
     kp = (k + q_tile - 1) // q_tile * q_tile
     big = walk_big(value.dtype)
     spad = jnp.pad(starts, (0, kp - k), constant_values=big)

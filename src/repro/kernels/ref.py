@@ -334,7 +334,7 @@ def ref_paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     """Oracle for ΔTree-paged decode attention.
 
     q:            (B, QH, D)
-    k/v_pages:    (NP, PS, KVH, D)
+    k/v_pages:    (NP, KVH, PS, D)
     block_tables: (B, MAXP) int32 physical page ids (-1 = unused)
     seq_lens:     (B,) int32
 
@@ -342,14 +342,14 @@ def ref_paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     runs masked GQA decode attention in f32. Returns (B, QH, D) in q.dtype.
     """
     b, qh, d = q.shape
-    np_, ps, kvh, _ = k_pages.shape
+    np_, kvh, ps, _ = k_pages.shape
     maxp = block_tables.shape[1]
     g = qh // kvh
     scale = 1.0 / jnp.sqrt(jnp.float32(d))
 
     bt = jnp.maximum(block_tables, 0)
-    k = k_pages[bt]  # (B, MAXP, PS, KVH, D)
-    v = v_pages[bt]
+    k = k_pages[bt].swapaxes(2, 3)  # (B, MAXP, PS, KVH, D)
+    v = v_pages[bt].swapaxes(2, 3)
     k = k.reshape(b, maxp * ps, kvh, d).astype(jnp.float32)
     v = v.reshape(b, maxp * ps, kvh, d).astype(jnp.float32)
 

@@ -2,10 +2,11 @@
 
 TPU mapping of the paper's locality argument (DESIGN.md §2): each query's
 current ΔNode row (UB keys in vEB order, padded to a 128-lane multiple) is
-gathered HBM→VMEM — one contiguous DMA per ΔNode, the dynamic-vEB pointer
-hop realized as a data-dependent row gather.  Inside the kernel the whole
-walk is VREG arithmetic: implicit complete-BST position math plus the
-compile-time vEB permutation table, vectorized across the query tile.
+read as one contiguous row — the dynamic-vEB pointer hop realized as a
+data-dependent row read.  Inside the kernel the whole walk is VREG
+arithmetic: implicit complete-BST position math, with each level's slot
+picked out of the row by its BFS label (a one-hot lane select: Mosaic
+lowers no per-lane gather), vectorized across the query tile.
 
 The multi-ΔNode walk runs in lockstep rounds at the JAX level
 (`ops.delta_walk`, the driver behind the ``"lockstep"`` SearchEngine):
@@ -32,10 +33,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import layout
 from repro.core.layout import EMPTY
+
+
+# Index maps return int32 zeros: a Python 0 traces as int64 under
+# JAX_ENABLE_X64, which Mosaic refuses — as it refuses any Python scalar
+# the kernels would turn into an array, hence their typed constants.
+_Z = np.int32(0)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -51,18 +60,41 @@ def walk_big(dtype) -> int:
     return int(layout.ROUTE_LEFT)
 
 
+def _pick(rows, labels, idx):
+    """Per-lane select: ``rows[i, j]`` where ``labels[0, j] == idx[i, 0]``.
+
+    Mosaic lowers no per-lane gather, so the lane is picked by a one-hot
+    ``where`` over the row plus a lane max.  Every index the walks ask
+    for labels exactly one lane, so the max is that lane's value, bit for
+    bit."""
+    low = jnp.asarray(jnp.iinfo(rows.dtype).min, rows.dtype)
+    return jnp.max(jnp.where(labels == idx, rows, low), axis=1,
+                   keepdims=True)
+
+
+def _lanes(n: int):
+    return jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+
+
+def _bfs_labels(height: int, ubp: int):
+    """(1, ubp) BFS index stored at each vEB lane of a row (0 on the pad
+    lanes; BFS indices start at 1) — the labels `_pick` matches a walk
+    position ``b`` against, in place of a ``pos[b]`` table gather."""
+    bfs = jnp.asarray(layout.veb_inverse_table(height))
+    return jnp.pad(bfs, (0, ubp - bfs.shape[0]))[None, :]
+
+
 def _kernel(height: int, big: int,
-            pos_ref, q_ref, rows_ref, childrows_ref,
+            bfs_ref, q_ref, rows_ref, childrows_ref,
             leaf_val_ref, leaf_b_ref, next_dn_ref, cand_ref):
     h = height
     bottom0 = 2 ** (h - 1)
-    pos = pos_ref[...]                                   # vEB permutation
-    v = q_ref[...]                                       # (QT,)
+    bfs = bfs_ref[...]                                   # (1, UBp) labels
+    v = q_ref[...]                                       # (QT, 1)
     rows = rows_ref[...]                                 # (QT, UBp) VMEM
 
     def take(b):
-        # per-lane gather rows[i, pos[b[i]]]
-        return jnp.take_along_axis(rows, pos[b][:, None], axis=1)[:, 0]
+        return _pick(rows, bfs, b)
 
     b = jnp.ones(v.shape, jnp.int32)
     cand = jnp.full(v.shape, big, rows.dtype)
@@ -79,8 +111,9 @@ def _kernel(height: int, big: int,
 
     leaf_val = take(b)
     at_bottom = b >= bottom0
-    slot = jnp.where(at_bottom, b - bottom0, 0)
-    child = jnp.take_along_axis(childrows_ref[...], slot[:, None], axis=1)[:, 0]
+    slot = jnp.where(at_bottom, b - bottom0, _Z)
+    childrows = childrows_ref[...]
+    child = _pick(childrows, _lanes(childrows.shape[1]), slot)
     nxt = jnp.where(at_bottom, child, jnp.int32(-1))
 
     leaf_val_ref[...] = leaf_val
@@ -89,9 +122,25 @@ def _kernel(height: int, big: int,
     cand_ref[...] = cand
 
 
+def _tiles(q_tile: int, width: int = 1):
+    """(q_tile, width) blocks walking the query tiles.  Per-lane state
+    rides (K, 1) columns — one query per sublane — so it broadcasts
+    against the lane's (QT, lanes) row tile."""
+    return pl.BlockSpec((q_tile, width), lambda i: (i, _Z))
+
+
+def _whole(width: int):
+    """A (1, width) table mapped whole into every grid cell."""
+    return pl.BlockSpec((1, width), lambda i: (_Z, _Z))
+
+
+def _cols(*xs):
+    return [x.reshape(x.shape[0], 1) for x in xs]
+
+
 @functools.partial(jax.jit, static_argnames=("height", "q_tile", "interpret"))
 def veb_walk_rows(rows: jax.Array, childrows: jax.Array, queries: jax.Array,
-                  *, height: int, q_tile: int = 256, interpret: bool = True):
+                  *, height: int, q_tile: int = 256, interpret: bool):
     """One full in-ΔNode descent per query.
 
     rows:      (K, UBp) int32/int64 — each query's current ΔNode row
@@ -112,69 +161,86 @@ def veb_walk_rows(rows: jax.Array, childrows: jax.Array, queries: jax.Array,
     cp = childrows.shape[1]
     big = walk_big(rows.dtype)
 
-    pos = jnp.asarray(layout.veb_pos_table(height))
-    posp = _round_up(pos.shape[0], 128)
-    pos = jnp.pad(pos, (0, posp - pos.shape[0]))
-
     out_shape = [
-        jax.ShapeDtypeStruct((k,), rows.dtype),   # leaf_val
-        jax.ShapeDtypeStruct((k,), jnp.int32),    # leaf_b
-        jax.ShapeDtypeStruct((k,), jnp.int32),    # next_dn
-        jax.ShapeDtypeStruct((k,), rows.dtype),   # cand
+        jax.ShapeDtypeStruct((k, 1), rows.dtype),   # leaf_val
+        jax.ShapeDtypeStruct((k, 1), jnp.int32),    # leaf_b
+        jax.ShapeDtypeStruct((k, 1), jnp.int32),    # next_dn
+        jax.ShapeDtypeStruct((k, 1), rows.dtype),   # cand
     ]
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, height, big),
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((posp,), lambda i: (0,)),
-            pl.BlockSpec((q_tile,), lambda i: (i,)),
-            pl.BlockSpec((q_tile, ubp), lambda i: (i, 0)),
-            pl.BlockSpec((q_tile, cp), lambda i: (i, 0)),
+            _whole(ubp),
+            _tiles(q_tile),
+            _tiles(q_tile, ubp),
+            _tiles(q_tile, cp),
         ],
-        out_specs=[pl.BlockSpec((q_tile,), lambda i: (i,))] * 4,
+        out_specs=[_tiles(q_tile)] * 4,
         out_shape=out_shape,
         interpret=interpret,
-    )(pos, queries, rows, childrows)
+    )(_bfs_labels(height, ubp), *_cols(queries), rows, childrows)
+    return tuple(o.reshape(k) for o in out)
+
+
+def _gather_rows(dn_ref, pairs):
+    """Row reads for the lane frontier: copy arena row ``dn[i]`` of every
+    ``(arena_ref, rows_ref)`` pair into row ``i`` of its rows tile.
+
+    Mosaic lowers no 1-D gather over a VMEM arena, but a scalar row id
+    does index a ref: each lane's id is read out of its (1, 1) slice and
+    drives one dynamic sublane slice per arena."""
+    def body(i, carry):
+        d = jnp.max(dn_ref[pl.ds(i, 1), :])
+        for arena_ref, rows_ref in pairs:
+            rows_ref[pl.ds(i, 1), :] = arena_ref[pl.ds(d, 1), :]
+        return carry
+
+    jax.lax.fori_loop(0, dn_ref.shape[0], body, 0)
 
 
 def _fused_kernel(height: int, big: int, max_rounds: int, m: int,
-                  pos_ref, q_ref, root_ref, value_ref, child_ref,
-                  leaf_val_ref, leaf_b_ref, final_dn_ref, hops_ref, cand_ref):
+                  bfs_ref, q_ref, root_ref, value_ref, child_ref,
+                  leaf_val_ref, leaf_b_ref, final_dn_ref, hops_ref, cand_ref,
+                  dn_ref, vrows_ref, crows_ref):
     """Persistent multi-round walk: the whole frontier loop of
     ``ops.delta_walk`` inside one kernel launch (per q_tile grid cell).
 
-    The padded arena is resident (VMEM on TPU — the caller budgets it);
-    each round is a *blind* in-ΔNode descent — one router gather per
-    level, always routing right through EMPTY territory (sound by the
-    connected-top-tree occupancy invariants; see
+    The padded arena is resident in VMEM (the caller budgets it); each
+    round reads every lane's ΔNode row into a (QT, UBp) tile
+    (`_gather_rows`), then runs a *blind* in-ΔNode descent — one router
+    pick per level, always routing right through EMPTY territory (sound
+    by the connected-top-tree occupancy invariants; see
     ``ref.ref_delta_walk_fused``, the bit-exact oracle) — followed by the
     bottom-slot child hop.  Rounds stop when every lane is resolved, so
     shallow trees never pay dead iterations.
     """
     h = height
     bottom0 = 2 ** (h - 1)
-    pos = pos_ref[...]
-    v = q_ref[...]                                        # (QT,)
-    vflat = value_ref[...].reshape(-1)                    # (M * UBp,)
-    cflat = child_ref[...].reshape(-1)                    # (M * CP,)
-    ub = value_ref.shape[1]
-    cp = child_ref.shape[1]
+    bfs = bfs_ref[...]
+    lanes = _lanes(crows_ref.shape[1])
+    v = q_ref[...]                                        # (QT, 1)
+    dt = value_ref.dtype
     dn0 = root_ref[...]
 
+    # lane flags ride the loop carry as int32 0/1: Mosaic carries no
+    # boolean vectors across loop iterations
     def cond(s):
-        return jnp.any(~s[1]) & (s[7] < max_rounds)
+        return (jnp.min(s[1]) == 0) & (s[7] < max_rounds)
 
     def body(s):
         dn, resolved, leaf_val, leaf_b, final_dn, hops, cand, rounds = s
-        dnc = jnp.clip(dn, 0, m - 1)
-        base = dnc * ub
+        resolved = resolved != 0
+        dn_ref[...] = jnp.clip(dn, _Z, jnp.int32(m - 1))
+        _gather_rows(dn_ref, ((value_ref, vrows_ref), (child_ref, crows_ref)))
+        rows = vrows_ref[...]
         b = jnp.ones(v.shape, jnp.int32)
         lb = jnp.ones(v.shape, jnp.int32)          # last occupied position
-        lv = jnp.zeros(v.shape, vflat.dtype)
-        rcand = jnp.full(v.shape, big, vflat.dtype)
+        lv = jnp.zeros(v.shape, dt)
+        rcand = jnp.full(v.shape, big, dt)
         routers, bs = [], []
         for _ in range(h):                          # blind descent
-            router = jnp.take(vflat, base + pos[b])
+            router = _pick(rows, bfs, b)
             routers.append(router)
             bs.append(b)
             occ = router != EMPTY
@@ -187,14 +253,14 @@ def _fused_kernel(height: int, big: int, max_rounds: int, m: int,
                     & (router < rcand))
             rcand = jnp.where(fold, router, rcand)
         at_bottom = lb >= bottom0
-        slot = jnp.where(at_bottom, lb - bottom0, 0)
-        ch = jnp.take(cflat, dnc * cp + slot)
+        slot = jnp.where(at_bottom, lb - bottom0, _Z)
+        ch = _pick(crows_ref[...], lanes, slot)
         nxt = jnp.where(at_bottom, ch, jnp.int32(-1))
         act = ~resolved
         done_now = act & (nxt < 0)
         return (
             jnp.where(act & (nxt >= 0), nxt, dn),
-            resolved | done_now,
+            (resolved | done_now).astype(jnp.int32),
             jnp.where(done_now, lv, leaf_val),
             jnp.where(done_now, lb, leaf_b),
             jnp.where(done_now, dn, final_dn),
@@ -203,16 +269,15 @@ def _fused_kernel(height: int, big: int, max_rounds: int, m: int,
             rounds + 1,
         )
 
-    bigv = jnp.asarray(big, vflat.dtype)
     init = (
         dn0,
-        v == bigv,                                  # sentinel lanes resolved
-        jnp.zeros(v.shape, vflat.dtype),
+        (v == jnp.asarray(big, dt)).astype(jnp.int32),  # sentinels resolved
+        jnp.zeros(v.shape, dt),
         jnp.ones(v.shape, jnp.int32),
         dn0,
         jnp.zeros(v.shape, jnp.int32),
-        jnp.full(v.shape, big, vflat.dtype),
-        jnp.int32(0),
+        jnp.full(v.shape, big, dt),
+        _Z,
     )
     s = jax.lax.while_loop(cond, body, init)
     leaf_val_ref[...] = s[2]
@@ -222,12 +287,26 @@ def _fused_kernel(height: int, big: int, max_rounds: int, m: int,
     cand_ref[...] = s[6]
 
 
+# Scoped-VMEM ceiling of the arena-resident kernels (v5e holds 128 MiB of
+# VMEM per core; Mosaic's default scoped limit is 16 MiB).  The arena is
+# mapped whole into VMEM once per launch (`memory_space=VMEM`, no block
+# pipeline, so no second buffer); `ops.FUSED_VMEM_BUDGET_BYTES` keeps the
+# arena far enough under this ceiling for the row tiles, the lane state
+# and the scan's output tile.
+FUSED_VMEM_LIMIT_BYTES = 100 * 1024 * 1024
+
+
+def _resident():
+    return pl.BlockSpec(memory_space=pltpu.VMEM,
+                        index_map=lambda i: (_Z, _Z))
+
+
 @functools.partial(jax.jit,
                    static_argnames=("height", "q_tile", "max_rounds",
                                     "interpret"))
 def veb_walk_fused(value_p: jax.Array, child_p: jax.Array, roots: jax.Array,
                    queries: jax.Array, *, height: int, q_tile: int = 256,
-                   max_rounds: int = 16, interpret: bool = True):
+                   max_rounds: int = 16, interpret: bool):
     """All walk rounds in one launch (grid over query tiles).
 
     value_p:  (M, UBp) padded arena rows (`pad_arena`), int32/int64
@@ -237,9 +316,9 @@ def veb_walk_fused(value_p: jax.Array, child_p: jax.Array, roots: jax.Array,
 
     Returns the full `ops.delta_walk` 5-tuple (leaf_val, leaf_b, final_dn,
     hops, cand), each (K,).  Sentinel queries (``walk_big``) are born
-    resolved.  The whole arena is mapped into every grid cell — callers
-    gate this path on the VMEM budget (`ops` falls back to the per-round
-    driver / the compiled jnp mirror when it doesn't fit).
+    resolved.  The whole arena is resident in VMEM — callers gate this
+    path on `ops.FUSED_VMEM_BUDGET_BYTES` (`ops` runs the XLA mirror
+    ``ref.ref_delta_walk_fused`` past it).
     """
     k = queries.shape[0]
     assert k % q_tile == 0, (k, q_tile)
@@ -249,75 +328,82 @@ def veb_walk_fused(value_p: jax.Array, child_p: jax.Array, roots: jax.Array,
     cp = child_p.shape[1]
     big = walk_big(value_p.dtype)
 
-    pos = jnp.asarray(layout.veb_pos_table(height))
-    posp = _round_up(pos.shape[0], 128)
-    pos = jnp.pad(pos, (0, posp - pos.shape[0]))
-
     out_shape = [
-        jax.ShapeDtypeStruct((k,), value_p.dtype),   # leaf_val
-        jax.ShapeDtypeStruct((k,), jnp.int32),       # leaf_b
-        jax.ShapeDtypeStruct((k,), jnp.int32),       # final_dn
-        jax.ShapeDtypeStruct((k,), jnp.int32),       # hops
-        jax.ShapeDtypeStruct((k,), value_p.dtype),   # cand
+        jax.ShapeDtypeStruct((k, 1), value_p.dtype),   # leaf_val
+        jax.ShapeDtypeStruct((k, 1), jnp.int32),       # leaf_b
+        jax.ShapeDtypeStruct((k, 1), jnp.int32),       # final_dn
+        jax.ShapeDtypeStruct((k, 1), jnp.int32),       # hops
+        jax.ShapeDtypeStruct((k, 1), value_p.dtype),   # cand
     ]
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_fused_kernel, height, big, max_rounds, m),
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((posp,), lambda i: (0,)),
-            pl.BlockSpec((q_tile,), lambda i: (i,)),
-            pl.BlockSpec((q_tile,), lambda i: (i,)),
-            pl.BlockSpec((m, ubp), lambda i: (0, 0)),
-            pl.BlockSpec((m, cp), lambda i: (0, 0)),
+            _whole(ubp),
+            _tiles(q_tile),
+            _tiles(q_tile),
+            _resident(),
+            _resident(),
         ],
-        out_specs=[pl.BlockSpec((q_tile,), lambda i: (i,))] * 5,
+        out_specs=[_tiles(q_tile)] * 5,
         out_shape=out_shape,
+        scratch_shapes=[
+            pltpu.VMEM((q_tile, 1), jnp.int32),          # frontier row ids
+            pltpu.VMEM((q_tile, ubp), value_p.dtype),    # value rows
+            pltpu.VMEM((q_tile, cp), jnp.int32),         # child rows
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(pos, queries, roots, value_p, child_p)
+    )(_bfs_labels(height, ubp), *_cols(queries, roots), value_p, child_p)
+    return tuple(o.reshape(k) for o in out)
 
 
 def _scan_kernel(height: int, big: int, pmask: int, max_rounds: int,
                  max_out: int, mo_p: int, m: int,
-                 pos_ref, start_ref, hi_ref, root_ref, value_ref, mark_ref,
-                 child_ref, out_ref, n_ref, hops_ref, more_ref):
+                 bfs_ref, start_ref, hi_ref, root_ref, value_ref, mark_ref,
+                 child_ref, out_ref, n_ref, hops_ref, more_ref,
+                 dn_ref, vrows_ref, mrows_ref, crows_ref):
     """Persistent emit-cursor scan: the whole find/verify/emit loop of
     ``ops.delta_scan`` inside one kernel launch (per q_tile grid cell).
 
-    Same blind-descent round structure as ``_fused_kernel``; each lane
-    additionally carries a scan cursor, a FIND/VERIFY mode bit and an
-    emit index into a VMEM-resident (QT, mo_p) output tile.  The exact
-    pass logic is documented on the bit-exact oracle,
+    Same round structure as ``_fused_kernel`` (row reads, then a blind
+    descent); each lane additionally carries a scan cursor, a FIND/VERIFY
+    mode bit and an emit index into a VMEM-resident (QT, mo_p) output
+    tile.  The exact pass logic is documented on the bit-exact oracle,
     ``ref.ref_delta_scan_fused``; ``mo_p`` is the lane-padded buffer
-    width (emission is still capped at ``max_out``).
+    width (emission is still capped at ``max_out``).  The mark plane is
+    int32 (nonzero = marked): a bool ref would load as an int on TPU.
     """
     h = height
     bottom0 = 2 ** (h - 1)
-    pos = pos_ref[...]
-    starts = start_ref[...]                              # (QT,) packed
+    bfs = bfs_ref[...]
+    lanes = _lanes(crows_ref.shape[1])
+    starts = start_ref[...]                              # (QT, 1) packed
     his = hi_ref[...]
     dn0 = root_ref[...]
-    vflat = value_ref[...].reshape(-1)                   # (M * UBp,)
-    mflat = mark_ref[...].reshape(-1)
-    cflat = child_ref[...].reshape(-1)
-    ub = value_ref.shape[1]
-    cp = child_ref.shape[1]
-    bigv = jnp.asarray(big, vflat.dtype)
-    pm = jnp.asarray(pmask, vflat.dtype)
-    col = jnp.arange(mo_p, dtype=jnp.int32)[None, :]
+    dt = value_ref.dtype
+    bigv = jnp.asarray(big, dt)
+    pm = jnp.asarray(pmask, dt)
+    col = _lanes(mo_p)
 
+    # lane flags ride the loop carry as int32 0/1 (as in `_fused_kernel`)
     def cond(s):
-        return jnp.any(~s[9]) & (s[10] < max_rounds)
+        return (jnp.min(s[9]) == 0) & (s[10] < max_rounds)
 
     def body(s):
         (dn, verify, q, cursor, cand, out, n, hops, more, done, rounds) = s
-        dnc = jnp.clip(dn, 0, m - 1)
-        base = dnc * ub
+        verify, more, done = verify != 0, more != 0, done != 0
+        dn_ref[...] = jnp.clip(dn, _Z, jnp.int32(m - 1))
+        _gather_rows(dn_ref, ((value_ref, vrows_ref), (mark_ref, mrows_ref),
+                              (child_ref, crows_ref)))
+        rows = vrows_ref[...]
         b = jnp.ones(q.shape, jnp.int32)
         lb = jnp.ones(q.shape, jnp.int32)          # last occupied position
-        lv = jnp.zeros(q.shape, vflat.dtype)
+        lv = jnp.zeros(q.shape, dt)
         routers, bs = [], []
         for _ in range(h):                          # blind descent
-            router = jnp.take(vflat, base + pos[b])
+            router = _pick(rows, bfs, b)
             routers.append(router)
             bs.append(b)
             occ = router != EMPTY
@@ -325,21 +411,21 @@ def _scan_kernel(height: int, big: int, pmask: int, max_rounds: int,
             lv = jnp.where(occ, router, lv)
             go_right = q >= router
             b = jnp.where(b < bottom0, 2 * b + go_right.astype(b.dtype), b)
-        rcand = jnp.full(q.shape, big, vflat.dtype)
+        rcand = jnp.full(q.shape, big, dt)
         for router, bi in zip(routers, bs):         # post-hoc cand fold
             fold = ((router != EMPTY) & (bi != lb) & (q < router)
                     & (router < rcand))
             rcand = jnp.where(fold, router, rcand)
         at_bottom = lb >= bottom0
-        slot = jnp.where(at_bottom, lb - bottom0, 0)
-        ch = jnp.take(cflat, dnc * cp + slot)
+        slot = jnp.where(at_bottom, lb - bottom0, _Z)
+        ch = _pick(crows_ref[...], lanes, slot)
         nxt = jnp.where(at_bottom, ch, jnp.int32(-1))
         act = ~done
         hopping = act & (nxt >= 0)
         res = act & (nxt < 0)
         cand = jnp.where(act & ~verify & (rcand < cand), rcand, cand)
-        leaf_mark = jnp.take(mflat, base + pos[lb])
-        leaf_live = (lv != EMPTY) & ~leaf_mark
+        leaf_marked = _pick(mrows_ref[...], bfs, lb) != 0
+        leaf_live = (lv != EMPTY) & ~leaf_marked
         f_res = res & ~verify
         leaf_fold = f_res & leaf_live & (lv > cursor) & (lv < cand)
         cand = jnp.where(leaf_fold, lv, cand)
@@ -352,43 +438,41 @@ def _scan_kernel(height: int, big: int, pmask: int, max_rounds: int,
         emit = hit & can_emit
         full = hit & ~can_emit
         chase = v_res & ~hit
-        out = jnp.where(emit[:, None] & (col == n[:, None]),
-                        lv[:, None], out)
+        out = jnp.where(emit & (col == n), lv, out)
         back_to_find = emit | chase
         restart = to_verify | back_to_find
         return (
             jnp.where(hopping, nxt, jnp.where(restart, dn0, dn)),
-            jnp.where(to_verify, True,
-                      jnp.where(back_to_find, False, verify)),
+            (to_verify | (verify & ~back_to_find)).astype(jnp.int32),
             jnp.where(to_verify, pending, q),
             jnp.where(back_to_find, q, cursor),
             jnp.where(restart, bigv, cand),
             out,
             n + emit.astype(jnp.int32),
             hops + act.astype(jnp.int32),
-            more | full,
-            done | f_none | full,
+            (more | full).astype(jnp.int32),
+            (done | f_none | full).astype(jnp.int32),
             rounds + 1,
         )
 
     init = (
         dn0,
-        jnp.zeros(starts.shape, jnp.bool_),
+        jnp.zeros(starts.shape, jnp.int32),
         starts,
         starts,
-        jnp.full(starts.shape, big, vflat.dtype),
-        jnp.full((starts.shape[0], mo_p), big, vflat.dtype),
+        jnp.full(starts.shape, big, dt),
+        jnp.full((starts.shape[0], mo_p), big, dt),
         jnp.zeros(starts.shape, jnp.int32),
         jnp.zeros(starts.shape, jnp.int32),
-        jnp.zeros(starts.shape, jnp.bool_),
-        starts == bigv,                             # sentinel lanes done
-        jnp.int32(0),
+        jnp.zeros(starts.shape, jnp.int32),
+        (starts == bigv).astype(jnp.int32),         # sentinel lanes done
+        _Z,
     )
     s = jax.lax.while_loop(cond, body, init)
     out_ref[...] = s[5]
     n_ref[...] = s[6]
     hops_ref[...] = s[7]
-    more_ref[...] = s[8].astype(jnp.int32)
+    more_ref[...] = s[8]
 
 
 @functools.partial(jax.jit,
@@ -398,11 +482,12 @@ def veb_scan_fused(value_p: jax.Array, mark_p: jax.Array, child_p: jax.Array,
                    roots: jax.Array, starts: jax.Array, his: jax.Array, *,
                    height: int, max_out: int, pmask: int = 0,
                    q_tile: int = 256, max_rounds: int = 256,
-                   interpret: bool = True):
+                   interpret: bool):
     """All scan passes in one launch (grid over query tiles).
 
-    value_p/mark_p: (M, UBp) padded arena rows + mark bits (`pad_arena` /
-                    same padding), int32/int64 rows
+    value_p:        (M, UBp) padded arena rows (`pad_arena`), int32/int64
+    mark_p:         (M, UBp) int32 mark plane, same padding (nonzero =
+                    marked)
     child_p:        (M, CP)  padded bottom-slot child ids (-1 none)
     roots:          (K,)     int32 per-lane frontier seeds
     starts/his:     (K,)     packed qpack bounds (start exclusive, hi
@@ -413,50 +498,56 @@ def veb_scan_fused(value_p: jax.Array, mark_p: jax.Array, child_p: jax.Array,
     lane-padded width ``mo_p = roundup(max_out, 128)`` — callers slice to
     ``max_out`` — n, hops, more(int32)), contract and bit-for-bit results
     documented on ``ref.ref_delta_scan_fused``.  The whole arena is
-    mapped into every grid cell — same VMEM budget gate as
-    ``veb_walk_fused``.
+    resident in VMEM — same budget gate as ``veb_walk_fused``.
     """
     k = starts.shape[0]
     assert k % q_tile == 0, (k, q_tile)
     assert starts.dtype == value_p.dtype, (starts.dtype, value_p.dtype)
+    assert mark_p.dtype == jnp.int32, mark_p.dtype
     n_tiles = k // q_tile
     m, ubp = value_p.shape
     cp = child_p.shape[1]
     big = walk_big(value_p.dtype)
     mo_p = _round_up(max_out, 128)
 
-    pos = jnp.asarray(layout.veb_pos_table(height))
-    posp = _round_up(pos.shape[0], 128)
-    pos = jnp.pad(pos, (0, posp - pos.shape[0]))
-
     out_shape = [
         jax.ShapeDtypeStruct((k, mo_p), value_p.dtype),   # out
-        jax.ShapeDtypeStruct((k,), jnp.int32),            # n
-        jax.ShapeDtypeStruct((k,), jnp.int32),            # hops
-        jax.ShapeDtypeStruct((k,), jnp.int32),            # more
+        jax.ShapeDtypeStruct((k, 1), jnp.int32),          # n
+        jax.ShapeDtypeStruct((k, 1), jnp.int32),          # hops
+        jax.ShapeDtypeStruct((k, 1), jnp.int32),          # more
     ]
-    return pl.pallas_call(
+    out, n, hops, more = pl.pallas_call(
         functools.partial(_scan_kernel, height, big, pmask, max_rounds,
                           max_out, mo_p, m),
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((posp,), lambda i: (0,)),
-            pl.BlockSpec((q_tile,), lambda i: (i,)),
-            pl.BlockSpec((q_tile,), lambda i: (i,)),
-            pl.BlockSpec((q_tile,), lambda i: (i,)),
-            pl.BlockSpec((m, ubp), lambda i: (0, 0)),
-            pl.BlockSpec((m, ubp), lambda i: (0, 0)),
-            pl.BlockSpec((m, cp), lambda i: (0, 0)),
+            _whole(ubp),
+            _tiles(q_tile),
+            _tiles(q_tile),
+            _tiles(q_tile),
+            _resident(),
+            _resident(),
+            _resident(),
         ],
         out_specs=[
-            pl.BlockSpec((q_tile, mo_p), lambda i: (i, 0)),
-            pl.BlockSpec((q_tile,), lambda i: (i,)),
-            pl.BlockSpec((q_tile,), lambda i: (i,)),
-            pl.BlockSpec((q_tile,), lambda i: (i,)),
+            _tiles(q_tile, mo_p),
+            _tiles(q_tile),
+            _tiles(q_tile),
+            _tiles(q_tile),
         ],
         out_shape=out_shape,
+        scratch_shapes=[
+            pltpu.VMEM((q_tile, 1), jnp.int32),          # frontier row ids
+            pltpu.VMEM((q_tile, ubp), value_p.dtype),    # value rows
+            pltpu.VMEM((q_tile, ubp), jnp.int32),        # mark rows
+            pltpu.VMEM((q_tile, cp), jnp.int32),         # child rows
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(pos, starts, his, roots, value_p, mark_p, child_p)
+    )(_bfs_labels(height, ubp), *_cols(starts, his, roots), value_p, mark_p,
+      child_p)
+    return out, n.reshape(k), hops.reshape(k), more.reshape(k)
 
 
 def pad_arena(value: jax.Array, child: jax.Array):

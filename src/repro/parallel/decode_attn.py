@@ -14,7 +14,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
@@ -56,9 +55,9 @@ def split_k_decode_attention(mesh, q, k_cache, v_cache, length,
         b, kvh, g, d = out.shape
         return out.reshape(b, 1, kvh * g, d).astype(q.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(None, axis, None, None), P(None, axis, None, None), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(q, k_cache, v_cache, length)

@@ -63,8 +63,8 @@ def prefill_to_pages(cfg: ModelConfig, params, page_size: int,
         v = c["v"][0]
         for bi, page in enumerate(pages):
             sl = slice(bi * page_size, (bi + 1) * page_size)
-            k_pages = k_pages.at[li, page].set(k[sl])
-            v_pages = v_pages.at[li, page].set(v[sl])
+            k_pages = k_pages.at[li, page].set(k[sl].swapaxes(0, 1))
+            v_pages = v_pages.at[li, page].set(v[sl].swapaxes(0, 1))
     return k_pages, v_pages, s, int(jnp.argmax(logits[0, -1]))
 
 
@@ -83,9 +83,9 @@ def paged_decode_step(params, cfg: ModelConfig, layer_params, tokens,
         kinds = (cfg.layer_kind(li), cfg.ffn_kind(li))
         h = rmsnorm_apply(lp["norm1"], x, cfg.norm_eps)
         q, k, v = qkv_proj(lp["mixer"], cfg, h, positions)
-        k_pages = k_pages.at[li, tail_page, tail_off].set(
+        k_pages = k_pages.at[li, tail_page, :, tail_off].set(
             k[:, 0].astype(k_pages.dtype))
-        v_pages = v_pages.at[li, tail_page, tail_off].set(
+        v_pages = v_pages.at[li, tail_page, :, tail_off].set(
             v[:, 0].astype(v_pages.dtype))
         o = paged_decode_attention(
             q[:, 0], k_pages[li], v_pages[li], block_tables, lengths + 1)

@@ -105,8 +105,8 @@ class ServeScheduler:
         L, NP = cfg.num_layers, pager_cfg.num_pages
         kvh, hd = cfg.num_kv_heads, cfg.head_dim
         dt = jnp.dtype(cfg.dtype)
-        self.k_pages = jnp.zeros((L, NP, self.ps, kvh, hd), dt)
-        self.v_pages = jnp.zeros((L, NP, self.ps, kvh, hd), dt)
+        self.k_pages = jnp.zeros((L, NP, kvh, self.ps, hd), dt)
+        self.v_pages = jnp.zeros((L, NP, kvh, self.ps, hd), dt)
         self.active: dict[int, ServeRequest] = {}   # every request ever
         self.lengths: dict[int, int] = {}
         self._next_id = 0

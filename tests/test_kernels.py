@@ -108,8 +108,8 @@ def test_paged_attention_kernel_vs_ref(b, qh, kvh, d, ps, maxp, dtype, tol):
     rng = np.random.default_rng(b * 100 + qh)
     npages = b * maxp + 3
     q = rng.standard_normal((b, qh, d)).astype(np.float32)
-    kp = rng.standard_normal((npages, ps, kvh, d)).astype(np.float32)
-    vp = rng.standard_normal((npages, ps, kvh, d)).astype(np.float32)
+    kp = rng.standard_normal((npages, kvh, ps, d)).astype(np.float32)
+    vp = rng.standard_normal((npages, kvh, ps, d)).astype(np.float32)
     lens = rng.integers(1, maxp * ps + 1, size=b).astype(np.int32)
     bt = np.full((b, maxp), -1, np.int32)
     perm = rng.permutation(npages)
@@ -134,8 +134,8 @@ def test_paged_attention_ignores_garbage_pages():
     b, qh, kvh, d, ps, maxp = 2, 4, 2, 32, 8, 3
     npages = 10
     q = rng.standard_normal((b, qh, d)).astype(np.float32)
-    kp = rng.standard_normal((npages, ps, kvh, d)).astype(np.float32)
-    vp = rng.standard_normal((npages, ps, kvh, d)).astype(np.float32)
+    kp = rng.standard_normal((npages, kvh, ps, d)).astype(np.float32)
+    vp = rng.standard_normal((npages, kvh, ps, d)).astype(np.float32)
     lens = np.asarray([9, 17], np.int32)
     bt = np.asarray([[4, 5, -1], [6, 7, 8]], np.int32)
     out1 = paged_decode_attention(jnp.asarray(q), jnp.asarray(kp),

@@ -178,10 +178,6 @@ def run_index(backend: str, initial: np.ndarray, key_hi: int,
     end), giving every perf row its hop / round / router columns."""
     from repro.obs import trace as OT
 
-    # one row = one measurement: REPRO_TRACE span counters must not leak
-    # across rows in a sweep (the chrome-trace event ring keeps the
-    # whole run's timeline and is left alone)
-    OT.reset_counters()
     if backend in STATS_BACKENDS:
         make_kw.setdefault("collect_stats", True)
     ix = make_index(backend, initial=initial, engine=engine,
@@ -243,8 +239,8 @@ def run_index(backend: str, initial: np.ndarray, key_hi: int,
     # Blocked and timed separately (``compile_seconds``) so no async
     # warmup work leaks into the steady-state clock.
     tc = time.perf_counter()
-    # host-side spans (nullcontext unless REPRO_TRACE): the warmup and
-    # steady-state loops are the rows of the --trace-dir chrome timeline
+    # host spans: the warmup and steady-state loops, as rows of the
+    # --trace-dir xplane
     with OT.span(f"bench.{backend}.compile"):
         for _ in range(2):
             ix, found = one_step(ix)

@@ -70,8 +70,9 @@ def main() -> None:
                     help="maint suite: run only this policy")
     ap.add_argument("--trace-dir", default=None,
                     help="capture an xprof trace of the whole run into "
-                         "this logdir (repro.obs.trace.capture; spans "
-                         "need REPRO_TRACE=1 in the environment)")
+                         "this logdir (repro.obs.trace.capture: the "
+                         "xplane, with the program's host spans beside "
+                         "the device ops)")
     add_common_args(ap)
     args, _ = ap.parse_known_args()
     if args.compiled:
@@ -110,10 +111,6 @@ def main() -> None:
     if args.trace_dir:
         from repro.obs import trace as OT
 
-        # asking for a trace dir IS the span opt-in: turn REPRO_TRACE on
-        # so the chrome-trace timeline below has events even off-TPU
-        # (where the xprof capture may have little to sample)
-        os.environ.setdefault(OT.ENV, "1")
         cm = OT.capture(args.trace_dir)
     else:
         import contextlib
@@ -150,13 +147,6 @@ def main() -> None:
             add("scan", scan_sweep.main(quick=quick, seed=seed,
                                         backend=backend, engine=engine,
                                         smoke=smoke))
-    if args.trace_dir:
-        from repro.obs import trace as OT
-
-        path = os.path.join(args.trace_dir, "chrome_trace.json")
-        n = OT.write_chrome_trace(path)
-        print(f"# chrome trace: {n} span events -> {path} "
-              "(chrome://tracing or ui.perfetto.dev)", flush=True)
     _consolidate(rows, dict(full=args.full, smoke=smoke, seed=seed,
                             backend=backend, engine=engine,
                             only=args.only, compiled=args.compiled))

@@ -16,9 +16,8 @@ order — for ``deltatree`` vs the ``sorted_array`` baseline (and
 Each JSON row records ``density`` / ``max_items`` / ``scans_per_s`` /
 ``items_per_s`` plus the hop telemetry, and lockstep rows pin
 ``walk_launches = 1.0``: the lockstep scan driver is a single
-``delta_scan`` launch per dispatch (``kernels.ops`` bumps the
-``delta_scan.dispatch`` counter exactly once per traced call), the
-scan-path analogue of the fused walk's single-launch guarantee.
+``delta_scan`` launch per dispatch, the scan-path analogue of the
+fused walk's single-launch guarantee.
 """
 
 from __future__ import annotations

@@ -14,6 +14,12 @@ Methods delegate through the spec; ``capability`` says which ones a
 backend supports (``CapabilityError`` otherwise).  ``insert_delete``
 returns a *new* handle — backends may donate the old state's buffers, so
 callers must rebind: ``ix, res = ix.insert_delete(batch)``.
+
+Every concrete read, scan and update call opens one ``index.<method>``
+span (``repro.obs.trace``) that carries the call's sequence number
+``seq`` and its ``lanes`` (scans also ``k``), and records the call's
+counters in the device ring of ``repro.obs.ring`` right after its own
+program is dispatched.  Calls made while JAX traces record nothing.
 """
 
 from __future__ import annotations
@@ -26,6 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api.opbatch import OpBatch
+from repro.obs import ring as OR
+from repro.obs import trace as OT
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,6 +63,23 @@ def cfg_attr(cfg, name: str, default=None):
     if v is None:
         v = getattr(getattr(cfg, "tree", None), name, None)
     return default if v is None else v
+
+
+def _lanes(keys) -> int:
+    return int(np.shape(keys)[0])
+
+
+def _free_top(state):
+    """The arena's free-list depth (a forest's, over all its shards);
+    None for backends without an arena."""
+    ft = getattr(state, "free_top", None)
+    if ft is None:
+        ft = getattr(getattr(state, "trees", None), "free_top", None)
+    return ft
+
+
+def _scan_counts(out):
+    return {"n": out[2], "hops": out[3], "more": out[4]}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -171,23 +196,45 @@ class Index:
                 f"backend {self.backend!r} does not support {flag!r} "
                 f"(capability: {self.capability})")
 
+    def _observed(self, kind: str, lanes: int, run, counts, **args):
+        """``run()`` under its ``index.<kind>`` span, then the call's
+        counters (``counts(out)``) into the ring; plain ``run()`` while
+        JAX traces."""
+        if not OT.top_level():
+            return run()
+        seq = OR.next_seq()
+        with OT.span(f"index.{kind}", seq=seq, lanes=lanes, **args):
+            out = run()
+            OR.record(kind, seq, lanes, **counts(out))
+        return out
+
     # ---- wait-free reads ----
 
     def search(self, keys: jax.Array):
         """Membership on the current snapshot. Returns (found[K], hops[K])
         — plus a trailing ``ReadStats`` when ``self.collect_stats``."""
-        return self.spec.backend.search(self.spec.cfg, self.state, keys)
+        return self._observed(
+            "search", _lanes(keys),
+            lambda: self.spec.backend.search(self.spec.cfg, self.state, keys),
+            lambda out: {"hops": out[1]})
 
     def lookup(self, keys: jax.Array):
         """Map-mode read. Returns (found[K], payload[K], hops[K]) — plus
         a trailing ``ReadStats`` when ``self.collect_stats``."""
         self._require("map_mode", self.spec.backend.lookup)
-        return self.spec.backend.lookup(self.spec.cfg, self.state, keys)
+        return self._observed(
+            "lookup", _lanes(keys),
+            lambda: self.spec.backend.lookup(self.spec.cfg, self.state, keys),
+            lambda out: {"hops": out[2]})
 
     def successor(self, keys: jax.Array):
         """Smallest stored key strictly greater. Returns (found[K], succ[K])."""
         self._require("successor", self.spec.backend.successor)
-        return self.spec.backend.successor(self.spec.cfg, self.state, keys)
+        return self._observed(
+            "successor", _lanes(keys),
+            lambda: self.spec.backend.successor(
+                self.spec.cfg, self.state, keys),
+            lambda out: {})
 
     def range_scan(self, lo: int, hi: int, *, max_items: int = 128,
                    cursor: "ScanCursor | None" = None) -> "ScanResult":
@@ -202,14 +249,12 @@ class Index:
         from repro.core import layout
         from repro.core.scan import ScanCursor, ScanResult
 
-        self._require("range_scan", self.spec.backend.scan)
         if cursor is not None:
             lo, hi = cursor.last_key + 1, cursor.hi
         hi = min(int(hi), layout.KEY_MAX)
         start = jnp.asarray([max(int(lo) - 1, 0)], jnp.int32)
         his = jnp.asarray([hi], jnp.int32)
-        ks, ps, n, _, more = self.spec.backend.scan(
-            self.spec.cfg, self.state, start, his, max_items)
+        ks, ps, n, _, more = self.scan(start, his, max_items)
         count = int(n[0])
         truncated = bool(more[0]) and count > 0
         keys = np.asarray(ks[0])[:count]
@@ -219,6 +264,17 @@ class Index:
         return ScanResult(keys=keys, payloads=pays, more=truncated,
                           cursor=cur)
 
+    def scan(self, starts: jax.Array, his: jax.Array, max_items: int):
+        """Bulk ordered range read: per lane, up to ``max_items`` live
+        (key, payload) rows with ``start < key <= hi`` (start EXCLUSIVE,
+        hi INCLUSIVE), ascending.  Returns the ``successor_k`` 5-tuple."""
+        self._require("range_scan", self.spec.backend.scan)
+        return self._observed(
+            "scan", _lanes(starts),
+            lambda: self.spec.backend.scan(
+                self.spec.cfg, self.state, starts, his, max_items),
+            _scan_counts, k=max_items)
+
     def successor_k(self, keys: jax.Array, k: int):
         """Bulk ordered read: per query, the ``k`` smallest live keys
         strictly greater.  Returns (keys (K, k) int32 ascending rows,
@@ -227,8 +283,11 @@ class Index:
         with further successors beyond the ``k`` returned; ``hops`` is 0
         for backends with no tree walk."""
         self._require("successor_k", self.spec.backend.successor_k)
-        return self.spec.backend.successor_k(
-            self.spec.cfg, self.state, keys, k)
+        return self._observed(
+            "successor_k", _lanes(keys),
+            lambda: self.spec.backend.successor_k(
+                self.spec.cfg, self.state, keys, k),
+            _scan_counts, k=k)
 
     # ---- updates ----
 
@@ -247,8 +306,11 @@ class Index:
         MaintenanceStats | None) — stats is None for backends without a
         maintenance scheduler (baselines)."""
         self._require("updates", self.spec.backend.update)
-        state, results, stats = self.spec.backend.update(
-            self.spec.cfg, self.state, batch)
+        state, results, stats = self._observed(
+            "update", _lanes(batch.keys),
+            lambda: self.spec.backend.update(
+                self.spec.cfg, self.state, batch),
+            lambda out: {"maint": out[2], "free_top": _free_top(out[0])})
         return Index(self.spec, state), results, stats
 
     def flush(self):
@@ -259,7 +321,10 @@ class Index:
         may be donated — always rebind."""
         if self.spec.backend.flush is None:
             return self, None
-        state, stats = self.spec.backend.flush(self.spec.cfg, self.state)
+        state, stats = self._observed(
+            "flush", 0,
+            lambda: self.spec.backend.flush(self.spec.cfg, self.state),
+            lambda out: {"maint": out[1], "free_top": _free_top(out[0])})
         return Index(self.spec, state), stats
 
     # ---- host-side diagnostics ----

@@ -11,6 +11,7 @@ no call-site changes.
 from __future__ import annotations
 
 from repro.api.index import BackendSpec, Index, IndexSpec
+from repro.obs import trace as OT
 
 _REGISTRY: dict[str, BackendSpec] = {}
 
@@ -78,7 +79,8 @@ def make_index(backend: str = "deltatree", *, initial=None, payloads=None,
     ``maintenance`` the scheduler policy ("eager" / "deferred" /
     "budgeted:K"; validated against the backend's declared policy kinds),
     remaining kwargs go to the backend's config (e.g. ``height=7`` or a
-    prebuilt ``cfg=...``)."""
+    prebuilt ``cfg=...``).  The build runs under the ``index.build``
+    span (``repro.obs.trace``)."""
     from repro.maintenance import parse_policy
 
     spec = get_backend(backend)
@@ -107,7 +109,8 @@ def make_index(backend: str = "deltatree", *, initial=None, payloads=None,
                 f"{kinds}, not {maintenance!r}")
         if spec.maintenance != ("eager",):
             kwargs["maintenance"] = str(pol)
-    cfg, state = spec.make(initial, payloads, **kwargs)
+    with OT.span("index.build"):
+        cfg, state = spec.make(initial, payloads, **kwargs)
     ix = Index(IndexSpec(backend=spec, cfg=cfg), state)
     if ix.engine not in supported_engines(backend):
         # catches engine typos smuggled in via a prebuilt cfg= (e.g.

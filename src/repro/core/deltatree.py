@@ -62,6 +62,7 @@ import numpy as np
 
 from repro.core import layout
 from repro.core.layout import EMPTY, ROUTE_LEFT
+from repro.obs import trace as OT
 
 NONE = jnp.int32(-1)
 
@@ -1054,102 +1055,111 @@ def lookup_jit(cfg: TreeConfig, t: DeltaTree, keys: jax.Array):
 
 def bulk_build(cfg: TreeConfig, values: np.ndarray,
                payloads: np.ndarray | None = None) -> DeltaTree:
-    """Build a half-dense ΔTree from unique keys (any order). Host-side."""
-    values = np.asarray(values, dtype=np.int64)
-    order = np.argsort(values)
-    values = values[order]
-    assert (np.diff(values) > 0).all(), "keys must be unique"
-    if payloads is None:
-        payloads = np.zeros(len(values), np.int64)
-    else:
-        payloads = np.asarray(payloads, np.int64)[order]
-    assert values.size == 0 or (
-        values[0] >= layout.KEY_MIN and values[-1] <= layout.KEY_MAX
-    )
-    if cfg.payload_bits:
-        packed = (values << cfg.payload_bits) | (payloads & cfg.pmask)
-        npdt = np.int64
-        route_left = np.int64(1) << 62
-    else:
-        packed = values.astype(np.int32)
-        npdt = np.int32
-        route_left = np.int32(ROUTE_LEFT)
+    """Build a half-dense ΔTree from unique keys (any order). Host-side.
 
-    m, ub, lc = cfg.max_dnodes, cfg.ub, cfg.leaf_cap
-    g = max(cfg.half_cap, 1)
-
-    value = np.full((m, ub), EMPTY, npdt)
-    child = np.full((m, lc), -1, np.int32)
-    nlive = np.zeros((m,), np.int32)
-    nchild = np.zeros((m,), np.int32)
-    parent = np.full((m,), -1, np.int32)
-    pslot = np.zeros((m,), np.int32)
-    alive = np.zeros((m,), bool)
-    next_id = 0
-
-    def new_node():
-        nonlocal next_id
-        i = next_id
-        next_id += 1
-        assert i < m, f"bulk_build: arena too small (need > {m} ΔNodes)"
-        alive[i] = True
-        return i
-
-    def rebuild_np(run, force_bottom=False):
-        return layout.rebuild_values_np(
-            cfg.height, run, run.size, force_bottom=force_bottom,
-            dtype=npdt, route_left=route_left,
+    Spans (``repro.obs.trace``): ``index.build.sort`` (order and pack
+    the keys), ``index.build.layout`` (the Python loop over ΔNodes) and
+    ``index.build.upload`` (the arena to the device, waited for)."""
+    with OT.span("index.build.sort"):
+        values = np.asarray(values, dtype=np.int64)
+        order = np.argsort(values)
+        values = values[order]
+        assert (np.diff(values) > 0).all(), "keys must be unique"
+        if payloads is None:
+            payloads = np.zeros(len(values), np.int64)
+        else:
+            payloads = np.asarray(payloads, np.int64)[order]
+        assert values.size == 0 or (
+            values[0] >= layout.KEY_MIN and values[-1] <= layout.KEY_MAX
         )
+        if cfg.payload_bits:
+            packed = (values << cfg.payload_bits) | (payloads & cfg.pmask)
+            npdt = np.int64
+            route_left = np.int64(1) << 62
+        else:
+            packed = values.astype(np.int32)
+            npdt = np.int32
+            route_left = np.int32(ROUTE_LEFT)
 
-    if packed.size == 0:
-        ids = [new_node()]
-    else:
-        ids, mins = [], []
-        for s in range(0, packed.size, g):
-            run = packed[s : s + g]
-            i = new_node()
-            value[i] = rebuild_np(run)
-            nlive[i] = run.size
-            ids.append(i)
-            mins.append(run[0])
-        while len(ids) > 1:
-            nids, nmins = [], []
-            for s in range(0, len(ids), g):
-                kids = ids[s : s + g]
-                kmins = np.asarray(mins[s : s + g], npdt)
+    with OT.span("index.build.layout"):
+        m, ub, lc = cfg.max_dnodes, cfg.ub, cfg.leaf_cap
+        g = max(cfg.half_cap, 1)
+
+        value = np.full((m, ub), EMPTY, npdt)
+        child = np.full((m, lc), -1, np.int32)
+        nlive = np.zeros((m,), np.int32)
+        nchild = np.zeros((m,), np.int32)
+        parent = np.full((m,), -1, np.int32)
+        pslot = np.zeros((m,), np.int32)
+        alive = np.zeros((m,), bool)
+        next_id = 0
+
+        def new_node():
+            nonlocal next_id
+            i = next_id
+            next_id += 1
+            assert i < m, f"bulk_build: arena too small (need > {m} ΔNodes)"
+            alive[i] = True
+            return i
+
+        def rebuild_np(run, force_bottom=False):
+            return layout.rebuild_values_np(
+                cfg.height, run, run.size, force_bottom=force_bottom,
+                dtype=npdt, route_left=route_left,
+            )
+
+        if packed.size == 0:
+            ids = [new_node()]
+        else:
+            ids, mins = [], []
+            for s in range(0, packed.size, g):
+                run = packed[s : s + g]
                 i = new_node()
-                value[i] = rebuild_np(kmins, force_bottom=True)
-                for slot, cid in enumerate(kids):
-                    child[i, slot] = cid
-                    parent[cid] = i
-                    pslot[cid] = slot
-                nchild[i] = len(kids)
-                nids.append(i)
-                nmins.append(kmins[0])
-            ids, mins = nids, nmins
+                value[i] = rebuild_np(run)
+                nlive[i] = run.size
+                ids.append(i)
+                mins.append(run[0])
+            while len(ids) > 1:
+                nids, nmins = [], []
+                for s in range(0, len(ids), g):
+                    kids = ids[s : s + g]
+                    kmins = np.asarray(mins[s : s + g], npdt)
+                    i = new_node()
+                    value[i] = rebuild_np(kmins, force_bottom=True)
+                    for slot, cid in enumerate(kids):
+                        child[i, slot] = cid
+                        parent[cid] = i
+                        pslot[cid] = slot
+                    nchild[i] = len(kids)
+                    nids.append(i)
+                    nmins.append(kmins[0])
+                ids, mins = nids, nmins
 
-    root = ids[0]
-    free = np.zeros(m, np.int32)
-    nfree = m - next_id
-    free[:nfree] = np.arange(m - 1, next_id - 1, -1, dtype=np.int32)
-    return DeltaTree(
-        value=jnp.asarray(value),
-        mark=jnp.zeros((m, ub), jnp.bool_),
-        child=jnp.asarray(child),
-        buf=jnp.full((m, cfg.buf_cap), EMPTY, cfg.vdtype),
-        nlive=jnp.asarray(nlive),
-        bcount=jnp.zeros((m,), jnp.int32),
-        nchild=jnp.asarray(nchild),
-        parent=jnp.asarray(parent),
-        pslot=jnp.asarray(pslot),
-        alive=jnp.asarray(alive),
-        free_stack=jnp.asarray(free),
-        free_top=jnp.int32(nfree),
-        root=jnp.int32(root),
-        ins_flag=jnp.zeros((m,), jnp.bool_),
-        del_flag=jnp.zeros((m,), jnp.bool_),
-        alloc_fail=jnp.bool_(False),
-    )
+        root = ids[0]
+        free = np.zeros(m, np.int32)
+        nfree = m - next_id
+        free[:nfree] = np.arange(m - 1, next_id - 1, -1, dtype=np.int32)
+    with OT.span("index.build.upload"):
+        t = DeltaTree(
+            value=jnp.asarray(value),
+            mark=jnp.zeros((m, ub), jnp.bool_),
+            child=jnp.asarray(child),
+            buf=jnp.full((m, cfg.buf_cap), EMPTY, cfg.vdtype),
+            nlive=jnp.asarray(nlive),
+            bcount=jnp.zeros((m,), jnp.int32),
+            nchild=jnp.asarray(nchild),
+            parent=jnp.asarray(parent),
+            pslot=jnp.asarray(pslot),
+            alive=jnp.asarray(alive),
+            free_stack=jnp.asarray(free),
+            free_top=jnp.int32(nfree),
+            root=jnp.int32(root),
+            ins_flag=jnp.zeros((m,), jnp.bool_),
+            del_flag=jnp.zeros((m,), jnp.bool_),
+            alloc_fail=jnp.bool_(False),
+        )
+        jax.block_until_ready(t)
+    return t
 
 
 # --------------------------------------------------------------------------

@@ -48,7 +48,6 @@ import jax.numpy as jnp
 from repro.core import deltatree as DT
 from repro.core import layout
 from repro.core.layout import EMPTY
-from repro.obs import trace as TR
 
 
 @dataclasses.dataclass(frozen=True)
@@ -238,8 +237,7 @@ def lookup_cols(cfg, t, keys: jax.Array):
     forest's dense per-shard dispatch reads through this so stats are
     derived exactly once, in the forest's own dispatch layer (mirroring
     the fused path, which also calls raw hooks)."""
-    with TR.annotate(f"engine.{cfg.engine}.lookup"):
-        return get_engine(cfg.engine).lookup(cfg, t, keys)
+    return get_engine(cfg.engine).lookup(cfg, t, keys)
 
 
 def lookup(cfg, t, keys: jax.Array):
@@ -276,8 +274,7 @@ def successor(cfg, t, keys: jax.Array):
     trees skip the fold (buffers are empty between steps — I5), keeping
     the pre-subsystem read bit-identical.
     """
-    with TR.annotate(f"engine.{cfg.engine}.successor"):
-        found, succ = get_engine(cfg.engine).successor(cfg, t, keys)
+    found, succ = get_engine(cfg.engine).successor(cfg, t, keys)
     policy = getattr(cfg, "maintenance", "eager")
     if policy == "eager" or not hasattr(cfg, "route_left"):
         return found, succ
@@ -315,9 +312,8 @@ def scan(cfg, t, starts: jax.Array, his: jax.Array, *, max_out: int,
     if eng.scan_batch is None:
         raise NotImplementedError(
             f"engine {cfg.engine!r} declares no scan_batch hook")
-    with TR.annotate(f"engine.{cfg.engine}.scan"):
-        out, n, hops, more = eng.scan_batch(cfg, t, starts, his, max_out,
-                                            root=root)
+    out, n, hops, more = eng.scan_batch(cfg, t, starts, his, max_out,
+                                        root=root)
     policy = getattr(cfg, "maintenance", "eager")
     if policy == "eager" or not hasattr(cfg, "route_left"):
         return out, n, hops, more

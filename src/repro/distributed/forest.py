@@ -94,7 +94,7 @@ class Forest(NamedTuple):
     `update_batch`; reads are pure, so read batches only accumulate when
     the caller opts in via the `record_reads` state transition.
 
-    ``epoch`` is the arena-mutation counter: bumped by every
+    ``epoch`` is the arena-mutation counter: advanced by every
     `update_batch`/`flush` (the only transitions that touch arena
     contents), preserved by pure-counter transitions (`record_reads`).
     It keys the host-side fused-view cache — a read on an unchanged
@@ -214,7 +214,7 @@ def _fused(fcfg: ForestConfig):
 # `kernels.veb_search.fuse_arenas`) is pure data derived from the arenas:
 # read-heavy loops over an unchanged forest were rebuilding it on every
 # call.  The public read wrappers below look it up in a small host-side
-# LRU keyed on ``(fcfg, epoch)`` — epoch bumps on every arena mutation,
+# LRU keyed on ``(fcfg, epoch)`` — epoch advances on every arena mutation,
 # and a paranoid identity check on the trees pytree catches two distinct
 # forests that happen to share an epoch — then hand it to the jitted read
 # core as a regular pytree argument.  Inside someone else's trace the

@@ -38,7 +38,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import layout
-from repro.obs import trace as TR
 from repro.parallel import make_forest_mesh
 
 
@@ -141,13 +140,12 @@ def dispatch(num_shards: int, fn, trees, *dense_args, sequential=False):
         return jax.vmap(fn)(trees_loc, *args_loc)
 
     nargs = 1 + len(dense_args)
-    with TR.annotate("router.dispatch"):
-        return jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(P("shards"),) * nargs,
-            out_specs=P("shards"),
-            check_vma=False,
-        )(trees, *dense_args)
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P("shards"),) * nargs,
+        out_specs=P("shards"),
+        check_vma=False,
+    )(trees, *dense_args)
 
 
 def build_fused_view(num_shards: int, make_view, trees):
@@ -165,19 +163,17 @@ def build_fused_view(num_shards: int, make_view, trees):
     mesh = forest_mesh(num_shards)
     d = mesh.devices.size
     if d == 1:
-        with TR.annotate("router.fuse_view"):
-            return make_view(trees)
+        return make_view(trees)
 
     def body(trees_loc):
         return jax.tree.map(lambda x: x[None], make_view(trees_loc))
 
-    with TR.annotate("router.fuse_view"):
-        return jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(P("shards"),),
-            out_specs=P("shards"),
-            check_vma=False,
-        )(trees)
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P("shards"),),
+        out_specs=P("shards"),
+        check_vma=False,
+    )(trees)
 
 
 def fused_dispatch(num_shards: int, fn, trees, sid, keys, view=None):
@@ -210,8 +206,7 @@ def fused_dispatch(num_shards: int, fn, trees, sid, keys, view=None):
     mesh = forest_mesh(num_shards)
     d = mesh.devices.size
     if d == 1:
-        with TR.annotate("router.fused"):
-            lane, per_shard = fn(trees, sid, keys, view)
+        lane, per_shard = fn(trees, sid, keys, view)
         return None, lane, per_shard
     sloc = num_shards // d
     r = route_by(sid // jnp.int32(sloc), d)
@@ -234,13 +229,12 @@ def fused_dispatch(num_shards: int, fn, trees, sid, keys, view=None):
         return jax.tree.map(lambda x: x[None], lane), per_shard
 
     extra = () if view is None else (view,)
-    with TR.annotate("router.fused"):
-        lane, per_shard = jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(P("shards"),) * (3 + len(extra)),
-            out_specs=P("shards"),
-            check_vma=False,
-        )(trees, dlid, dkeys, *extra)
+    lane, per_shard = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P("shards"),) * (3 + len(extra)),
+        out_specs=P("shards"),
+        check_vma=False,
+    )(trees, dlid, dkeys, *extra)
     return r, lane, per_shard
 
 

@@ -50,7 +50,6 @@ from repro.kernels.veb_search import (
     _round_up, pad_arena, veb_scan_fused, veb_walk_fused, veb_walk_rows,
     walk_big,
 )
-from repro.obs import trace as TR
 
 
 def default_interpret() -> bool:
@@ -285,20 +284,18 @@ def delta_walk(value: jax.Array, child: jax.Array, root: jax.Array,
                 bound; ``walk_big(dtype)`` = the dtype's ROUTE_LEFT when no
                 left turn happened)
     """
-    TR.bump("delta_walk.dispatch")
     q_tile = _resolve_q_tile(
         q_tile, height, 0 if value.dtype == jnp.int32 else 1)
     max_rounds = _resolve_max_rounds(max_rounds, height, value.shape[0])
     interpret = _resolve_interpret(interpret)
-    with TR.annotate("delta_walk"):
-        if _resolve_fused(fused):
-            return _delta_walk_fused(value, child, root, queries,
-                                     height=height, q_tile=q_tile,
-                                     max_rounds=max_rounds,
-                                     interpret=interpret)
-        return _delta_walk(value, child, root, queries, height=height,
-                           q_tile=q_tile, max_rounds=max_rounds,
-                           interpret=interpret)
+    if _resolve_fused(fused):
+        return _delta_walk_fused(value, child, root, queries,
+                                 height=height, q_tile=q_tile,
+                                 max_rounds=max_rounds,
+                                 interpret=interpret)
+    return _delta_walk(value, child, root, queries, height=height,
+                       q_tile=q_tile, max_rounds=max_rounds,
+                       interpret=interpret)
 
 
 def delta_walk_fused(value: jax.Array, child: jax.Array, root: jax.Array,
@@ -381,17 +378,13 @@ def _delta_walk(value, child, root, queries, *, height, q_tile, max_rounds,
         return jnp.any(~s["resolved"]) & (s["rounds"] < max_rounds)
 
     def body(s):
-        # REPRO_TRACE: names one frontier round in xprof (the paper's
-        # "one memory transfer") — gated at trace time, so flipping the
-        # env var between calls does not retrace cached programs
-        with TR.annotate("delta_walk.round"):
-            dnc = jnp.clip(s["dn"], 0, value.shape[0] - 1)
-            rows = value_p[dnc]      # (K, UBp) — the per-query ΔNode DMA
-            childrows = child_p[dnc]
-            lv, lb, nxt, rcand = _row_walk(
-                rows, childrows, qpad, height=height, q_tile=q_tile,
-                interpret=interpret,
-            )
+        dnc = jnp.clip(s["dn"], 0, value.shape[0] - 1)
+        rows = value_p[dnc]      # (K, UBp) — the per-query ΔNode DMA
+        childrows = child_p[dnc]
+        lv, lb, nxt, rcand = _row_walk(
+            rows, childrows, qpad, height=height, q_tile=q_tile,
+            interpret=interpret,
+        )
         act = ~s["resolved"]
         done_now = act & (nxt < 0)
         return dict(
@@ -452,17 +445,15 @@ def delta_scan(value: jax.Array, mark: jax.Array, child: jax.Array,
       more: bool — buffer filled with live items remaining; resume from
             ``key_of(out[lane, n-1])``
     """
-    TR.bump("delta_scan.dispatch")
     q_tile = _resolve_q_tile(
         q_tile, height, 0 if value.dtype == jnp.int32 else 1)
     if max_rounds is None:
         max_rounds = scan_round_cap(height, value.shape[0], max_out)
     interpret = _resolve_interpret(interpret)
-    with TR.annotate("delta_scan"):
-        return _delta_scan(value, mark, child, root, starts, his,
-                           height=height, max_out=max_out, pmask=pmask,
-                           q_tile=q_tile, max_rounds=int(max_rounds),
-                           interpret=interpret)
+    return _delta_scan(value, mark, child, root, starts, his,
+                       height=height, max_out=max_out, pmask=pmask,
+                       q_tile=q_tile, max_rounds=int(max_rounds),
+                       interpret=interpret)
 
 
 @functools.partial(

@@ -37,7 +37,6 @@ import jax.numpy as jnp
 from repro.core import deltatree as DT
 from repro.maintenance.policy import MaintenancePolicy, parse_policy
 from repro.maintenance.stats import MaintenanceStats
-from repro.obs import trace as TR
 
 _Work = tuple  # (rebuilds, expands, merges, reclaimed) int32 scalars
 
@@ -251,11 +250,9 @@ def _run_eager(cfg, t, kinds, keys, payloads, results, pending, budget):
 
     def round_body(s):
         t, results, pending, rounds, work = s
-        with TR.annotate("maint.ops"):
-            t, results, pending, _ = _ops_phase(cfg, t, results, pending,
-                                                kinds, keys, payloads, budget)
-        with TR.annotate("maint.sweep"):
-            t, work = _maint_phases(cfg, t, work, budget)
+        t, results, pending, _ = _ops_phase(cfg, t, results, pending,
+                                            kinds, keys, payloads, budget)
+        t, work = _maint_phases(cfg, t, work, budget)
         return t, results, pending, rounds + 1, work
 
     t, results, pending, rounds, work = jax.lax.while_loop(
@@ -369,10 +366,9 @@ def _run_relaxed(cfg, policy: MaintenancePolicy, t, kinds, keys, payloads,
 
     def round_body(s):
         t, results, pending, rounds, work, repairs, residual = s
-        with TR.annotate("maint.ops"):
-            t, results, pending, dns = _ops_phase(cfg, t, results, pending,
-                                                  kinds, keys, payloads,
-                                                  budget)
+        t, results, pending, dns = _ops_phase(cfg, t, results, pending,
+                                              kinds, keys, payloads,
+                                              budget)
         if vol:
             t, work, repairs, residual = jax.lax.cond(
                 (repairs < vol) & jnp.any((t.ins_flag | t.del_flag)
@@ -382,8 +378,7 @@ def _run_relaxed(cfg, policy: MaintenancePolicy, t, kinds, keys, payloads,
 
         def forced(args):
             t, work, residual = args
-            with TR.annotate("maint.sweep"):
-                t, work, pmask = _ins_sweep(cfg, t, work, fmask, budget)
+            t, work, pmask = _ins_sweep(cfg, t, work, fmask, budget)
             residual = (residual & ~pmask) | (pmask & (t.bcount > 0)
                                               & t.alive)
             return t, work, residual
@@ -447,8 +442,7 @@ def flush(cfg, t, budget: int = 64):
 
     def round_body(s):
         t, rounds, work = s
-        with TR.annotate("maint.sweep"):
-            t, work = _maint_phases(cfg, t, work, budget)
+        t, work = _maint_phases(cfg, t, work, budget)
         return t, rounds + 1, work
 
     t, rounds, work = jax.lax.while_loop(

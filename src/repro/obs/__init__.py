@@ -1,43 +1,48 @@
 """repro.obs — cross-cutting observability (DESIGN.md §9).
 
-Three kinds of instrument, all zero-cost when off:
+One always-on span and counter mechanism, plus the paper's gated
+measurements:
 
+- **Spans** (`obs.trace`): ``span(name, **args)`` is a
+  ``jax.profiler.TraceAnnotation`` on the profiler's clock, so a
+  captured xplane lays the program's host spans beside the device ops;
+  it adds a process-wide host total per name (``trace.totals()``) and
+  never touches a compiled program.  Every concrete ``Index`` call opens
+  ``index.<method>`` with its sequence number ``seq``; ``make_index``
+  opens ``index.build`` (children ``index.build.sort``, ``.layout``,
+  ``.upload``); the serve scheduler opens ``serve.*``.
+  ``trace.capture(logdir)`` records an xplane of a region.
+- **Per-call counters** (`obs.ring`): each concrete ``Index`` call
+  writes one row (``seq``, kind, lanes, ``hops_sum``/``hops_max``, a
+  scan's ``emitted``/``truncated``, an update's ``MaintenanceStats`` and
+  ``free_top``) into an int32 ring on the device, by a small program
+  dispatched after the call's own; the host waits on it only when it is
+  read: ``obs.calls(seqs)`` (numpy rows, joined to the spans by
+  ``seq``) and ``ring.totals()`` (running sums, also in
+  ``export.program_totals()``).
 - **Counter pytrees** (`obs.stats`): jit/shard_map-safe NamedTuples of
-  int32 counters riding the return path, generalizing the
-  ``MaintenanceStats`` pattern (which now lives here) — ``SearchStats``
-  for the read path, ``RouterStats`` for the forest router,
-  ``ServeStats`` for the decode loop.  Collection is gated by the
-  *static* ``TreeConfig.collect_stats`` flag: the disabled path lowers
-  to HLO byte-identical to a build without the stats code at all
+  int32 counters riding the return path — ``MaintenanceStats``,
+  ``ScanStats``, ``SearchStats`` (inside ``ReadStats``), ``RouterStats``,
+  ``ServeStats``.  ``ReadStats`` is the paper's Table 1 measurement,
+  gated by the *static* ``TreeConfig.collect_stats`` flag: the disabled
+  path lowers to HLO byte-identical to a build without the stats code
   (asserted by ``tests/test_obs.py``).
-- **Trace spans** (`obs.trace`): ``jax.profiler.TraceAnnotation`` /
-  ``jax.named_scope`` wrappers around engine dispatch, ``delta_walk``
-  rounds, router dispatch and maintenance phases, gated by the
-  ``REPRO_TRACE`` env var, plus an xprof trace-dump helper
-  (``obs.trace.capture``) for the compiled-performance campaign.
+- **Measured transfers** (`obs.transfers`): a device-side replay of the
+  descent deriving ``TransferStats``, equal on a quiescent tree to the
+  analytical `core.baselines.count_block_transfers` exactly, gated by
+  ``TreeConfig.collect_transfers`` under ``collect_stats`` (DESIGN.md
+  §14).
+- **Metrics export** (`obs.export`): stats pytrees and running totals →
+  one named snapshot → Prometheus text exposition / JSON
+  (``ServeScheduler.metrics()`` is the live producer).
 - **Benchmark reports** (`obs.report`): a stdlib-only CLI that renders
-  consolidated ``BENCH_*.json`` files as per-suite tables, *diffs* them
-  against a baseline file (speedup deltas, regression flags), and
-  renders per-suite ``--history`` trajectories across many files::
+  consolidated ``BENCH_*.json`` files as per-suite tables and diffs them::
 
       python -m repro.obs.report BENCH_NEW.json --diff BENCH_OLD.json
       python -m repro.obs.report BENCH_*.json --history
-
-Two more instruments complete the transfer-accounting loop
-(DESIGN.md §14):
-
-- **Measured transfers** (`obs.transfers`): a device-side replay of the
-  descent deriving ``TransferStats`` — distinct ΔNode visits and
-  distinct B-block touches per read batch — equal on a quiescent tree
-  to the analytical `core.baselines.count_block_transfers` *exactly*,
-  gated by ``TreeConfig.collect_transfers`` under ``collect_stats``.
-- **Metrics export** (`obs.export`): stats pytrees → one named snapshot
-  → Prometheus text exposition / JSON (``ServeScheduler.metrics()`` is
-  the live producer), plus `obs.trace.write_chrome_trace` for a
-  perfetto-compatible span timeline.
 """
 
-from repro.obs import export, report, stats, trace, transfers
+from repro.obs import export, report, ring, stats, trace, transfers
 from repro.obs.stats import (
     MaintenanceStats,
     ReadStats,
@@ -48,6 +53,8 @@ from repro.obs.stats import (
     TransferStats,
 )
 
+calls = ring.calls   # obs.calls(seqs): the ring's rows of those calls
+
 __all__ = [
     "MaintenanceStats",
     "ReadStats",
@@ -56,8 +63,10 @@ __all__ = [
     "SearchStats",
     "ServeStats",
     "TransferStats",
+    "calls",
     "export",
     "report",
+    "ring",
     "stats",
     "trace",
     "transfers",

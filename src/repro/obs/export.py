@@ -10,11 +10,16 @@ one named snapshot and serializes it two ways:
 - ``to_json(snap)``: the same snapshot as a JSON document (for BENCH
   rows, dashboards that ingest JSON, or plain logging).
 
-``ServeScheduler.metrics()`` is the live producer: it snapshots the
-decode loop's ``ServeStats``, the maintenance worker's drain counters
-and the pager's host-side op counters each call.  stdlib+numpy only —
-rendering a snapshot must never trace or sync anything beyond the
-``asdict`` host reads the stats classes already do.
+``program_totals()`` gives the process's running totals as two more
+groups: the per-call counters of every ``Index`` call (``index``,
+``repro.obs.ring.totals``) and the host totals of every span
+(``spans``, ``repro.obs.trace.totals``).  ``ServeScheduler.metrics()`` is
+the live producer: it snapshots the decode loop's ``ServeStats``, the
+maintenance worker's drain counters, the pager's host-side op counters
+and those totals each call.  Rendering a snapshot traces nothing; it
+reads the device only where its groups do: the ``asdict`` host reads of
+the stats classes, and ``program_totals``, which waits for the ``Index``
+calls recorded so far.
 """
 
 from __future__ import annotations
@@ -24,7 +29,7 @@ import numbers
 
 import numpy as np
 
-__all__ = ["snapshot", "to_prometheus", "to_json"]
+__all__ = ["program_totals", "snapshot", "to_prometheus", "to_json"]
 
 
 def _plain(v):
@@ -38,6 +43,20 @@ def _plain(v):
     if hasattr(v, "item"):  # 0-d jax array
         return v.item()
     return v
+
+
+def program_totals() -> dict:
+    """The running totals as ``snapshot`` groups (None while empty):
+    ``index`` (per-call counters by kind) and ``spans`` (``<span>_count``,
+    ``_total_s``, ``_max_s``; dots in span names become underscores) —
+    ``snapshot(**program_totals(), serve=...)``."""
+    from repro.obs import ring, trace
+
+    spans = {}
+    for name, row in sorted(trace.totals().items()):
+        for k, v in row.items():
+            spans[f"{name.replace('.', '_')}_{k}"] = v
+    return {"index": ring.totals() or None, "spans": spans or None}
 
 
 def snapshot(**groups) -> dict:

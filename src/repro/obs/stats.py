@@ -456,7 +456,7 @@ class ServeStats(NamedTuple):
 
     def record_probe(self, queries: int, hits: int) -> "ServeStats":
         """Fold one read-service ``probe`` call in (between decode steps
-        — bumps no step counter and writes no latency sample)."""
+        — advances no step counter and writes no latency sample)."""
         return self._replace(
             probe_queries=self.probe_queries + jnp.int32(queries),
             probe_hits=self.probe_hits + jnp.int32(hits))

@@ -1,177 +1,85 @@
-"""Trace spans: profiler annotations for every layer, off by default.
+"""Trace spans: one always-on host span helper on the profiler's clock.
 
-Gated by the ``REPRO_TRACE`` env var (unset/0 = every helper is a
-zero-cost ``nullcontext`` and traced programs lower byte-identically to
-an unannotated build).  With ``REPRO_TRACE=1``:
-
-- ``span(name)`` opens a host-side ``jax.profiler.TraceAnnotation`` *and*
-  a device-side ``jax.named_scope`` — use it around host-driven sections
-  (engine dispatch, a ServeEngine decode step).  Each completed span
-  additionally records a host wall-clock event for the Chrome-trace
-  writer below.
-- ``annotate(name)`` opens only the ``named_scope`` — use it *inside*
-  traced functions (``delta_walk`` rounds, maintenance phases, the router
-  dispatch), where a host annotation would stamp trace time, not run time.
-  Callers under an outer jit bake the gate at their trace time: flipping
-  ``REPRO_TRACE`` does not retrace already-cached programs.
-- ``capture(logdir)`` wraps a region in ``jax.profiler.start_trace`` /
-  ``stop_trace`` — the xprof/perfetto trace-dump hook the ROADMAP's
-  compiled-performance campaign points at a device run (also reachable as
+- ``span(name, **args)`` is a ``jax.profiler.TraceAnnotation(name,
+  **args)``: with a profiler session active the span lands on the host
+  plane of the xplane beside the device's ops, its keyword arguments as
+  integer stats on the event (``index.lookup`` carries ``seq`` and
+  ``lanes``); with none active it costs about a microsecond.  It adds
+  no device-side scope, so it never changes a compiled program.  Every
+  completed span also adds to a process-wide table of host totals per
+  name (``totals()``: count, total and max seconds) — set-up runs before
+  any profiler session starts, so the totals are its only record.  A
+  span entered while JAX is tracing (under ``jit``/``vmap``) records
+  nothing: there it would time the trace, not the run.
+- ``capture(logdir)`` / ``trace_run`` wrap a region in
+  ``jax.profiler.start_trace`` / ``stop_trace`` (also reachable as
   ``benchmarks/run.py --trace-dir``).
-- ``write_chrome_trace(path)`` dumps the recorded span events as a
-  Chrome-trace / perfetto JSON timeline (``{"traceEvents": [...]}``) —
-  host wall-clock only, so ``--trace-dir`` emits a browsable timeline
-  even where ``jax.profiler`` has no device backend to sample.
 
-Counters and the event ring are guarded by one module lock: the serve
-layer's maintenance worker is headed for its own thread (ROADMAP), and
-dict item updates from two threads would otherwise drop bumps.
+The totals table is guarded by one module lock: the serve layer's
+maintenance worker may close spans from its own thread.
 """
 
 from __future__ import annotations
 
 import contextlib
-import functools
-import json
-import os
 import threading
 import time
 
 import jax
 
-ENV = "REPRO_TRACE"
-
-# Host-side span counters (``REPRO_TRACE`` gated like the spans): every
-# entered span/annotate/bump increments its name.  A span opened at trace
-# time counts traces, one opened per call counts dispatches — which is the
-# point: `bump("delta_walk.dispatch")` in `ops.delta_walk` is the
-# kernel-dispatch counter behind the benchmarks' ``walk_launches`` column
-# (the per-ROUND launch count is device data — the driver's round counter
-# — because while_loop iterations never re-enter the host).
-_COUNTS: dict[str, int] = {}
-# Completed host-side span events for `write_chrome_trace`, bounded so a
-# long benchmark loop can't grow without limit (drops count under the
-# reserved name below instead of silently vanishing).
-_EVENTS: list[dict] = []
-_EVENT_CAP = 200_000
-_DROPPED = "trace.events_dropped"
 _LOCK = threading.Lock()
-_EPOCH = time.perf_counter()
+_TOTALS: dict[str, list] = {}      # name -> [count, total_s, max_s]
 
 
-def enabled() -> bool:
-    """True when ``REPRO_TRACE`` asks for spans (read at call time)."""
-    env = os.environ.get(ENV, "").strip()
-    return bool(env) and env.lower() not in ("0", "false", "no")
+def top_level() -> bool:
+    """False while JAX traces a function (jit, vmap, grad ...)."""
+    return jax.core.trace_ctx.is_top_level()
 
 
-def bump(name: str, n: int = 1) -> None:
-    """Count an event under ``name`` (no-op unless ``REPRO_TRACE``)."""
-    if enabled():
+class _Span:
+    __slots__ = ("name", "ann", "t0")
+
+    def __init__(self, name: str, args: dict):
+        self.name = name
+        self.ann = jax.profiler.TraceAnnotation(name, **args)
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self.t0
+        self.ann.__exit__(*exc)
         with _LOCK:
-            _COUNTS[name] = _COUNTS.get(name, 0) + n
+            row = _TOTALS.get(self.name)
+            if row is None:
+                _TOTALS[self.name] = [1, dt, dt]
+            else:
+                row[0] += 1
+                row[1] += dt
+                row[2] = max(row[2], dt)
+        return False
 
 
-def counters() -> dict[str, int]:
-    """Snapshot of the span/event counters accumulated so far."""
-    with _LOCK:
-        return dict(_COUNTS)
-
-
-def reset_counters() -> None:
-    """Clear the counters — callers that reuse one process for many
-    measurement rows (``benchmarks/common.run_index``) reset between
-    rows so counts like ``walk_launches`` can't leak across.  The
-    chrome-trace event ring is deliberately untouched: a ``--trace-dir``
-    run wants the whole run's timeline (``reset_events`` exists for
-    callers that do want it cleared)."""
-    with _LOCK:
-        _COUNTS.clear()
-
-
-def reset_events() -> None:
-    with _LOCK:
-        _EVENTS.clear()
-
-
-def _record_event(name: str, t0: float, t1: float) -> None:
-    ev = {"name": name, "ph": "X", "pid": os.getpid(),
-          "tid": threading.get_ident(),
-          "ts": round((t0 - _EPOCH) * 1e6, 3),
-          "dur": round((t1 - t0) * 1e6, 3)}
-    with _LOCK:
-        if len(_EVENTS) < _EVENT_CAP:
-            _EVENTS.append(ev)
-        else:
-            _COUNTS[_DROPPED] = _COUNTS.get(_DROPPED, 0) + 1
-
-
-def events() -> list[dict]:
-    """Snapshot of the recorded Chrome-trace span events."""
-    with _LOCK:
-        return [dict(e) for e in _EVENTS]
-
-
-def write_chrome_trace(path: str) -> int:
-    """Write the recorded span events as Chrome-trace JSON (open in
-    ``chrome://tracing`` or https://ui.perfetto.dev).  Returns the event
-    count written.  Unconditional like ``capture`` — asking for the file
-    is the opt-in — but only spans entered under ``REPRO_TRACE=1``
-    recorded anything."""
-    evs = events()
-    with open(path, "w") as f:
-        json.dump({"traceEvents": evs, "displayTimeUnit": "ms"}, f)
-    return len(evs)
-
-
-def annotate(name: str):
-    """Device-side scope: names the ops traced under it in HLO/xprof.
-    Safe anywhere (host or trace time); nullcontext when disabled."""
-    if not enabled():
+def span(name: str, **args):
+    """Host span ``name`` with integer ``args`` as profiler stats."""
+    if not top_level():
         return contextlib.nullcontext()
-    bump(name)
-    return jax.named_scope(name)
+    return _Span(name, args)
 
 
-@contextlib.contextmanager
-def _timed_span(name: str):
-    with contextlib.ExitStack() as stack:
-        stack.enter_context(jax.profiler.TraceAnnotation(name))
-        stack.enter_context(jax.named_scope(name))
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            _record_event(name, t0, time.perf_counter())
-
-
-def span(name: str):
-    """Host wall-clock span + device scope; nullcontext when disabled."""
-    if not enabled():
-        return contextlib.nullcontext()
-    bump(name)
-    return _timed_span(name)
-
-
-def traced(name: str):
-    """Decorator form of ``span`` (host-driven functions)."""
-
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            with span(name):
-                return fn(*args, **kwargs)
-
-        return wrapper
-
-    return deco
+def totals() -> dict[str, dict]:
+    """{span name: {"count", "total_s", "max_s"}} over the process."""
+    with _LOCK:
+        return {k: {"count": c, "total_s": t, "max_s": m}
+                for k, (c, t, m) in _TOTALS.items()}
 
 
 @contextlib.contextmanager
 def capture(logdir: str):
     """Dump an xprof/perfetto trace of the enclosed region to ``logdir``
-    (view with xprof / tensorboard-profile / perfetto).  Unconditional —
-    asking for a trace dump *is* the opt-in, no ``REPRO_TRACE`` needed."""
+    (view with xprof / tensorboard-profile / perfetto)."""
     jax.profiler.start_trace(logdir)
     try:
         yield logdir

@@ -59,7 +59,7 @@ class RequestQueue:
     # ---- arrival / departure ----
 
     def submit(self, req: ServeRequest) -> bool:
-        """Enqueue; False (and ``rejected`` bumps) when admission control
+        """Enqueue; False (and ``rejected`` grows by one) when admission control
         bounds the FIFO and it is full."""
         if self.max_waiting and len(self.waiting) >= self.max_waiting:
             self.rejected += 1

@@ -47,6 +47,7 @@ import numpy as np
 from repro.api import Index
 from repro.distributed import forest as DF
 from repro.models.config import ModelConfig
+from repro.obs import ring as OR
 from repro.obs import trace as OT
 from repro.obs.stats import ScanStats, ServeStats
 from repro.serve import decode as D
@@ -301,11 +302,11 @@ class ServeScheduler:
         combined update lands.
 
         Returns ``{seq_id: np.ndarray of page ids in block order}``
-        (empty array for unmapped sequences).  Folds one ``ScanStats``
-        sample into ``self.scan_obs`` (exported by ``metrics()``)."""
+        (empty array for unmapped sequences).  Folds the call's
+        ``repro.obs`` ring row into ``self.scan_obs`` (exported by
+        ``metrics()``)."""
         pg = self.pager
         ix = pg.index
-        ix._require("range_scan", ix.spec.backend.scan)
         if max_items is None:
             max_items = pg.cfg.max_blocks
         sids = np.asarray(seq_ids, np.int64)
@@ -318,12 +319,14 @@ class ServeScheduler:
                                                 pg.cfg.max_blocks - 1)),
                           jnp.int32)
         with OT.span("serve.scan"):
-            _, pages, n, hops, more = ix.spec.backend.scan(
-                ix.spec.cfg, ix.state, starts, his, max_items)
-        pg.stats["searches"] += len(sids)
-        pg.stats["hops"] += int(np.asarray(hops).sum())
-        self.scan_obs = self.scan_obs.merge(ScanStats.of(n, hops, more))
+            _, pages, n, _, _ = ix.scan(starts, his, max_items)
         pages, n = np.asarray(pages), np.asarray(n)
+        row = OR.calls([OR.last_seq()])[0]
+        pg.stats["searches"] += len(sids)
+        pg.stats["hops"] += int(row["hops_sum"])
+        self.scan_obs = self.scan_obs.merge(ScanStats(
+            scans=jnp.int32(1),
+            **{f: jnp.int32(row[f]) for f in ScanStats._fields[1:]}))
         return {int(s): pages[i, : n[i]] for i, s in enumerate(sids)}
 
     # ---------------------------------------------------------- metrics ---
@@ -334,14 +337,15 @@ class ServeScheduler:
         maintenance worker's drain counters, the pager's host-side op
         counters, the read path's last ``ReadStats`` legs (search /
         router / measured transfers — present when the underlying index
-        was built with ``collect_stats``), and any ``REPRO_TRACE`` span
-        counters.  ``fmt``: "dict" (nested plain dict), "prometheus"
+        was built with ``collect_stats``), and the process's running
+        totals of every ``Index`` call and span (groups ``index`` and
+        ``spans``).  ``fmt``: "dict" (nested plain dict), "prometheus"
         (text exposition), or "json"."""
         from repro.obs import export as OX
 
         rs = self.pager.last_read_stats
-        tr = OT.counters()
         snap = OX.snapshot(
+            **OX.program_totals(),
             serve=self.obs,
             scan=self.scan_obs,
             maintenance=self.worker.stats(),
@@ -349,7 +353,6 @@ class ServeScheduler:
             search=rs.search if rs is not None else None,
             router=rs.router if rs is not None else None,
             transfers=rs.transfers if rs is not None else None,
-            trace=tr or None,
         )
         if fmt == "prometheus":
             return OX.to_prometheus(snap)

@@ -192,7 +192,7 @@ class DeltaPager:
 
     def stage_allocate(self, seq_id: int, n_blocks: int) -> list[int]:
         """``allocate`` split in two: page accounting now (free-list pop,
-        block-count bump — the scheduler needs the page ids to scatter
+        block-count increment — the scheduler needs the page ids to scatter
         prefill K/V), index inserts staged for the step's one combined
         ``apply_staged`` batch."""
         start = self.seq_blocks.get(seq_id, 0)

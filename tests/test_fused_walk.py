@@ -204,23 +204,6 @@ def test_autotune_cache_roundtrip(tmp_path, monkeypatch):
     assert autotune.save_cache({key: 64}) is None  # no cache = no-op
 
 
-def test_walk_dispatch_counter(monkeypatch):
-    """REPRO_TRACE=1 makes every delta_walk dispatch count under
-    `delta_walk.dispatch` (the host half of walk_launches telemetry)."""
-    from repro.obs import trace as TR
-
-    cfg, t, q = _churned_tree(4, 512, 800, seed=5, n_updates=32)
-    monkeypatch.setenv("REPRO_TRACE", "1")
-    TR.reset_counters()
-    delta_walk(t.value, t.child, t.root, q, height=4, q_tile=128)
-    delta_walk(t.value, t.child, t.root, q, height=4, q_tile=128)
-    assert TR.counters().get("delta_walk.dispatch") == 2
-    TR.reset_counters()
-    monkeypatch.setenv("REPRO_TRACE", "0")
-    delta_walk(t.value, t.child, t.root, q, height=4, q_tile=128)
-    assert TR.counters() == {}
-
-
 def test_fused_walk_map_mode_int64_subprocess():
     """Map-mode (int64 packed rows) fused walk parity — x64 subprocess:
     fused vs per-round vs the legacy search contract on packed queries."""

@@ -1,4 +1,5 @@
-"""repro.obs — counter pytrees, trace spans, report CLI (DESIGN.md §9).
+"""repro.obs — counter pytrees, spans and per-call counters, report CLI
+(DESIGN.md §9).
 
 The two contracts everything else leans on:
 
@@ -182,11 +183,10 @@ def test_buffer_hits_under_deferred_maintenance():
     assert int(stats.search.buffer_hits) == expected
 
 
-def test_collect_stats_false_hlo_identical(monkeypatch):
+def test_collect_stats_false_hlo_identical():
     """The static gate's whole contract: the disabled dispatch lowers
     byte-identically to the bare engine-hook composition (= the pre-obs
     read path), and the enabled one doesn't."""
-    monkeypatch.delenv(trace.ENV, raising=False)  # spans would rename scopes
     cfg = TreeConfig(height=4, max_dnodes=64, buf_cap=8)
     t = DT.bulk_build(cfg, KEYS[:20])
     q = jnp.asarray(KEYS[:8], jnp.int32)
@@ -323,84 +323,6 @@ print("lanes", int(np.asarray(sa.router.lanes).sum()), "of", q.shape[0])
 # ----------------------------------------------------------------- trace ---
 
 
-def test_trace_gating(monkeypatch):
-    import contextlib
-
-    monkeypatch.delenv(trace.ENV, raising=False)
-    assert not trace.enabled()
-    assert isinstance(trace.annotate("x"), contextlib.nullcontext)
-    assert isinstance(trace.span("x"), contextlib.nullcontext)
-    monkeypatch.setenv(trace.ENV, "1")
-    assert trace.enabled()
-    with trace.span("obs-test"), trace.annotate("obs-test-inner"):
-        assert int(jnp.int32(1) + 1) == 2
-    monkeypatch.setenv(trace.ENV, "0")
-    assert not trace.enabled()
-
-
-def test_trace_span_events_and_chrome_trace(tmp_path, monkeypatch):
-    monkeypatch.setenv(trace.ENV, "1")
-    trace.reset_counters()
-    trace.reset_events()
-    with trace.span("obs-evt"):
-        pass
-    with trace.span("obs-evt"):
-        pass
-    evs = trace.events()
-    assert len(evs) == 2
-    assert all(e["name"] == "obs-evt" and e["ph"] == "X" and
-               e["dur"] >= 0 for e in evs)
-    # counters reset keeps the event ring (whole-run --trace-dir
-    # timelines survive per-row counter resets); reset_events clears it
-    assert trace.counters()["obs-evt"] == 2
-    trace.reset_counters()
-    assert trace.counters() == {}
-    assert len(trace.events()) == 2
-
-    path = tmp_path / "chrome_trace.json"
-    n = trace.write_chrome_trace(str(path))
-    assert n == 2
-    doc = json.loads(path.read_text())
-    assert [e["name"] for e in doc["traceEvents"]] == ["obs-evt", "obs-evt"]
-
-    trace.reset_events()
-    assert trace.events() == []
-
-
-def test_trace_counters_thread_safe(monkeypatch):
-    """Concurrent bumps from many threads must not drop counts (the
-    module lock satellite: dict updates raced before)."""
-    import threading
-
-    monkeypatch.setenv(trace.ENV, "1")
-    trace.reset_counters()
-    N, T = 2000, 8
-
-    def work():
-        for _ in range(N):
-            trace.bump("obs-race")
-
-    threads = [threading.Thread(target=work) for _ in range(T)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    assert trace.counters()["obs-race"] == N * T
-    trace.reset_counters()
-
-
-def test_run_index_resets_counters_between_rows(monkeypatch):
-    """benchmarks.common.run_index resets the span counters per row so
-    columns like walk_launches can't leak across measurement rows."""
-    monkeypatch.setenv(trace.ENV, "1")
-    trace.bump("leaked.counter", 41)
-    from benchmarks.common import run_index
-
-    run_index("sorted_array", KEYS, key_hi=500, update_pct=0.0,
-              batch=8, total_ops=16)
-    assert "leaked.counter" not in trace.counters()
-
-
 def test_trace_capture_smoke(tmp_path):
     try:
         out = trace.trace_run(
@@ -409,6 +331,278 @@ def test_trace_capture_smoke(tmp_path):
         pytest.skip(f"profiler unavailable here: {e}")
     assert int(out) == 56
     assert any(tmp_path.rglob("*"))             # something was dumped
+
+
+# --------------------------------------------- program spans and counters ---
+
+# one concrete call each of lookup, successor_k and update under a CPU
+# profiler capture; every call's index.<method> event must carry a seq
+# whose ring row equals, bit for bit, what the call itself returned
+_JOIN = """
+import tempfile
+import numpy as np, jax, jax.numpy as jnp
+from jax.profiler import ProfileData
+from repro.api import OpBatch, make_index
+from repro.obs import calls, ring, trace
+
+keys = np.arange(10, 4000, 7, dtype=np.int64)
+kw = dict(height=4, max_dnodes=512, payload_bits=32)
+if backend == "forest":
+    kw["num_shards"] = 1
+ix = make_index(backend, initial=keys, payloads=keys * 3, **kw)
+q = jnp.asarray(np.arange(5, 4100, 9), jnp.int32)    # hits and misses
+new = jnp.asarray(np.arange(4001, 4400, 2), jnp.int32)
+
+
+def free_top(state):
+    ft = getattr(state, "free_top", None)
+    return int(jnp.sum(state.trees.free_top if ft is None else ft))
+
+
+want = []
+logdir = tempfile.mkdtemp()
+with trace.capture(logdir):
+    found, pay, hops = ix.lookup(q)
+    want.append(("index.lookup", dict(
+        lanes=q.shape[0], hops_sum=int(hops.sum()),
+        hops_max=int(hops.max()))))
+    _, _, n, hops, more = ix.successor_k(q[:64], 8)
+    want.append(("index.successor_k", dict(
+        lanes=64, hops_sum=int(hops.sum()), hops_max=int(hops.max()),
+        emitted=int(n.sum()), truncated=int(more.sum()))))
+    ix, res, st = ix.update(OpBatch.inserts(new, new * 5))
+    w = {f: int(getattr(st, f)) for f in st._fields}
+    w.update(lanes=new.shape[0], free_top=free_top(ix.state))
+    want.append(("index.update", w))
+assert want[2][1]["expands"] + want[2][1]["rebuilds"] > 0, want
+
+(path,) = __import__("glob").glob(logdir + "/plugins/profile/*/*.xplane.pb")
+evs = sorted((ev.start_ns, ev.name, dict(ev.stats)["seq"])
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("index."))
+assert [e[1] for e in evs] == [w[0] for w in want], evs
+rows = calls([e[2] for e in evs])
+for (_, name, seq), row, (_, exp) in zip(evs, rows, want):
+    full = dict.fromkeys(ring.COLUMNS, 0)
+    full.update(exp, seq=seq, kind=ring.KINDS.index(name[len("index."):]))
+    assert {c: int(row[c]) for c in ring.COLUMNS} == full, (name, row, full)
+print("JOINED", len(evs))
+"""
+
+
+@pytest.mark.parametrize("backend", ["deltatree", "forest"])
+def test_index_calls_join_their_ring_rows_bit_exact(backend):
+    """Map mode (x64 subprocess), a one-shard forest as well: each
+    Index call yields one index.<method> event whose seq joins to a ring
+    row equal to the call's own hops / n / more / MaintenanceStats /
+    free_top."""
+    from tests._subproc import run_py
+
+    out = run_py(f"backend = {backend!r}\n" + _JOIN, x64=True)
+    assert "JOINED 3" in out
+
+
+def test_ring_wraps_and_keeps_the_last_rows():
+    from repro.obs import calls, ring
+
+    extra = 37
+    seqs = []
+    for i in range(ring.ROWS + extra):
+        seq = ring.next_seq()
+        ring.record("search", seq, 3,
+                    hops=jnp.asarray([i % 5, 1, 2], jnp.int32))
+        seqs.append(seq)
+    rows = calls(seqs)
+    assert (rows["seq"][:extra] == -1).all()        # overwritten
+    held = rows[extra:]
+    assert held["seq"].tolist() == seqs[extra:]
+    i = np.arange(extra, ring.ROWS + extra)
+    assert held["hops_sum"].tolist() == (i % 5 + 3).tolist()
+    assert held["hops_max"].tolist() == np.maximum(i % 5, 2).tolist()
+    assert (held["lanes"] == 3).all()
+    assert ring.ROWS >= 4096
+    assert ring.ROWS * len(ring.COLUMNS) * 4 <= 256 * 1024
+
+
+def test_ring_totals_are_the_sums_of_the_rows():
+    from repro.api import OpBatch
+    from repro.obs import calls, ring
+
+    ix = make_index("deltatree", initial=KEYS, height=4, max_dnodes=256,
+                    buf_cap=8, maintenance="deferred")
+    before = ring.totals()
+    seqs = []
+    q = _queries()
+    for i in range(3):
+        ix.search(q)
+        seqs.append(ring.last_seq())
+        ix.successor_k(q, 4)
+        seqs.append(ring.last_seq())
+        ix, _, _ = ix.update(OpBatch.inserts(
+            jnp.asarray(np.arange(401 + 40 * i, 441 + 40 * i, 3), jnp.int32)))
+        seqs.append(ring.last_seq())
+    ix, _ = ix.flush()
+    seqs.append(ring.last_seq())
+    after = ring.totals()
+    rows = calls(seqs)
+    assert (rows["seq"] == seqs).all()
+    for k, kind in enumerate(ring.KINDS):
+        mine = rows[rows["kind"] == k]
+        got = {c: after.get(f"{kind}_{c}", 0) - before.get(f"{kind}_{c}", 0)
+               for c in ("calls",) + ring.SUMMED}
+        want = {c: int(mine[c].sum()) for c in ring.SUMMED}
+        want["calls"] = len(mine)
+        assert got == want, kind
+    assert after["update_calls"] - before.get("update_calls", 0) == 3
+    assert after["flush_rounds"] > before.get("flush_rounds", 0)
+
+
+def test_ring_reads_race_records_from_another_thread():
+    """Readers and a recording thread interleave: every record donates
+    the ring and the totals, and no read may touch a donated buffer."""
+    import threading
+
+    from repro.obs import calls, ring
+
+    n = 300
+    seqs, done = [], threading.Event()
+
+    def writer():
+        for _ in range(n):
+            seq = ring.next_seq()
+            ring.record("search", seq, 2,
+                        hops=jnp.asarray([1, 2], jnp.int32))
+            seqs.append(seq)
+        done.set()
+
+    t = threading.Thread(target=writer)
+    t.start()
+    reads = 0
+    while not done.is_set() or reads == 0:
+        if seqs:
+            s = seqs[-1]       # recorded before it was appended
+            assert calls([s])[0]["seq"] == s
+        assert ring.totals().get("search_calls", 0) >= 0
+        reads += 1
+    t.join()
+    rows = calls(seqs)
+    assert rows["seq"].tolist() == seqs
+    assert (rows["hops_sum"] == 3).all() and (rows["hops_max"] == 2).all()
+
+
+def test_programs_lower_alike_with_the_profiler_on_and_off(tmp_path):
+    """lookup_jit, successor_k_jit and update_batch (map mode, x64
+    subprocess) lower to identical text with a profiler session active
+    and without one; Index calls under jit add nothing to the program."""
+    from tests._subproc import run_py
+
+    out = run_py(f"""
+import numpy as np, jax, jax.numpy as jnp
+from repro.api import make_index
+from repro.core import deltatree as DT
+from repro.obs import trace
+
+keys = np.arange(10, 4000, 7, dtype=np.int64)
+ix = make_index("deltatree", initial=keys, payloads=keys * 3, height=4,
+                max_dnodes=512, payload_bits=32)
+cfg, t = ix.cfg, ix.state
+q = jnp.asarray(np.arange(5, 4100, 9), jnp.int32)
+kinds = jnp.ones(64, jnp.int32)
+
+def lowered():
+    return [DT.lookup_jit.lower(cfg, t, q).as_text(),
+            DT.successor_k_jit.lower(cfg, t, q, 8).as_text(),
+            DT.update_batch.lower(cfg, t, kinds, q[:64], q[:64]).as_text()]
+
+off = lowered()
+with trace.capture({str(tmp_path)!r}):
+    on = lowered()
+assert on == off
+via_index = jax.jit(lambda ix, q: ix.lookup(q)).lower(ix, q).as_text()
+bare = jax.jit(lambda t, q: DT.lookup_jit(cfg, t, q)).lower(t, q).as_text()
+assert via_index == bare
+print("SAME HLO", sum(map(len, off)))
+""", x64=True)
+    assert "SAME HLO" in out
+
+
+def test_span_under_jit_tracing_records_nothing():
+    from repro.obs import ring
+
+    ix = make_index("deltatree", initial=KEYS, height=4, max_dnodes=256)
+    q = _queries()
+    name = "obs.test.traced"
+    before = ring.next_seq()
+
+    @jax.jit
+    def f(x):
+        with trace.span(name, lanes=1):
+            found, hops = ix.search(x)
+        return hops
+
+    f(q).block_until_ready()
+    f(q + 1).block_until_ready()
+    assert ring.next_seq() == before + 1          # no Index call numbered
+    assert name not in trace.totals()
+    with trace.span(name, lanes=1):
+        pass
+    assert trace.totals()[name]["count"] == 1
+
+
+def test_span_totals_count_time_and_max_across_threads():
+    import threading
+    import time
+
+    name = "obs.test.threads"
+    n, t = 300, 6
+
+    def work():
+        for _ in range(n):
+            with trace.span(name):
+                pass
+
+    threads = [threading.Thread(target=work) for _ in range(t)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join()
+    with trace.span(name):
+        time.sleep(0.02)
+    tot = trace.totals()[name]
+    assert tot["count"] == n * t + 1
+    assert tot["max_s"] >= 0.02
+    assert tot["max_s"] <= tot["total_s"]
+
+
+def test_make_index_times_the_build_and_its_parts():
+    before = trace.totals()
+    make_index("deltatree", initial=KEYS, height=4, max_dnodes=256)
+    after = trace.totals()
+    parts = ("index.build.sort", "index.build.layout", "index.build.upload")
+    for name in ("index.build",) + parts:
+        assert (after[name]["count"]
+                - before.get(name, {"count": 0})["count"]) == 1, name
+    spent = {k: after[k]["total_s"] - before.get(k, {"total_s": 0.0})[
+        "total_s"] for k in ("index.build",) + parts}
+    assert 0 < sum(spent[p] for p in parts) <= spent["index.build"]
+
+
+def test_export_snapshot_program_totals():
+    from repro.obs import export
+
+    ix = make_index("deltatree", initial=KEYS, height=4, max_dnodes=256)
+    ix.search(_queries())
+    snap = export.snapshot(**export.program_totals(),
+                           pager={"searches": 1})
+    assert snap["index"]["search_calls"] >= 1
+    assert snap["index"]["search_lanes"] >= _queries().shape[0]
+    assert snap["spans"]["index_search_count"] >= 1
+    assert snap["pager"] == {"searches": 1}
+    prom = export.to_prometheus(snap)
+    assert "# TYPE repro_index_search_calls gauge" in prom
+    assert "repro_spans_index_build_total_s" in prom
+    assert "index" not in export.snapshot(pager={"searches": 1})
 
 
 # ---------------------------------------------------------------- report ---

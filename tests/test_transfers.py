@@ -207,7 +207,6 @@ def test_compiled_fused_hlo_identity_subprocess():
     out = run_py("""
 import os
 os.environ["REPRO_PALLAS_INTERPRET"] = "0"
-os.environ.pop("REPRO_TRACE", None)   # spans would rename scopes
 import re
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import deltatree as DT

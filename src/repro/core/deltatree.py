@@ -1299,107 +1299,95 @@ def successor_jit(cfg: TreeConfig, t: DeltaTree, keys: jax.Array):
     return successor_batch(cfg, t, keys)
 
 
-def scan_one(cfg: TreeConfig, t: DeltaTree, start, hi, max_out: int,
-             chase_slack: int = 16):
-    """Scalar reference for the emit-cursor scan: emit up to ``max_out``
+def scan_one(cfg: TreeConfig, t: DeltaTree, start, hi, max_out: int):
+    """Scalar reference for the leaf-run scan: emit up to ``max_out``
     live *leaf* items with ``start < key <= hi`` in key order (wait-free
     read; overflow buffers are merged by the engine dispatch, where I5'
     correctness lives).
 
-    The pass structure mirrors the lockstep scan kernel exactly
-    (`kernels.ref.ref_delta_scan_fused`): alternate a FIND pass (the
-    `successor_one` candidate walk, leaf fold included) with a VERIFY
-    pass (exact walk for the candidate key — candidate routers may be
-    tombstones; dead candidates are chased without emitting).  ``hops``
-    counts ΔNode visits across every pass — bit-identical to the
+    One ΔNode row per round, the pass logic of the lockstep scan kernel
+    (`kernels.ref.ref_delta_scan_fused` documents it): descend the row
+    for the query, emit the live in-band key-leaves from the landing up
+    to the first marker, then hop to that marker's child or, at the end
+    of the ΔNode, restart at the root for the region bound ``U``.  Keys
+    are placed by a scatter on their running count, not by the kernel's
+    compaction.  ``hops`` counts the rounds — bit-identical to the
     lockstep accounting.
 
     Returns (out (max_out,) packed ascending with ``cfg.route_left``
-    padding, n int32, hops int32, more bool); ``more`` means the buffer
-    filled with live items remaining — resume from ``key_of(out[n-1])``.
+    padding, n int32, hops int32, more bool); ``more`` means a live
+    in-band item was left out — resume from ``key_of(out[n-1])``.
     """
-    pos = _pos(cfg)
-    bottom0 = cfg.bottom0
+    from repro.kernels.ops import scan_round_cap
+
+    h, ub = cfg.height, cfg.ub
+    tab = layout.inorder_tables(h)
+    storage, left = jnp.asarray(tab["storage"]), jnp.asarray(tab["left"])
+    bottom = jnp.asarray(tab["bottom"])
+    rank = jnp.arange(ub, dtype=jnp.int32)
     big = cfg.route_left
     pm = jnp.asarray(cfg.pmask, cfg.vdtype)
     start_q = cfg.qpack(jnp.asarray(start, jnp.int32))
     hi_q = cfg.qpack(jnp.asarray(hi, jnp.int32))
-    max_passes = 2 * (max_out + chase_slack)
+    max_rounds = scan_round_cap(h, cfg.max_dnodes)
 
-    def walk_pass(q):
-        # one full root-to-leaf walk: (cand fold, leaf_val, leaf_live,
-        # ΔNodes visited) — the eager-descent twin of one kernel pass
-        def cond(s):
-            return ~s[2]
+    def cond(s):
+        return (~s["done"]) & (s["rounds"] < max_rounds)
 
-        def body(s):
-            dn, b, _, cand, hops = s
-            router = t.value[dn, pos[b]]
-            at_bottom = b >= bottom0
-            left_val = jnp.where(
-                at_bottom, jnp.zeros((), cfg.vdtype),
-                t.value[dn, pos[jnp.minimum(2 * b, 2 * bottom0 - 1)]],
-            )
-            internal = (~at_bottom) & (left_val != EMPTY)
-            go_left = internal & (q < router)
-            cand = jnp.where(go_left & (router < cand), router, cand)
-            slot = jnp.where(at_bottom, b - bottom0, 0)
-            ch = jnp.where(at_bottom, t.child[dn, slot], NONE)
-            hop = at_bottom & (ch >= 0)
-            nb = jnp.where(internal, 2 * b + (q >= router).astype(jnp.int32), b)
-            nb = jnp.where(hop, jnp.int32(1), nb)
-            ndn = jnp.where(hop, ch, dn)
-            done = (~internal) & (~hop)
-            return ndn, nb, done, cand, hops + hop.astype(jnp.int32)
-
-        dn, b, _, cand, hops = jax.lax.while_loop(
-            cond, body,
-            (jnp.int32(t.root), jnp.int32(1), jnp.bool_(False), big,
-             jnp.int32(1)))
-        leaf_val = t.value[dn, pos[b]]
-        leaf_live = (leaf_val != EMPTY) & ~t.mark[dn, pos[b]]
-        return cand, leaf_val, leaf_live, hops
-
-    def outer_cond(s):
-        return (~s["done"]) & (s["passes"] < max_passes)
-
-    def outer_body(s):
-        cand, lv, live, h1 = walk_pass(s["cursor"])
-        leaf_fold = live & (lv > s["cursor"]) & (lv < cand)
-        cand = jnp.where(leaf_fold, lv, cand)
-        none = (cand == big) | (cand > hi_q)
-        pending = cand | pm
-
-        def verify(_):
-            _, lv2, live2, h2 = walk_pass(pending)
-            hit = live2 & ((lv2 | pm) == pending)
-            return lv2, hit, h2
-
-        lv2, hit, h2 = jax.lax.cond(
-            none,
-            lambda _: (jnp.zeros((), cfg.vdtype), jnp.bool_(False),
-                       jnp.int32(0)),
-            verify, None)
-        can_emit = s["n"] < max_out
-        emit = (~none) & hit & can_emit
-        full = (~none) & hit & ~can_emit
-        upd = s["out"].at[jnp.minimum(s["n"], max_out - 1)].set(lv2)
+    def body(s):
+        x = t.value[s["dn"]][storage]                 # the row in in-order
+        dead = t.mark[s["dn"]][storage]
+        ch = t.child[s["dn"]][rank // 2]              # bottom rank 2j -> j
+        r = jnp.int32(2 ** (h - 1) - 1)
+        land = r
+        for d in range(h):
+            land = jnp.where(x[r] != EMPTY, r, land)
+            if d < h - 1:
+                off = 2 ** (h - 2 - d)
+                r = jnp.where(s["q"] >= x[r], r + off, r - off)
+        occ = x != EMPTY
+        marker = bottom & occ & (ch >= 0)
+        leaf = occ & ~(~bottom & occ[left]) & ~marker & (x != big)
+        after = rank >= land
+        stop = jnp.min(jnp.where(marker & after, rank, ub))
+        run = leaf & after & (rank < stop)
+        emit = run & ~dead & (x > start_q) & (x <= hi_q)
+        count = jnp.sum(emit, dtype=jnp.int32)
+        at = s["n"] + jnp.cumsum(emit.astype(jnp.int32)) - 1
+        out = s["out"].at[jnp.where(emit, at, max_out)].set(x, mode="drop")
+        full = count > max_out - s["n"]
+        past_hi = jnp.any(run & (x > hi_q))
+        hop = stop < ub
+        # left-turn routers above the marker bound the region after it
+        fold = s["bound"]
+        bm = cfg.bottom0 + stop // 2                  # the marker's BFS index
+        for d in range(h - 1):
+            a = bm >> (h - 1 - d)
+            v = t.value[s["dn"], _pos(cfg)[a]]
+            turn_left = ((bm >> (h - 2 - d)) & 1) == 0
+            fold = jnp.where(turn_left & (v < fold), v, fold)
+        spent = (s["bound"] == big) | (s["bound"] > hi_q)
+        done = full | past_hi | (~hop & spent)
+        restart = ~done & ~hop
         return dict(
-            cursor=jnp.where(emit | ((~none) & ~hit), pending, s["cursor"]),
-            out=jnp.where(emit, upd, s["out"]),
-            n=s["n"] + emit.astype(jnp.int32),
-            hops=s["hops"] + h1 + h2,
-            more=s["more"] | full,
-            done=s["done"] | none | full,
-            passes=s["passes"] + 1,
+            dn=jnp.where(done, s["dn"],
+                         jnp.where(hop, t.child[s["dn"], stop // 2], t.root)),
+            q=jnp.where(restart, s["bound"] | pm, s["q"]),
+            bound=jnp.where(restart, big, jnp.where(hop, fold, s["bound"])),
+            out=out,
+            n=jnp.minimum(s["n"] + count, max_out),
+            hops=s["hops"] + 1,
+            more=full,
+            done=done,
+            rounds=s["rounds"] + 1,
         )
 
-    init = dict(cursor=start_q,
+    init = dict(dn=jnp.asarray(t.root, jnp.int32), q=start_q,
+                bound=jnp.asarray(big, cfg.vdtype),
                 out=jnp.full((max_out,), big, cfg.vdtype),
-                n=jnp.int32(0), hops=jnp.int32(0),
-                more=jnp.bool_(False), done=jnp.bool_(False),
-                passes=jnp.int32(0))
-    s = jax.lax.while_loop(outer_cond, outer_body, init)
+                n=jnp.int32(0), hops=jnp.int32(0), more=jnp.bool_(False),
+                done=jnp.bool_(False), rounds=jnp.int32(0))
+    s = jax.lax.while_loop(cond, body, init)
     return s["out"], s["n"], s["hops"], s["more"]
 
 

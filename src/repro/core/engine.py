@@ -88,7 +88,7 @@ class ForestBatch:
     successor: Callable[..., Any]
     make_view: Callable[..., Any] | None = None
     # scan: (cfg, trees, starts[S_loc], his[S_loc], max_out, *, view=None)
-    #       -> (out[S_loc, max_out], n, hops, more) — one emit-cursor lane
+    #       -> (out[S_loc, max_out], n, hops, more) — one leaf-run scan lane
     #       per co-resident shard over the fused view (each lane scans its
     #       own arena band), per-shard I5' buffered merge included; None
     #       means the forest falls back to the dense per-shard dispatch
@@ -537,10 +537,10 @@ def _lockstep_successor(cfg, t, keys: jax.Array, max_chase: int = 8):
 
 def _lockstep_scan(cfg, t, starts: jax.Array, his: jax.Array, max_out: int,
                    root=None):
-    """The emit-cursor scan frontier: ONE `delta_scan` dispatch for the
-    whole scan — every FIND/VERIFY pass of every lane inside a single
-    launch (`veb_scan_fused`, or its XLA mirror where Pallas cannot
-    lower).  ``root`` as in `_lockstep_walk`: per-lane seeds drive the
+    """The leaf-run scan frontier: ONE `delta_scan` dispatch for the
+    whole scan — every round of every lane (one ΔNode row read, its run
+    emitted) inside a single launch (`veb_scan_fused`, or its XLA mirror
+    where Pallas cannot lower).  ``root`` as in `_lockstep_walk`: per-lane seeds drive the
     fused multi-shard view, each lane scanning its own arena."""
     from repro.kernels import ops as OPS
 

@@ -74,6 +74,35 @@ def veb_inverse_table(h: int) -> np.ndarray:
     return np.asarray(veb_order(h), dtype=np.int32)
 
 
+@functools.lru_cache(maxsize=None)
+def inorder_tables(h: int) -> dict[str, np.ndarray]:
+    """Static in-order view of a height-``h`` ΔNode, for the scans.
+
+    In-order rank ``r`` (0-based) of BFS node (depth ``d``, row offset
+    ``j``) is ``(2j + 1) * 2**(h-1-d) - 1``: the root sits at rank
+    ``2**(h-1) - 1``, bottom slot ``j`` at rank ``2j``, and a depth-``d``
+    node's children at ``r -/+ 2**(h-2-d)``.  Returns arrays of shape
+    (2**h - 1,) indexed by rank:
+
+      - ``storage[r]``: vEB storage position of the node at rank r;
+      - ``left[r]``: rank of its left child (its own rank on the bottom
+        row, which has no children);
+      - ``bottom[r]``: whether it is a bottom-row position (even r).
+    """
+    n = 2**h - 1
+    pos = veb_pos_table(h)
+    storage = np.zeros(n, np.int32)
+    left = np.arange(n, dtype=np.int32)
+    for b in range(1, 2**h):
+        d = b.bit_length() - 1
+        r = (2 * (b - 2**d) + 1) * 2 ** (h - 1 - d) - 1
+        storage[r] = pos[b]
+        if d < h - 1:
+            left[r] = r - 2 ** (h - 2 - d)
+    bottom = np.arange(n) % 2 == 0
+    return {"storage": storage, "left": left, "bottom": bottom}
+
+
 def num_nodes(h: int) -> int:
     return 2**h - 1
 

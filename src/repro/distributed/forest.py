@@ -444,7 +444,7 @@ def scan_batch(fcfg: ForestConfig, f: Forest, starts: jax.Array,
     Returns the engine `scan` contract — (out (K, max_items) packed
     ascending with sentinel padding, n (K,), hops (K,), more (K,) bool).
     Unlike point reads, a range can span shards, so every lane is scanned
-    against every shard (one emit-cursor lane per (lane, shard) pair —
+    against every shard (one leaf-run scan lane per (lane, shard) pair —
     still ONE ``delta_scan`` dispatch under the fused frontier); shards
     partition the key space in split order, so the per-shard bands
     concatenate sorted and the first ``max_items`` of the union are the

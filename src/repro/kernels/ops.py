@@ -403,14 +403,16 @@ def _delta_walk(value, child, root, queries, *, height, q_tile, max_rounds,
             state["final_dn"][:k], state["hops"][:k], state["cand"][:k])
 
 
-def scan_round_cap(height: int, max_dnodes: int, max_out: int,
-                   chase_slack: int = 16) -> int:
-    """Trace-time round bound for the emit-cursor scan frontier: each
-    emitted item costs at most two full walk passes (FIND + VERIFY), each
-    bounded by `walk_round_cap`, plus slack passes for tombstone chases.
-    Generous by design — the in-kernel loop exits as soon as every lane
-    is done, so the cap only bounds the lowered loop."""
-    return walk_round_cap(height, max_dnodes) * 2 * (max_out + chase_slack)
+def scan_round_cap(height: int, max_dnodes: int) -> int:
+    """Trace-time round bound for the leaf-run scan frontier, sound for
+    any band and any share of tombstones: a lane finishes each ΔNode at
+    most once, and then descends from its root again (at most
+    `walk_round_cap` rounds), so it makes at most ``max_dnodes + 1``
+    descents, plus one hop into each child it enters from a run.  The
+    in-kernel loop exits as soon as every lane is done, so the cap only
+    bounds the lowered loop."""
+    cap = (max_dnodes + 1) * (walk_round_cap(height, max_dnodes) + 1)
+    return min(cap, 2**31 - 1)
 
 
 def delta_scan(value: jax.Array, mark: jax.Array, child: jax.Array,
@@ -418,37 +420,45 @@ def delta_scan(value: jax.Array, mark: jax.Array, child: jax.Array,
                height: int, max_out: int, pmask: int = 0,
                q_tile: int | None = None, max_rounds: int | None = None,
                interpret: bool | None = None):
-    """Ordered range/successor-k scan in lockstep passes over the lane
-    frontier — the emit-cursor variant of `delta_walk` (ONE dispatch for
-    the whole scan, every pass inside a single launch).
+    """Ordered range/successor-k scan in lockstep rounds over the lane
+    frontier — the leaf-run variant of `delta_walk` (ONE dispatch for
+    the whole scan, every round inside a single launch).
 
     value/mark/child are unpadded arena arrays; ``starts``/``his`` are
     *packed* ``qpack`` bounds per lane (start exclusive, hi inclusive in
     key space).  ``root`` is scalar or per-lane (K,) seeds — the
     multi-root form drives one fused scan across concatenated shard
     arenas (`veb_search.fuse_arenas`), each lane emitting its owner
-    shard's band.  A lane whose start equals ``walk_big(dtype)`` is born
-    done (the router's pad-lane contract).
+    shard's band and restarting at its own seed.  A lane whose start
+    equals ``walk_big(dtype)`` is born done (the router's pad-lane
+    contract).
+
+    Each round a lane reads its ΔNode's row once, descends it, and emits
+    the whole run of live in-band key-leaves from its landing up to the
+    first marker; then it hops to that marker's child, or restarts at
+    its root for the next region — O(log_B N + k/B) rows per scan, not a
+    root walk per key (pass logic documented on the mirror).
 
     Single-launch discipline matches `delta_walk`: the persistent Pallas
     kernel `veb_search.veb_scan_fused` where it lowers (interpret mode
     anywhere; compiled on TPU for int32 arenas within the VMEM budget),
     else the XLA-compiled mirror `ref.ref_delta_scan_fused` — both
-    bit-identical, pass logic documented on the mirror.
+    bit-identical.
 
     Returns per lane (pad width sliced off):
       out:  (K, max_out) packed live *leaf* values in (start, hi], key
             ascending, ``walk_big`` padding (overflow buffers are merged
             by the engine dispatch — I5' correctness lives there)
       n:    emitted count
-      hops: ΔNode visits across every pass (`delta_walk` accounting)
-      more: bool — buffer filled with live items remaining; resume from
+      hops: ΔNode rows read, the rounds the lane stayed active
+            (`delta_walk` accounting)
+      more: bool — a live in-band item was left out; resume from
             ``key_of(out[lane, n-1])``
     """
     q_tile = _resolve_q_tile(
         q_tile, height, 0 if value.dtype == jnp.int32 else 1)
     if max_rounds is None:
-        max_rounds = scan_round_cap(height, value.shape[0], max_out)
+        max_rounds = scan_round_cap(height, value.shape[0])
     interpret = _resolve_interpret(interpret)
     return _delta_scan(value, mark, child, root, starts, his,
                        height=height, max_out=max_out, pmask=pmask,

@@ -148,63 +148,137 @@ def ref_delta_walk_fused(value: jax.Array, child: jax.Array, root: jax.Array,
     return (s["leaf_val"], s["leaf_b"], s["final_dn"], s["hops"], s["cand"])
 
 
+def _pick_rank(x, r):
+    """``x[r[i], i]`` per lane of a slot-major (N, K) tile: a one-hot
+    select and a max down the slots (a per-lane gather lowers poorly on
+    the TPU; every rank asked for labels exactly one slot)."""
+    hit = jnp.arange(x.shape[0], dtype=jnp.int32)[:, None] == r[None, :]
+    return jnp.max(jnp.where(hit, x, jnp.iinfo(x.dtype).min), axis=0)
+
+
+def _shift(x, s: int, fill):
+    """Rows of a slot-major tile moved by a static ``s``: ``y[i] =
+    x[i - s]`` (``s`` > 0, down) or ``x[i + |s|]`` (up), ``fill`` where
+    that falls outside."""
+    if abs(s) >= x.shape[0]:
+        return jnp.full_like(x, fill)
+    pad = jnp.full((abs(s),) + x.shape[1:], fill, x.dtype)
+    if s > 0:
+        return jnp.concatenate([pad, x[:-s]], axis=0)
+    return jnp.concatenate([x[-s:], pad], axis=0)
+
+
+def _compact(x, keep, fill):
+    """Move the kept rows of each lane to its top rows, in order: row
+    ``i`` rises by ``d_i``, the rows dropped above it, one bit of
+    ``d_i`` per stage from the lowest.  ``d`` never falls along the kept
+    rows, so two never meet in a stage and their order holds.  Rows past
+    the kept count hold leftovers."""
+    n = x.shape[0]
+    drop = (~keep).astype(jnp.int32)
+    d, s = drop, 1
+    while s < n:                                  # inclusive prefix count
+        d = d + _shift(d, s, 0)
+        s *= 2
+    d = jnp.where(keep, d - drop, 0)
+    s = 1
+    while s < n:
+        moves = keep & ((d & s) != 0)
+        lands = _shift(moves, -s, False)
+        x = jnp.where(lands, _shift(x, -s, fill), x)
+        d = jnp.where(lands, _shift(d, -s, 0), d)
+        keep = lands | (keep & ~moves)
+        s *= 2
+    return x
+
+
+def _place(x, n, width: int, fill):
+    """Rows ``0..`` of each lane moved down to start at row ``n[lane]``,
+    cut to ``width`` rows: one static shift per bit of ``n``."""
+    if x.shape[0] >= width:
+        x = x[:width]
+    else:
+        pad = jnp.full((width - x.shape[0],) + x.shape[1:], fill, x.dtype)
+        x = jnp.concatenate([x, pad], axis=0)
+    for b in range(max(width, 1).bit_length()):
+        x = jnp.where(((n >> b) & 1)[None, :] != 0, _shift(x, 1 << b, fill), x)
+    return x
+
+
 @functools.partial(
     jax.jit, static_argnames=("height", "max_rounds", "max_out", "pmask"))
 def ref_delta_scan_fused(value: jax.Array, mark: jax.Array, child: jax.Array,
                          root: jax.Array, starts: jax.Array, his: jax.Array,
                          *, height: int, max_rounds: int, max_out: int,
                          pmask: int = 0):
-    """Fused emit-cursor scan frontier, XLA-compiled: the whole
-    find/verify/emit loop in one program (contract of ``ops.delta_scan``).
+    """Fused leaf-run scan frontier, XLA-compiled: the whole loop in one
+    program (contract of ``ops.delta_scan``).
 
-    Each lane carries an emit cursor over the packed key space and fills
-    ``out[lane, :]`` with the live *leaf* values in ``(start, hi]`` in key
-    order (packed, ascending; ``walk_big`` pads unused slots).  ``starts``
-    and ``his`` are packed ``qpack`` bounds: start exclusive, hi inclusive
-    in key space (``v > start_q`` iff ``key(v) > start_key`` since qpack
-    packs an all-ones payload).  A lane alternates two pass kinds over the
-    same blind-descent round structure as ``ref_delta_walk_fused``:
+    Each lane fills ``out[lane, :]`` with the live *leaf* values in
+    ``(start, hi]`` in key order (packed, ascending; ``walk_big`` pads
+    unused slots).  ``starts`` and ``his`` are packed ``qpack`` bounds:
+    start exclusive, hi inclusive in key space (``v > start_q`` iff
+    ``key(v) > start_key`` since qpack packs an all-ones payload).
 
-    * FIND — a successor walk from the root for the cursor, folding
-      left-turn routers plus the final live leaf into a candidate;
-    * VERIFY — an exact walk for the candidate key (candidate routers may
-      be tombstones); a live hit is emitted and becomes the new cursor, a
-      dead one is chased (cursor advances past it without emitting).
+    One pass kind, one ΔNode row per lane per round.  A lane carries a
+    descent query ``q`` (first the start) and a region bound ``U``, the
+    smallest left-turn router of the rows above its ΔNode: the first key
+    past the ΔNode's region.  Each round it reads its whole row (value,
+    mark, child) in in-order (``layout.inorder_tables``), descends it
+    blind for ``q`` to the landing leaf, and takes the *run*: the
+    key-leaves (occupied, not internal, not markers — I1, I3; sorted by
+    I4) from the landing up to the first marker at or after it.  The
+    live ones in band are emitted at columns ``n…`` through a prefix
+    count, tombstones skipped.  Then the lane
+    * hops to the marker's child with the same ``q`` (every key there
+      exceeds it, so the child is entered at its leftmost leaf), folding
+      the row's left-turn routers above the marker into ``U``; a landing
+      on a marker is an empty run and such a hop; or
+    * at the end of its ΔNode restarts at its own root seed with
+      ``q = U`` taken inclusive (``U | pmask``) and ``U`` reset, or is
+      done when no bound is left or ``U > hi``.
+    A lane is also done when its run passes a key above ``hi``, or holds
+    a live in-band key past ``max_out`` (``more``); a buffer that fills
+    exactly at a run's end goes on until it finds the next live in-band
+    key or shows there is none.
 
-    Overflow buffers are NOT consulted — the engine dispatch merges
-    I5' buffered items into the emitted run (``repro.core.engine``), so
-    both engines share one merge and stay bit-identical.
+    The rows ride slot-major (slots × lanes), so the prefix count, the
+    compaction and the placement are static row shifts, O(K × UB) per
+    round.  Overflow buffers are NOT consulted — the engine dispatch
+    merges I5' buffered items into the emitted run
+    (``repro.core.engine``), so both engines share one merge and stay
+    bit-identical.
 
     Returns (out (K, max_out) packed, n (K,) int32, hops (K,) int32,
-    more (K,) bool).  ``hops`` counts ΔNode visits across every pass —
-    exactly the rounds the lane stayed active, matching ``delta_walk``'s
-    accounting.  ``more`` marks lanes whose buffer filled with live items
-    remaining; the continuation cursor is the last emitted key
-    (``key_of(out[lane, n-1])``).  A lane whose start equals ``walk_big``
-    is born done (the q_tile pad contract).
+    more (K,) bool).  ``hops`` counts ΔNode visits — exactly the rounds
+    the lane stayed active, matching ``delta_walk``'s accounting.
+    ``more`` marks lanes that left out a live in-band item; the
+    continuation cursor is the last emitted key (``key_of(out[lane,
+    n-1])``).  A lane whose start equals ``walk_big`` is born done (the
+    q_tile pad contract).
     """
     from repro.kernels.veb_search import walk_big
 
     h = height
-    bottom0 = 2 ** (h - 1)
-    m, ub = value.shape
-    pos = jnp.asarray(layout.veb_pos_table(h))
+    ub = 2 ** h - 1
+    tab = layout.inorder_tables(h)
+    storage = jnp.asarray(tab["storage"])
+    bottom = jnp.asarray(tab["bottom"])[:, None]
+    m = value.shape[0]
     big = jnp.asarray(walk_big(value.dtype), value.dtype)
     starts = starts.astype(value.dtype)
     his = his.astype(value.dtype)
-    k = starts.shape[0]
-    vflat = value.reshape(-1)
-    mflat = mark.reshape(-1)
+    k = his.shape[0]
     dn0 = jnp.broadcast_to(jnp.asarray(root, jnp.int32), (k,))
     pm = jnp.asarray(pmask, value.dtype)
+    rank = jnp.arange(ub, dtype=jnp.int32)[:, None]
+    col = jnp.arange(max_out, dtype=jnp.int32)[:, None]
 
     state = dict(
         dn=dn0,
-        verify=jnp.zeros((k,), jnp.bool_),
-        q=starts,                       # FIND: cursor_q; VERIFY: pending_q
-        cursor=starts,                  # start / last emitted (packed qpack)
-        cand=jnp.full((k,), big, value.dtype),
-        out=jnp.full((k, max_out), big, value.dtype),
+        q=starts,
+        bound=jnp.full((k,), big, value.dtype),
+        out=jnp.full((max_out, k), big, value.dtype),
         n=jnp.zeros((k,), jnp.int32),
         hops=jnp.zeros((k,), jnp.int32),
         more=jnp.zeros((k,), jnp.bool_),
@@ -216,75 +290,66 @@ def ref_delta_scan_fused(value: jax.Array, mark: jax.Array, child: jax.Array,
         return jnp.any(~s["done"]) & (s["rounds"] < max_rounds)
 
     def body(s):
-        dnc = jnp.clip(s["dn"], 0, m - 1)
-        base = dnc * ub
-        v = s["q"]
-        b = jnp.ones((k,), jnp.int32)
-        lb = jnp.ones((k,), jnp.int32)          # last occupied position
-        lv = jnp.zeros((k,), value.dtype)
-        routers, bs = [], []
-        for _ in range(h):                       # blind descent: h gathers
-            router = vflat.at[base + pos[b]].get(mode="promise_in_bounds")
-            routers.append(router)
-            bs.append(b)
-            occ = router != EMPTY
-            lb = jnp.where(occ, b, lb)
-            lv = jnp.where(occ, router, lv)
-            go_right = v >= router               # EMPTY always routes right
-            b = jnp.where(b < bottom0, 2 * b + go_right.astype(b.dtype), b)
-        rcand = jnp.full((k,), big, value.dtype)
-        for router, bi in zip(routers, bs):      # post-hoc candidate fold
-            fold = ((router != EMPTY) & (bi != lb) & (v < router)
-                    & (router < rcand))
-            rcand = jnp.where(fold, router, rcand)
-        at_bottom = lb >= bottom0
-        slot = jnp.where(at_bottom, lb - bottom0, 0)
-        ch = child.at[dnc, slot].get(mode="promise_in_bounds")
-        nxt = jnp.where(at_bottom, ch, jnp.int32(-1))
         act = ~s["done"]
-        hopping = act & (nxt >= 0)
-        res = act & (nxt < 0)                    # pass resolved this round
-        # pass-level candidate fold (FIND passes only)
-        cand = jnp.where(act & ~s["verify"] & (rcand < s["cand"]),
-                         rcand, s["cand"])
-        leaf_mark = mflat.at[base + pos[lb]].get(mode="promise_in_bounds")
-        leaf_live = (lv != EMPTY) & ~leaf_mark
-        # FIND resolution: fold the final leaf, then accept / stop
-        f_res = res & ~s["verify"]
-        leaf_fold = f_res & leaf_live & (lv > s["cursor"]) & (lv < cand)
-        cand = jnp.where(leaf_fold, lv, cand)
-        f_none = f_res & ((cand == big) | (cand > his))
-        pending = cand | pm                      # qpack of candidate key
-        to_verify = f_res & ~f_none
-        # VERIFY resolution: emit a live hit, chase a tombstone
-        v_res = res & s["verify"]
-        hit = v_res & leaf_live & ((lv | pm) == s["q"])
-        can_emit = s["n"] < max_out
-        emit = hit & can_emit
-        full = hit & ~can_emit
-        chase = v_res & ~hit
-        col = jnp.arange(max_out, dtype=jnp.int32)[None, :]
-        out = jnp.where(emit[:, None] & (col == s["n"][:, None]),
-                        lv[:, None], s["out"])
-        back_to_find = emit | chase
-        restart = to_verify | back_to_find
+        n, bound = s["n"], s["bound"]
+        dnc = jnp.clip(s["dn"], 0, m - 1)
+        x = jnp.take(value, dnc, axis=0).T[storage]      # (UB, K) in-order
+        dead = jnp.take(mark, dnc, axis=0).T[storage]
+        ch = jnp.take(child, dnc, axis=0).T              # ((UB + 1) // 2, K)
+        # blind descent in rank space: the landing is the last occupied
+        r = jnp.full((k,), 2 ** (h - 1) - 1, jnp.int32)
+        land = r
+        for d in range(h):
+            v = _pick_rank(x, r)
+            land = jnp.where(v != EMPTY, r, land)
+            if d < h - 1:
+                off = 2 ** (h - 2 - d)
+                r = jnp.where(s["q"] >= v, r + off, r - off)
+        occ = x != EMPTY
+        marker = bottom & occ & (jnp.repeat(ch, 2, axis=0)[:ub] >= 0)
+        internal = ~bottom & occ[tab["left"]]
+        after = rank >= land[None, :]
+        stop = jnp.min(jnp.where(marker & after, rank, ub), axis=0)
+        run = (after & (rank < stop[None, :]) & occ & ~internal & ~marker
+               & (x != big))
+        emit = (run & ~dead & (x > starts[None, :]) & (x <= his[None, :])
+                & act[None, :])
+        count = jnp.sum(emit, axis=0, dtype=jnp.int32)
+        room = max_out - n
+        took = jnp.minimum(count, room)
+        out = jnp.where((col >= n[None, :]) & (col < (n + took)[None, :]),
+                        _place(_compact(x, emit, big), n, max_out, big),
+                        s["out"])
+        full = act & (count > room)
+        past_hi = jnp.any(run & (x > his[None, :]), axis=0)
+        # next region: the marker's child, or back to the root for U
+        hop = stop < ub
+        j = stop // 2
+        nxt = _pick_rank(ch, j)
+        fold = bound
+        for d in range(h - 1):
+            sh = h - 1 - d
+            a = ((2 * (j >> sh) + 1) << sh) - 1        # ancestor's rank
+            v = _pick_rank(x, a)
+            fold = jnp.where((stop < a) & (v < fold), v, fold)
+        spent = (bound == big) | (bound > his)
+        done_now = act & (full | past_hi | (~hop & spent))
+        go = act & ~done_now
+        restart = go & ~hop
         return dict(
-            dn=jnp.where(hopping, nxt, jnp.where(restart, dn0, s["dn"])),
-            verify=jnp.where(to_verify, True,
-                             jnp.where(back_to_find, False, s["verify"])),
-            q=jnp.where(to_verify, pending, s["q"]),
-            cursor=jnp.where(back_to_find, s["q"], s["cursor"]),
-            cand=jnp.where(restart, big, cand),
+            dn=jnp.where(go & hop, nxt, jnp.where(restart, dn0, s["dn"])),
+            q=jnp.where(restart, bound | pm, s["q"]),
+            bound=jnp.where(go & hop, fold, jnp.where(restart, big, bound)),
             out=out,
-            n=s["n"] + emit.astype(jnp.int32),
+            n=n + took,
             hops=s["hops"] + act.astype(jnp.int32),
             more=s["more"] | full,
-            done=s["done"] | f_none | full,
+            done=s["done"] | done_now,
             rounds=s["rounds"] + 1,
         )
 
     s = jax.lax.while_loop(cond, body, state)
-    return s["out"], s["n"], s["hops"], s["more"]
+    return s["out"].T, s["n"], s["hops"], s["more"]
 
 
 @functools.partial(jax.jit, static_argnames=("height",))

@@ -359,25 +359,39 @@ def veb_walk_fused(value_p: jax.Array, child_p: jax.Array, roots: jax.Array,
     return tuple(o.reshape(k) for o in out)
 
 
+def _rank_labels(height: int, ubp: int):
+    """(1, ubp) in-order rank stored at each vEB lane of a row (-1 on the
+    pad lanes) — the labels the scan's `_pick` matches a rank against."""
+    lab = np.full(ubp, -1, np.int32)
+    lab[layout.inorder_tables(height)["storage"]] = np.arange(
+        2 ** height - 1, dtype=np.int32)
+    return jnp.asarray(lab)[None, :]
+
+
 def _scan_kernel(height: int, big: int, pmask: int, max_rounds: int,
                  max_out: int, mo_p: int, m: int,
-                 bfs_ref, start_ref, hi_ref, root_ref, value_ref, mark_ref,
+                 rank_ref, start_ref, hi_ref, root_ref, value_ref, mark_ref,
                  child_ref, out_ref, n_ref, hops_ref, more_ref,
                  dn_ref, vrows_ref, mrows_ref, crows_ref):
-    """Persistent emit-cursor scan: the whole find/verify/emit loop of
-    ``ops.delta_scan`` inside one kernel launch (per q_tile grid cell).
+    """Persistent leaf-run scan: the whole loop of ``ops.delta_scan``
+    inside one kernel launch (per q_tile grid cell).
 
     Same round structure as ``_fused_kernel`` (row reads, then a blind
-    descent); each lane additionally carries a scan cursor, a FIND/VERIFY
-    mode bit and an emit index into a VMEM-resident (QT, mo_p) output
-    tile.  The exact pass logic is documented on the bit-exact oracle,
-    ``ref.ref_delta_scan_fused``; ``mo_p`` is the lane-padded buffer
-    width (emission is still capped at ``max_out``).  The mark plane is
-    int32 (nonzero = marked): a bool ref would load as an int on TPU.
+    descent, here by in-order rank); each lane additionally carries its
+    descent query, its region bound and an emit index into a
+    VMEM-resident (QT, mo_p) output tile.  The run is a loop over the
+    row's in-order positions: per position a one-hot pick of its value,
+    mark, left child and child lanes (`_pick`: Mosaic lowers no per-lane
+    gather), a running count that places the key, and the first marker
+    at or after the landing.  The pass logic is
+    documented on the bit-exact oracle, ``ref.ref_delta_scan_fused``;
+    ``mo_p`` is the lane-padded buffer width (emission is still capped at
+    ``max_out``).  The mark plane is int32 (nonzero = marked): a bool ref
+    would load as an int on TPU.
     """
     h = height
-    bottom0 = 2 ** (h - 1)
-    bfs = bfs_ref[...]
+    ub = 2 ** h - 1
+    ranks = rank_ref[...]
     lanes = _lanes(crows_ref.shape[1])
     starts = start_ref[...]                              # (QT, 1) packed
     his = hi_ref[...]
@@ -386,79 +400,83 @@ def _scan_kernel(height: int, big: int, pmask: int, max_rounds: int,
     bigv = jnp.asarray(big, dt)
     pm = jnp.asarray(pmask, dt)
     col = _lanes(mo_p)
+    none = jnp.int32(ub)
 
     # lane flags ride the loop carry as int32 0/1 (as in `_fused_kernel`)
     def cond(s):
-        return (jnp.min(s[9]) == 0) & (s[10] < max_rounds)
+        return (jnp.min(s[7]) == 0) & (s[8] < max_rounds)
 
     def body(s):
-        (dn, verify, q, cursor, cand, out, n, hops, more, done, rounds) = s
-        verify, more, done = verify != 0, more != 0, done != 0
+        dn, q, bound, out, n, hops, more, done, rounds = s
+        act = done == 0
         dn_ref[...] = jnp.clip(dn, _Z, jnp.int32(m - 1))
         _gather_rows(dn_ref, ((value_ref, vrows_ref), (mark_ref, mrows_ref),
                               (child_ref, crows_ref)))
         rows = vrows_ref[...]
-        b = jnp.ones(q.shape, jnp.int32)
-        lb = jnp.ones(q.shape, jnp.int32)          # last occupied position
-        lv = jnp.zeros(q.shape, dt)
-        routers, bs = [], []
-        for _ in range(h):                          # blind descent
-            router = _pick(rows, bfs, b)
-            routers.append(router)
-            bs.append(b)
-            occ = router != EMPTY
-            lb = jnp.where(occ, b, lb)
-            lv = jnp.where(occ, router, lv)
-            go_right = q >= router
-            b = jnp.where(b < bottom0, 2 * b + go_right.astype(b.dtype), b)
-        rcand = jnp.full(q.shape, big, dt)
-        for router, bi in zip(routers, bs):         # post-hoc cand fold
-            fold = ((router != EMPTY) & (bi != lb) & (q < router)
-                    & (router < rcand))
-            rcand = jnp.where(fold, router, rcand)
-        at_bottom = lb >= bottom0
-        slot = jnp.where(at_bottom, lb - bottom0, _Z)
-        ch = _pick(crows_ref[...], lanes, slot)
-        nxt = jnp.where(at_bottom, ch, jnp.int32(-1))
-        act = ~done
-        hopping = act & (nxt >= 0)
-        res = act & (nxt < 0)
-        cand = jnp.where(act & ~verify & (rcand < cand), rcand, cand)
-        leaf_marked = _pick(mrows_ref[...], bfs, lb) != 0
-        leaf_live = (lv != EMPTY) & ~leaf_marked
-        f_res = res & ~verify
-        leaf_fold = f_res & leaf_live & (lv > cursor) & (lv < cand)
-        cand = jnp.where(leaf_fold, lv, cand)
-        f_none = f_res & ((cand == bigv) | (cand > his))
-        pending = cand | pm
-        to_verify = f_res & ~f_none
-        v_res = res & verify
-        hit = v_res & leaf_live & ((lv | pm) == q)
-        can_emit = n < max_out
-        emit = hit & can_emit
-        full = hit & ~can_emit
-        chase = v_res & ~hit
-        out = jnp.where(emit & (col == n), lv, out)
-        back_to_find = emit | chase
-        restart = to_verify | back_to_find
+        r = jnp.full(q.shape, 2 ** (h - 1) - 1, jnp.int32)
+        land = r
+        for d in range(h):                          # blind descent
+            v = _pick(rows, ranks, r)
+            land = jnp.where(v != EMPTY, r, land)
+            if d < h - 1:
+                off = jnp.int32(2 ** (h - 2 - d))
+                r = jnp.where(q >= v, r + off, r - off)
+        marks = mrows_ref[...]
+        crows = crows_ref[...]
+
+        def sweep(rr, c):                           # the run, in in-order
+            stop, nxt, count, past_hi, out = c
+            x = _pick(rows, ranks, rr)
+            occ = x != EMPTY
+            low = (rr + 1) & -(rr + 1)              # 2**(h-1-depth)
+            ch = _pick(crows, lanes, rr >> 1)       # bottom slot rr // 2
+            marker = (low == 1) & occ & (ch >= 0)
+            after = (land <= rr) & (stop == none)
+            first = after & marker
+            left = _pick(rows, ranks, rr - (low >> 1))
+            leaf = occ & ~marker & ((low == 1) | (left == EMPTY))
+            run = after & leaf & (x != bigv)
+            emit = (run & (_pick(marks, ranks, rr) == 0) & (x > starts)
+                    & (x <= his) & act)
+            return (jnp.where(first, rr, stop), jnp.where(first, ch, nxt),
+                    count + emit.astype(jnp.int32),
+                    past_hi | (run & (x > his)).astype(jnp.int32),
+                    jnp.where(emit & (col == n + count), x, out))
+
+        stop, nxt, count, past_hi, out = jax.lax.fori_loop(
+            _Z, none, sweep,
+            (jnp.full(q.shape, ub, jnp.int32), jnp.full(q.shape, -1, jnp.int32),
+             jnp.zeros(q.shape, jnp.int32), jnp.zeros(q.shape, jnp.int32),
+             out))
+        room = jnp.int32(max_out) - n
+        took = jnp.minimum(count, room)
+        full = act & (count > room)
+        hop = stop < none
+        fold = bound                    # left-turn routers above the marker
+        j = stop >> 1
+        for d in range(h - 1):
+            sh = h - 1 - d
+            a = ((2 * (j >> sh) + 1) << sh) - 1
+            v = _pick(rows, ranks, a)
+            fold = jnp.where((stop < a) & (v < fold), v, fold)
+        spent = (bound == bigv) | (bound > his)
+        done_now = act & (full | (past_hi != 0) | (~hop & spent))
+        go = act & ~done_now
+        restart = go & ~hop
         return (
-            jnp.where(hopping, nxt, jnp.where(restart, dn0, dn)),
-            (to_verify | (verify & ~back_to_find)).astype(jnp.int32),
-            jnp.where(to_verify, pending, q),
-            jnp.where(back_to_find, q, cursor),
-            jnp.where(restart, bigv, cand),
+            jnp.where(go & hop, nxt, jnp.where(restart, dn0, dn)),
+            jnp.where(restart, bound | pm, q),
+            jnp.where(go & hop, fold, jnp.where(restart, bigv, bound)),
             out,
-            n + emit.astype(jnp.int32),
+            n + took,
             hops + act.astype(jnp.int32),
-            (more | full).astype(jnp.int32),
-            (done | f_none | full).astype(jnp.int32),
+            ((more != 0) | full).astype(jnp.int32),
+            ((done != 0) | done_now).astype(jnp.int32),
             rounds + 1,
         )
 
     init = (
         dn0,
-        jnp.zeros(starts.shape, jnp.int32),
-        starts,
         starts,
         jnp.full(starts.shape, big, dt),
         jnp.full((starts.shape[0], mo_p), big, dt),
@@ -469,10 +487,10 @@ def _scan_kernel(height: int, big: int, pmask: int, max_rounds: int,
         _Z,
     )
     s = jax.lax.while_loop(cond, body, init)
-    out_ref[...] = s[5]
-    n_ref[...] = s[6]
-    hops_ref[...] = s[7]
-    more_ref[...] = s[8]
+    out_ref[...] = s[3]
+    n_ref[...] = s[4]
+    hops_ref[...] = s[5]
+    more_ref[...] = s[6]
 
 
 @functools.partial(jax.jit,
@@ -483,7 +501,7 @@ def veb_scan_fused(value_p: jax.Array, mark_p: jax.Array, child_p: jax.Array,
                    height: int, max_out: int, pmask: int = 0,
                    q_tile: int = 256, max_rounds: int = 256,
                    interpret: bool):
-    """All scan passes in one launch (grid over query tiles).
+    """All scan rounds in one launch (grid over query tiles).
 
     value_p:        (M, UBp) padded arena rows (`pad_arena`), int32/int64
     mark_p:         (M, UBp) int32 mark plane, same padding (nonzero =
@@ -545,7 +563,7 @@ def veb_scan_fused(value_p: jax.Array, mark_p: jax.Array, child_p: jax.Array,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(_bfs_labels(height, ubp), *_cols(starts, his, roots), value_p, mark_p,
+    )(_rank_labels(height, ubp), *_cols(starts, his, roots), value_p, mark_p,
       child_p)
     return out, n.reshape(k), hops.reshape(k), more.reshape(k)
 

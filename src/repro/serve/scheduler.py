@@ -294,7 +294,7 @@ class ServeScheduler:
     def scan(self, seq_ids, max_items: int | None = None):
         """Ordered read service: each referenced sequence's full
         block -> page mapping in block order, resolved through ONE
-        engine scan dispatch (one emit-cursor lane per sequence over the
+        engine scan dispatch (one leaf-run scan lane per sequence over the
         pager index's contiguous per-sequence key band) — the bulk
         companion to ``probe``'s point lookups.  Like ``probe`` it runs
         between steps against the current wait-free snapshot; staged

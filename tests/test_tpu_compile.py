@@ -99,7 +99,7 @@ def test_veb_scan_fused_compiles_at_budget_edge(one_chip, x64):
     k = 4 * q_tile
     fn = lambda v, mk, c, r, a, b: V.veb_scan_fused(
         v, mk, c, r, a, b, height=height, max_out=max_out, q_tile=q_tile,
-        max_rounds=OPS.scan_round_cap(height, m, max_out), interpret=False)
+        max_rounds=OPS.scan_round_cap(height, m), interpret=False)
     text = _compile_text(fn, one_chip, x64, *([((m, 128), jnp.int32)] * 3),
                          *([((k,), jnp.int32)] * 3))
     assert "tpu_custom_call" in text

@@ -40,6 +40,8 @@ TRACE_DIR = ROOT / ".bench_trace"
 TRACE_SECONDS = 5.0      # a traced run's window: long enough for tens of
 #                          batches of the slowest cell, short enough that
 #                          the trace stays a few MB
+CHECK_IN_FLIGHT = 8      # probe calls of the read-back dispatched ahead of
+#                          the one fetched
 
 if str(BENCH) not in sys.path:
     sys.path.insert(0, str(BENCH))
@@ -103,24 +105,44 @@ class IndexSystem:
 
         self._jax, self._OpBatch = jax, OpBatch
         self._op_insert, self._op_search = OP_INSERT, OP_SEARCH
-        ix = config["index"]
-        self.ix = make_index(ix["backend"], initial=keys, payloads=ids,
-                             height=int(ix["height"]),
-                             max_dnodes=int(ix["max_dnodes"]),
-                             payload_bits=int(ix["payload_bits"]),
-                             engine=ix["engine"])
+        # every key of the configuration's ``index`` but the backend goes
+        # to the backend's config as it stands in the file
+        ix = dict(config["index"])
+        self.ix = make_index(ix.pop("backend"), initial=keys, payloads=ids,
+                             **ix)
         self._kinds: dict[int, np.ndarray] = {}
 
     def impls(self, scan_width: int) -> dict:
-        """Which walk and scan implementation this arena runs."""
+        """Which walk and scan implementation this arena runs.  A sharded
+        backend's state stacks its shards' arenas on a leading axis: it
+        reports its shards, the devices of their mesh, its read dispatch
+        (``fused``: one walk per device over its shards' arenas; ``vmap``:
+        one per shard), and the ΔNodes, walk and scan of one shard."""
+        from repro.api.index import cfg_attr
         from repro.kernels import ops
 
-        t, h = self.ix.state, self.ix.cfg.height
-        return {"engine": self.ix.engine,
-                "arena_dnodes": int(t.value.shape[0]),
-                "walk": ops.walk_impl(t.value, t.child, height=h),
-                "scan": ops.scan_impl(t.value, t.child, height=h,
-                                      max_out=max(scan_width, 1))}
+        ix, h = self.ix, cfg_attr(self.ix.cfg, "height")
+        if not ix.capability.sharded:
+            value, child = ix.state.value, ix.state.child
+            out = {"engine": ix.engine, "arena_dnodes": int(value.shape[0])}
+        else:
+            from jax import ShapeDtypeStruct
+            from repro.distributed.router import forest_mesh
+
+            n, trees = ix.cfg.num_shards, ix.state.trees
+            # one shard's arena by its shape: a slice would copy it on the
+            # device, and raise the peak
+            value, child = (ShapeDtypeStruct(a.shape[1:], a.dtype)
+                            for a in (trees.value, trees.child))
+            out = {"backend": ix.backend, "engine": ix.engine,
+                   "num_shards": n,
+                   "devices": [d.id for d in forest_mesh(n).devices.flat],
+                   "read_dispatch": ("fused" if ix.capability.fused_forest
+                                     else "vmap"),
+                   "dnodes_per_shard": int(value.shape[0])}
+        return out | {"walk": ops.walk_impl(value, child, height=h),
+                      "scan": ops.scan_impl(value, child, height=h,
+                                            max_out=max(scan_width, 1))}
 
     def read(self, keys):
         """(found, payload, hops) of each key."""
@@ -306,39 +328,65 @@ def absent_keys(reference: SortedMap, key_hi: int) -> np.ndarray:
     return q[~reference.contains(q)]
 
 
+def _probe(system, call, chunks, compare) -> int:
+    """Sum ``compare(q, m, got)`` over the probe batches ``(q, m)`` of
+    ``chunks``, ``got`` being ``call(q)`` fetched to the host.  Up to
+    ``CHECK_IN_FLIGHT`` calls are dispatched before the oldest one is
+    fetched, so the device walks the next probes while the host compares."""
+    bad = 0
+    pending = collections.deque()
+    for q, m in chunks:
+        pending.append((q, m, call(q)))
+        if len(pending) == CHECK_IN_FLIGHT:
+            oldest, n, out = pending.popleft()
+            bad += compare(oldest, n, system.fetch(out))
+    for oldest, n, out in pending:
+        bad += compare(oldest, n, system.fetch(out))
+    return bad
+
+
+def _chunks(probes: np.ndarray, lanes: int):
+    """``probes`` in batches of ``lanes``, the last padded with repeats:
+    (batch, how many of it are probes)."""
+    for i in range(0, probes.size, lanes):
+        yield (np.resize(probes[i:i + lanes], lanes),
+               min(lanes, probes.size - i))
+
+
 def live_mismatch(system, reference: SortedMap, traffic: Traffic,
                   key_lo: int, key_hi: int) -> dict:
     """Read the whole live map back through the system and count what
-    differs from the reference.  With scans: overlapping full-width scans
-    that tile the reference (each starts at the last key of the one
-    before, the first at ``key_lo``), so a missing, an extra or a misplaced
-    key or record id shows in some row or count.  Reads only: every
-    reference key must be found with its record id, a key next to each
-    (``absent_keys``) must not be found, and ``size`` must match."""
+    differs from the reference, in batches of the window's shape
+    (``n_scan`` starts, ``n_read`` lanes).  With scans: overlapping
+    full-width scans that tile the reference (each starts at the last key
+    of the one before, the first at ``key_lo``), so a missing, an extra
+    or a misplaced key or record id shows in some row or count.  Reads
+    only: every reference key must be found with its record id, a key
+    next to each (``absent_keys``) must not be found, and ``size`` must
+    match."""
     keys = reference.keys
     if traffic.n_scan:
         w, s = traffic.scan_width, traffic.n_scan
         step = w - 1
         starts = np.concatenate([[key_lo], keys[step::step]]).astype(np.int32)
-        bad = 0
-        for i in range(0, starts.size, s):
-            m = min(s, starts.size - i)
-            q = np.resize(starts[i:i + s], s)   # pad with repeats
-            got = system.fetch(system.scan(q, w))
+
+        def scan_bad(q, m, got):
             want = reference.scan(q, np.full(s, w, np.int32), w)
-            bad += int(np.count_nonzero(scan_mismatch(got, want, w)[:m]))
-        return {"live_keys": bad}
+            return int(np.count_nonzero(scan_mismatch(got, want, w)[:m]))
+
+        return {"live_keys": _probe(system, lambda q: system.scan(q, w),
+                                    _chunks(starts, s), scan_bad)}
+
+    def read_bad(q, m, got):
+        bad_f, bad_p = read_mismatch(got, reference.lookup(q))
+        return int(np.count_nonzero((bad_f | bad_p)[:m]))
+
     r = traffic.n_read
     out = {"live_keys": abs(system.size() - len(reference)),
            "absent_found": 0}
     for probes, kind in ((keys, "live_keys"),
                          (absent_keys(reference, key_hi), "absent_found")):
-        for i in range(0, probes.size, r):
-            m = min(r, probes.size - i)
-            q = np.resize(probes[i:i + r], r)
-            bad_f, bad_p = read_mismatch(system.fetch(system.read(q)),
-                                         reference.lookup(q))
-            out[kind] += int(np.count_nonzero((bad_f | bad_p)[:m]))
+        out[kind] += _probe(system, system.read, _chunks(probes, r), read_bad)
     return out
 
 
@@ -455,9 +503,21 @@ def per_layer_metrics(view: RunView) -> dict:
 # --------------------------------------------------------------------------
 
 
+def device_memory(devices) -> dict:
+    """The memory entries of a result's ``device``: each of the cell's
+    chips' ``peak_bytes_in_use`` after the window (0 where the backend
+    keeps none), and the fullest chip's."""
+    by_device = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                 for d in devices]
+    return {"memory_peak_bytes": max(by_device),
+            "memory_peak_bytes_by_device": by_device}
+
+
 def end_to_end_metrics(cell: Cell, window: Window, attempted: int,
                        peak: int, live_keys: int, setup_s: float) -> dict:
-    """The cell's end-to-end metrics of one untraced run."""
+    """The cell's end-to-end metrics of one untraced run; ``peak`` is
+    the sum of the cell's chips' peaks, so that ``peak_bytes_per_key``
+    counts every chip that holds the map."""
     values = {"ops_per_s": attempted / window.seconds,
               "peak_bytes_per_key": peak / live_keys,
               "setup_s": setup_s}
@@ -483,7 +543,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     if needs_x64(cell.config) and not jax.config.jax_enable_x64:
         raise SystemExit("bench: map mode needs JAX_ENABLE_X64=1 set before "
                          "JAX is imported")
-    dev = jax.devices()[0]
+    devs = jax.devices()[:cell.chips]
+    dev = devs[0]
     peaks = peaks_for(dev.device_kind) if trace else None
     setup = {"init_s": time.perf_counter() - t_process}
     t = time.perf_counter()
@@ -512,7 +573,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         jax.profiler.stop_trace()
     else:
         window = run_window(system, traffic, seconds)
-    peak = int((dev.memory_stats() or {}).get("peak_bytes_in_use", 0))
+    memory = device_memory(devs)
     n_batches = len(window.results)
     lat = 1e3 * window.latency_s
     tenths = [round(float(x.mean()), 3) for x in np.array_split(lat, 10)
@@ -526,14 +587,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     reference = SortedMap(traffic.loaded, traffic.loaded_ids)
     key_domain = tuple(int(x) for x in cell.config["key_domain"])
     nums, kinds = check(window, traffic, reference, system, key_domain)
-    log(f"check: {time.perf_counter() - t!r} s, mismatches by kind "
-        f"{json.dumps(kinds)}")
+    check_s = time.perf_counter() - t
+    log(f"check: {check_s!r} s, mismatches by kind {json.dumps(kinds)}")
     attempted = n_batches * traffic.batch_ops
 
     result = {"correct": all(v == 0 for v in nums.values()),
               "attempted": attempted, "failed": nums["wrong_answers"]}
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(jax.devices()), "memory_peak_bytes": peak}
+              "count": len(jax.devices())} | memory
     if trace:
         from trace_reduce import reduce_trace
 
@@ -548,10 +609,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         result["breakdown"] = red.breakdown()
     else:
         result["metrics"] = end_to_end_metrics(
-            cell, window, attempted, peak, len(reference), setup_s)
+            cell, window, attempted,
+            sum(memory["memory_peak_bytes_by_device"]), len(reference),
+            setup_s)
         result["device"] = device
     result["implementations"] = impl
     result["setup_split"] = setup
+    result["check_s"] = check_s
     result["batches"] = n_batches
     result["compiles_in_window"] = window.compiles
     result["mismatches_by_kind"] = kinds

@@ -9,7 +9,10 @@ closed-loop window, check of every answer — without the look for a chip.
 Pallas is sent to its compiled path (``REPRO_PALLAS_INTERPRET=0``), which
 off the TPU runs the XLA mirrors, so ``engine="auto"`` resolves to the
 lockstep engine as on the chip.  JAX runs with x64 on, as map mode needs
-on the chip too.  Then it plants one altered answer under
+on the chip too.  Where a cell asks for 4 chips, the CPU gets 4 host
+devices (``--xla_force_host_platform_device_count``, unless ``XLA_FLAGS``
+already sets a count), so that such a cell rehearses on as many devices
+as it runs on.  Then it plants one altered answer under
 each cell's window and shows that the run reads ``correct: false``.
 
 It prints each run's ``correct`` and compared numbers, and no device
@@ -38,6 +41,17 @@ import harness  # noqa: E402
 TINY_RECORDS = 20_000
 TINY_DNODES = 2048
 SHRINK = 8
+HOST_DEVICES = "--xla_force_host_platform_device_count"
+
+
+def with_host_devices(xla_flags: str, spec: dict) -> str:
+    """``XLA_FLAGS`` giving the CPU as many devices as the most chips a
+    cell of ``spec`` asks for; as it was where it already sets a count or
+    every cell takes one chip."""
+    chips = max(int(w["chips"]) for w in spec["workloads"])
+    if chips == 1 or HOST_DEVICES in xla_flags:
+        return xla_flags
+    return f"{xla_flags} {HOST_DEVICES}={chips}".strip()
 
 
 def tiny(cell: harness.Cell) -> harness.Cell:
@@ -74,6 +88,9 @@ def main() -> None:
     ap.add_argument("--seconds", type=float, default=2.0)
     args = ap.parse_args()
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    flags = with_host_devices(os.environ.get("XLA_FLAGS", ""), spec)
+    if flags:
+        os.environ["XLA_FLAGS"] = flags    # before JAX starts its backend
     ok = True
     for w in spec["workloads"]:
         cell = tiny(harness.load_cell(w["name"]))

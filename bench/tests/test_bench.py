@@ -12,7 +12,9 @@ v5e (``bench/testdata``).
 
 from __future__ import annotations
 
+import json
 import os
+import subprocess
 import sys
 import time
 from pathlib import Path
@@ -177,6 +179,178 @@ def test_control_reads_incorrect(name):
     assert not res["correct"], res["checks"]
 
 
+# the 1-chip cells build and report as they did before the harness took
+# any backend: make_index's keyword arguments with their types, and the
+# printed implementations, at rehearsal size
+PINNED_INDEX_CALL = {"height": 7, "max_dnodes": rehearse.TINY_DNODES,
+                     "payload_bits": 32, "engine": "auto"}
+PINNED_IMPLS = ('{"engine": "lockstep", "arena_dnodes": 2048, '
+                '"walk": "ref_delta_walk_fused", '
+                '"scan": "ref_delta_scan_fused"}')
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_one_chip_cells_build_and_report_as_before(name, monkeypatch):
+    import repro.api
+
+    calls = []
+    real = repro.api.make_index
+
+    def recording(backend, **kw):
+        calls.append((backend, {k: v for k, v in kw.items()
+                                if k not in ("initial", "payloads")}))
+        return real(backend, **kw)
+
+    monkeypatch.setattr(repro.api, "make_index", recording)
+    cell = rehearse.tiny(harness.load_cell(name))
+    tr = TF.make_traffic(cell.config, cell.mix, SEED)
+    system = harness.IndexSystem(cell.config, tr.loaded, tr.loaded_ids)
+    [(backend, kw)] = calls
+    assert backend == "deltatree"
+    assert kw == PINNED_INDEX_CALL
+    assert all(type(kw[k]) is type(v) for k, v in PINNED_INDEX_CALL.items())
+    assert json.dumps(system.impls(tr.scan_width)) == PINNED_IMPLS
+
+
+class _StubDevice:
+    def __init__(self, peak):
+        self.peak = peak
+
+    def memory_stats(self):
+        return None if self.peak is None else {"peak_bytes_in_use": self.peak}
+
+
+def test_device_memory_is_read_on_every_chip_of_the_cell(monkeypatch):
+    """``peak_bytes_per_key`` divides the sum over the cell's chips by the
+    live keys; ``memory_peak_bytes`` is the fullest chip's."""
+    stubs = [_StubDevice(p) for p in (100, 700, None, 200)]
+    mem = harness.device_memory(stubs)
+    assert mem == {"memory_peak_bytes": 700,
+                   "memory_peak_bytes_by_device": [100, 700, 0, 200]}
+    read_on = []
+
+    def stubbed(devices):
+        read_on.append(list(devices))
+        return mem
+
+    monkeypatch.setattr(harness, "device_memory", stubbed)
+    cell = rehearse.tiny(harness.load_cell("ycsb_c.4m"))
+    res = harness.run(cell, SEED, SECONDS, False, time.perf_counter())
+    import jax
+
+    assert read_on == [jax.devices()[:cell.chips]]
+    assert res["device"]["memory_peak_bytes"] == 700
+    assert res["device"]["memory_peak_bytes_by_device"] == [100, 700, 0, 200]
+    # C inserts nothing: the live keys are the loaded records
+    assert res["metrics"]["peak_bytes_per_key"]["value"] == \
+        1000 / cell.config["recordcount"]
+
+
+def test_rehearsal_gives_the_cpu_a_device_per_chip():
+    one = {"workloads": [{"chips": 1}, {"chips": 1}]}
+    four = {"workloads": [{"chips": 1}, {"chips": 4}]}
+    flag = "--xla_force_host_platform_device_count"
+    assert rehearse.with_host_devices("", one) == ""
+    assert rehearse.with_host_devices("--xla_foo", one) == "--xla_foo"
+    assert rehearse.with_host_devices("", four) == f"{flag}=4"
+    assert rehearse.with_host_devices("--xla_foo", four) == \
+        f"--xla_foo {flag}=4"
+    # a count already set stands
+    assert rehearse.with_host_devices(f"{flag}=8", four) == f"{flag}=8"
+
+
+class _Prober:
+    """A read-only system that counts its probe calls in flight."""
+
+    def __init__(self, reference):
+        self.ref, self.out, self.most = reference, 0, 0
+
+    def read(self, keys):
+        self.out += 1
+        self.most = max(self.most, self.out)
+        found, pay = self.ref.lookup(keys)
+        return found, pay, np.zeros(keys.size, np.int32)
+
+    def scan(self, starts, width):
+        self.out += 1
+        self.most = max(self.most, self.out)
+        return self.ref.scan(starts, np.full(starts.size, width, np.int32),
+                             width)
+
+    def fetch(self, out):
+        self.out -= 1
+        return out
+
+    def size(self):
+        return len(self.ref)
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_read_back_keeps_probe_calls_in_flight(name):
+    """The read-back dispatches up to CHECK_IN_FLIGHT probe calls of the
+    window's shape before it fetches the oldest, fetches every one, and
+    counts a planted difference once."""
+    cell = rehearse.tiny(harness.load_cell(name))
+    # few scans a batch, so that the tiles of the map take many batches
+    mix = dict(cell.mix, scan=16) if cell.mix["scan"] else cell.mix
+    tr = TF.make_traffic(cell.config, mix, SEED)
+    ref = SortedMap(tr.loaded, tr.loaded_ids)
+    lo, hi = cell.config["key_domain"]
+    prober = _Prober(SortedMap(tr.loaded, tr.loaded_ids))
+    assert set(harness.live_mismatch(prober, ref, tr, lo, hi).values()) == {0}
+    assert prober.most == harness.CHECK_IN_FLIGHT and prober.out == 0
+    # the system's copy loses its last key
+    prober = _Prober(SortedMap(tr.loaded, tr.loaded_ids))
+    prober.ref.keys, prober.ref.payloads = (prober.ref.keys[:-1],
+                                            prober.ref.payloads[:-1])
+    got = harness.live_mismatch(prober, ref, tr, lo, hi)
+    want = {"live_keys": 2, "absent_found": 0} if tr.n_read else \
+        {"live_keys": 1}
+    assert got == want
+
+
+# ---- a sharded forest on 4 devices -----------------------------------------
+
+
+@pytest.fixture(scope="module")
+def forest_runs():
+    """Tiny C and E runs of a 4-shard forest (``bench/forest_probe.py``),
+    clean and with one answer altered, in a process whose CPU has 4
+    devices: the count is set before JAX starts."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run(
+        [sys.executable, str(BENCH / "forest_probe.py"), "--tiny",
+         "--seed", str(SEED), "--seconds", str(SECONDS),
+         "--fault", "answer_altered"],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    runs = [json.loads(line) for line in out.stdout.splitlines()
+            if line.startswith("{")]
+    return {(r["workload"], r["fault"]): r for r in runs}
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_forest_on_four_devices_runs_correct(forest_runs, name):
+    res = forest_runs[f"{name}.forest4", None]
+    assert res["correct"], res["checks"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+    assert res["implementations"] == {
+        "backend": "forest", "engine": "lockstep", "num_shards": 4,
+        "devices": [0, 1, 2, 3], "read_dispatch": "fused",
+        "dnodes_per_shard": rehearse.TINY_DNODES,
+        "walk": "ref_delta_walk_fused", "scan": "ref_delta_scan_fused"}
+    assert res["device"]["count"] == 4
+    assert len(res["device"]["memory_peak_bytes_by_device"]) == 4
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_forest_answer_altered_reads_incorrect(forest_runs, name):
+    res = forest_runs[f"{name}.forest4", "answer_altered"]
+    assert not res["correct"], res["checks"]
+    assert res["failed"] == 1
+
+
 def test_benchmark_cells_and_metrics_are_found_by_name():
     for name in CELLS:
         cell = harness.load_cell(name)
@@ -271,6 +445,26 @@ def test_union_and_coverage_on_known_intervals():
                     ("bench.batch", 1.0)]
 
 
+def test_device_time_by_device():
+    """Per device: busy seconds in the window (their mean is ``busy_s``)
+    and module time under ``module_ns``'s name filter (their sum is
+    ``module_ns``)."""
+    red = TRD.Reduced(
+        spans=[("bench.batch", 0, 10)], batches=np.array([[0, 10]], float),
+        busy=[TRD.union(np.array([[0, 4], [6, 8]], float)),
+              TRD.union(np.array([[2, 14]], float))],
+        modules={"jit_lookup_jit": 9.0, "jit_record": 1.0},
+        module_runs={"jit_lookup_jit": 2, "jit_record": 2}, ops={},
+        device_modules=[{"jit_lookup_jit": 5.0, "jit_record": 0.5},
+                        {"jit_lookup_jit": 4.0, "jit_record": 0.5}])
+    assert red.busy_s_by_device() == [pytest.approx(6e-9),
+                                      pytest.approx(8e-9)]
+    assert red.busy_s == pytest.approx(7e-9)
+    walk = lambda name: "lookup_jit" in name  # noqa: E731
+    assert red.module_ns_by_device(walk) == [5.0, 4.0]
+    assert red.module_ns(walk) == 9.0
+
+
 RECORDED = BENCH / "testdata" / "ycsb_c.1m.xplane.pb"
 
 
@@ -292,3 +486,8 @@ def test_reduction_of_a_recorded_chip_trace():
     assert [n for n, _ in bd["idle_gaps"]] == ["bench.fetch", "bench.search"]
     assert bd["idle_gaps"][0][1] == pytest.approx(0.017061475, rel=1e-9)
     assert 0 < len(bd["device_ops"]) <= TRD.TOP
+    # one chip: its plane is the one device (the trace's other device
+    # plane, the runtime's own, holds no ops)
+    assert red.busy_s_by_device() == [pytest.approx(red.busy_s, rel=1e-12)]
+    every = lambda name: True  # noqa: E731
+    assert red.module_ns_by_device(every) == [red.module_ns(every)]

@@ -107,10 +107,11 @@ def test_readers_read_the_rows_and_spans(traced, monkeypatch):
     # E's scan spans hold the runtime's back-pressure, not host work
     assert "dispatch_ms_per_batch.scans" not in m
     rows = PT.window_rows(view, "index.successor_k")
+    # the ring's own count: ΔNode rows read over keys emitted, however
+    # many rows the scan engine reads for a key
+    assert rows["hops_sum"].sum() > 0 and rows["emitted"].sum() > 0
     want = rows["hops_sum"].sum() / rows["emitted"].sum()
     assert m["scan_visits_per_key"]["value"] == pytest.approx(want)
-    # every emitted key costs at least one ΔNode visit
-    assert m["scan_visits_per_key"]["value"] >= 1
     n = len(view.window.results)
     # the reading is the process's total; one process builds once in a
     # benchmark run, several times here

@@ -8,9 +8,10 @@ plane, whose threads carry the benchmark's ``bench.*`` spans
 profiler's one clock.  From them:
 
 - busy time: the union of the device's op intervals (averaged over the
-  devices when there are several), and the traced window: from the start
-  of the first ``bench.batch`` span to the end of the last;
-- device time per XLA module name;
+  devices when there are several, and also kept per device), and the
+  traced window: from the start of the first ``bench.batch`` span to the
+  end of the last;
+- device time per XLA module name, summed over the devices and per device;
 - device idle gaps, each named by the innermost ``bench.*`` span that
   was open on the host at the gap's midpoint ("no span" when none was).
 
@@ -66,6 +67,8 @@ class Reduced:
     modules: dict          # module name -> total device ns (all devices)
     module_runs: dict      # module name -> executions (all devices)
     ops: dict              # op name -> total device ns (all devices)
+    device_modules: list = dataclasses.field(default_factory=list)
+    #                        per device of ``busy``: module name -> ns
 
     @property
     def window(self) -> tuple[float, float]:
@@ -88,9 +91,20 @@ class Reduced:
     def busy_s(self) -> float:
         return self.busy_ns(*self.window) * 1e-9
 
+    def busy_s_by_device(self) -> list[float]:
+        """Device-busy seconds inside the window, per device (``busy_s``
+        is their mean): the slowest chip, or the spread between chips."""
+        lo, hi = self.window
+        return [covered(b, lo, hi) * 1e-9 for b in self.busy]
+
     def module_ns(self, pred) -> float:
         """Device ns of the modules whose name satisfies ``pred``."""
         return float(sum(t for n, t in self.modules.items() if pred(n)))
+
+    def module_ns_by_device(self, pred) -> list[float]:
+        """``module_ns(pred)`` per device (the same devices as ``busy``)."""
+        return [float(sum(t for n, t in mods.items() if pred(n)))
+                for mods in self.device_modules]
 
     def gaps(self) -> list[tuple[str, float]]:
         """Idle gaps of the first device inside the window, as (name of the
@@ -145,13 +159,13 @@ def reduce_xplane(path: Path) -> Reduced:
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(str(path))
-    spans, busy = [], []
+    spans, busy, device_modules = [], [], []
     modules: dict[str, float] = {}
     runs: dict[str, int] = {}
     ops: dict[str, float] = {}
     for plane in pd.planes:
         if plane.name.startswith(DEVICE_PLANE):
-            iv = []
+            iv, mods = [], {}
             for line in plane.lines:
                 if line.name == OPS_LINE:
                     for ev in line.events:
@@ -161,9 +175,12 @@ def reduce_xplane(path: Path) -> Reduced:
                     for ev in line.events:
                         modules[ev.name] = (modules.get(ev.name, 0.0)
                                             + ev.duration_ns)
+                        mods[ev.name] = mods.get(ev.name, 0.0) + ev.duration_ns
                         runs[ev.name] = runs.get(ev.name, 0) + 1
+            # a device plane with no ops (the runtime's own) is no chip
             if iv:
                 busy.append(union(np.asarray(iv, np.float64)))
+                device_modules.append(mods)
         else:
             for line in plane.lines:
                 for ev in line.events:
@@ -171,7 +188,7 @@ def reduce_xplane(path: Path) -> Reduced:
                         spans.append((ev.name, ev.start_ns, ev.end_ns))
     batches = np.asarray(sorted((s, e) for n, s, e in spans
                                 if n == BATCH_SPAN), np.float64).reshape(-1, 2)
-    return Reduced(spans, batches, busy, modules, runs, ops)
+    return Reduced(spans, batches, busy, modules, runs, ops, device_modules)
 
 
 def reduce_trace(path) -> Reduced:
@@ -182,7 +199,8 @@ def reduce_trace(path) -> Reduced:
 
 def summary(red: Reduced) -> dict:
     return {"batches": int(len(red.batches)), "window_s": red.window_s,
-            "busy_s": red.busy_s, "modules_s": {
+            "busy_s": red.busy_s, "busy_s_by_device": red.busy_s_by_device(),
+            "modules_s": {
                 k: v * 1e-9 for k, v in sorted(red.modules.items())},
             "module_runs": dict(sorted(red.module_runs.items())),
             "breakdown": red.breakdown()}

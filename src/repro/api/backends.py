@@ -177,6 +177,7 @@ register_backend(BackendSpec(
     size=_forest_size,
     alloc_failed=lambda cfg, f: F.alloc_failed(f),
     flush=F.flush,
+    routing=F.routing,
     engines=("*",),   # per-shard reads dispatch on cfg.tree.engine
     maintenance=("*",),   # per-shard scheduler dispatch on cfg.tree.maintenance
 ))

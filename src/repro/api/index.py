@@ -19,7 +19,9 @@ Every concrete read, scan and update call opens one ``index.<method>``
 span (``repro.obs.trace``) that carries the call's sequence number
 ``seq`` and its ``lanes`` (scans also ``k``), and records the call's
 counters in the device ring of ``repro.obs.ring`` right after its own
-program is dispatched.  Calls made while JAX traces record nothing.
+program is dispatched; a sharded backend's point reads also hand the
+ring their keys and the backend's ``routing`` (its boundaries and
+dispatch layout).  Calls made while JAX traces record nothing.
 """
 
 from __future__ import annotations
@@ -129,6 +131,10 @@ class BackendSpec:
     touch: Callable[..., Any] | None = None     # (cfg, state) -> (key -> [flat indices])
     alloc_failed: Callable[..., bool] | None = None  # (cfg, state) -> bool
     flush: Callable[..., Any] | None = None     # (cfg, state) -> (state, stats)
+    routing: Callable[..., dict] | None = None  # (cfg, state) -> the router
+    #                                             inputs of a point read's
+    #                                             ring row (sharded backends:
+    #                                             splits, devices, lane_rows)
     engines: tuple[str, ...] = ("scalar",)      # selectable read engines
     maintenance: tuple[str, ...] = ("eager",)   # selectable policy kinds
 
@@ -208,6 +214,14 @@ class Index:
             OR.record(kind, seq, lanes, **counts(out))
         return out
 
+    def _routed(self, keys) -> dict:
+        """What the ring needs of a point read's routing: the sharded
+        backend's ``routing`` and the call's keys; nothing for others."""
+        routing = self.spec.backend.routing
+        if routing is None:
+            return {}
+        return routing(self.spec.cfg, self.state) | {"keys": keys}
+
     # ---- wait-free reads ----
 
     def search(self, keys: jax.Array):
@@ -216,7 +230,7 @@ class Index:
         return self._observed(
             "search", _lanes(keys),
             lambda: self.spec.backend.search(self.spec.cfg, self.state, keys),
-            lambda out: {"hops": out[1]})
+            lambda out: {"hops": out[1], **self._routed(keys)})
 
     def lookup(self, keys: jax.Array):
         """Map-mode read. Returns (found[K], payload[K], hops[K]) — plus
@@ -225,7 +239,7 @@ class Index:
         return self._observed(
             "lookup", _lanes(keys),
             lambda: self.spec.backend.lookup(self.spec.cfg, self.state, keys),
-            lambda out: {"hops": out[2]})
+            lambda out: {"hops": out[2], **self._routed(keys)})
 
     def successor(self, keys: jax.Array):
         """Smallest stored key strictly greater. Returns (found[K], succ[K])."""

@@ -235,31 +235,45 @@ class DeltaTree(NamedTuple):
 # --------------------------------------------------------------------------
 
 
-def empty(cfg: TreeConfig) -> DeltaTree:
-    m, ub, lc, bc = cfg.max_dnodes, cfg.ub, cfg.leaf_cap, cfg.buf_cap
+def empty_layout(cfg: TreeConfig) -> DeltaTree:
+    """An empty arena as host (numpy) arrays: the root pre-allocated,
+    every other ΔNode on the free stack.  ``empty`` uploads it; the
+    forest stacks its shards' layouts and places them, one per device."""
+    m, ub, lc = cfg.max_dnodes, cfg.ub, cfg.leaf_cap
     # free stack holds ids M-1 .. 1 (0 is the root, pre-allocated).
     free = np.zeros(m, dtype=np.int32)
     free[: m - 1] = np.arange(m - 1, 0, -1, dtype=np.int32)
     alive = np.zeros(m, dtype=bool)
     alive[0] = True
+    return _host_tree(cfg, value=np.full((m, ub), EMPTY, cfg.vdtype),
+                      child=np.full((m, lc), -1, np.int32),
+                      nlive=np.zeros((m,), np.int32),
+                      nchild=np.zeros((m,), np.int32),
+                      parent=np.full((m,), -1, np.int32),
+                      pslot=np.zeros((m,), np.int32), alive=alive,
+                      free_stack=free, free_top=m - 1, root=0)
+
+
+def _host_tree(cfg: TreeConfig, *, free_top: int, root: int,
+               **planes) -> DeltaTree:
+    """A host arena from the planes a layout fills; the mark, buffer and
+    flag planes start empty."""
+    m, ub, bc = cfg.max_dnodes, cfg.ub, cfg.buf_cap
     return DeltaTree(
-        value=jnp.full((m, ub), EMPTY, cfg.vdtype),
-        mark=jnp.zeros((m, ub), jnp.bool_),
-        child=jnp.full((m, lc), -1, jnp.int32),
-        buf=jnp.full((m, bc), EMPTY, cfg.vdtype),
-        nlive=jnp.zeros((m,), jnp.int32),
-        bcount=jnp.zeros((m,), jnp.int32),
-        nchild=jnp.zeros((m,), jnp.int32),
-        parent=jnp.full((m,), -1, jnp.int32),
-        pslot=jnp.zeros((m,), jnp.int32),
-        alive=jnp.asarray(alive),
-        free_stack=jnp.asarray(free),
-        free_top=jnp.int32(m - 1),
-        root=jnp.int32(0),
-        ins_flag=jnp.zeros((m,), jnp.bool_),
-        del_flag=jnp.zeros((m,), jnp.bool_),
-        alloc_fail=jnp.bool_(False),
+        mark=np.zeros((m, ub), bool),
+        buf=np.full((m, bc), EMPTY, cfg.vdtype),
+        bcount=np.zeros((m,), np.int32),
+        free_top=np.int32(free_top),
+        root=np.int32(root),
+        ins_flag=np.zeros((m,), bool),
+        del_flag=np.zeros((m,), bool),
+        alloc_fail=np.bool_(False),
+        **planes,
     )
+
+
+def empty(cfg: TreeConfig) -> DeltaTree:
+    return jax.device_put(empty_layout(cfg))
 
 
 def _pos(cfg: TreeConfig) -> jnp.ndarray:
@@ -1055,11 +1069,23 @@ def lookup_jit(cfg: TreeConfig, t: DeltaTree, keys: jax.Array):
 
 def bulk_build(cfg: TreeConfig, values: np.ndarray,
                payloads: np.ndarray | None = None) -> DeltaTree:
-    """Build a half-dense ΔTree from unique keys (any order). Host-side.
+    """Build a half-dense ΔTree from unique keys (any order): the host
+    layout (``bulk_layout``), then ``index.build.upload``, the arena to
+    the device, waited for."""
+    t = bulk_layout(cfg, values, payloads)
+    with OT.span("index.build.upload"):
+        t = jax.device_put(t)
+        jax.block_until_ready(t)
+    return t
+
+
+def bulk_layout(cfg: TreeConfig, values: np.ndarray,
+                payloads: np.ndarray | None = None) -> DeltaTree:
+    """The half-dense arena of unique keys (any order) as host (numpy)
+    arrays of every field.
 
     Spans (``repro.obs.trace``): ``index.build.sort`` (order and pack
-    the keys), ``index.build.layout`` (the Python loop over ΔNodes) and
-    ``index.build.upload`` (the arena to the device, waited for)."""
+    the keys) and ``index.build.layout`` (the Python loop over ΔNodes)."""
     with OT.span("index.build.sort"):
         values = np.asarray(values, dtype=np.int64)
         order = np.argsort(values)
@@ -1139,27 +1165,10 @@ def bulk_build(cfg: TreeConfig, values: np.ndarray,
         free = np.zeros(m, np.int32)
         nfree = m - next_id
         free[:nfree] = np.arange(m - 1, next_id - 1, -1, dtype=np.int32)
-    with OT.span("index.build.upload"):
-        t = DeltaTree(
-            value=jnp.asarray(value),
-            mark=jnp.zeros((m, ub), jnp.bool_),
-            child=jnp.asarray(child),
-            buf=jnp.full((m, cfg.buf_cap), EMPTY, cfg.vdtype),
-            nlive=jnp.asarray(nlive),
-            bcount=jnp.zeros((m,), jnp.int32),
-            nchild=jnp.asarray(nchild),
-            parent=jnp.asarray(parent),
-            pslot=jnp.asarray(pslot),
-            alive=jnp.asarray(alive),
-            free_stack=jnp.asarray(free),
-            free_top=jnp.int32(nfree),
-            root=jnp.int32(root),
-            ins_flag=jnp.zeros((m,), jnp.bool_),
-            del_flag=jnp.zeros((m,), jnp.bool_),
-            alloc_fail=jnp.bool_(False),
-        )
-        jax.block_until_ready(t)
-    return t
+    return _host_tree(cfg, value=value, child=child, nlive=nlive,
+                      nchild=nchild, parent=parent, pslot=pslot,
+                      alive=alive, free_stack=free, free_top=nfree,
+                      root=root)
 
 
 # --------------------------------------------------------------------------

@@ -3,7 +3,9 @@
 The forest is the scale-out layer over `repro.core` (DESIGN.md §4): each
 shard is a full ΔTree arena owning a contiguous key range, stacked into one
 pytree with a leading (S,) axis and driven through ``jax.shard_map`` over
-the "shards" mesh (`repro.launch.mesh.make_forest_mesh`).  The public API
+the "shards" mesh (`repro.launch.mesh.make_forest_mesh`).  The shards are
+laid out in host memory and placed from there, each device receiving
+only its own shards' rows.  The public API
 is a drop-in superset of `repro.core`:
 
     ForestConfig, Forest, empty, bulk_build,
@@ -57,6 +59,7 @@ from repro.core import engine as E
 from repro.distributed import router as R
 from repro.distributed import splits as SP
 from repro.maintenance import MaintenanceStats
+from repro.obs import trace as OT
 
 OP_SEARCH, OP_INSERT, OP_DELETE = DT.OP_SEARCH, DT.OP_INSERT, DT.OP_DELETE
 
@@ -108,21 +111,27 @@ class Forest(NamedTuple):
     epoch: jax.Array      # () int32 — arena-mutation counter (view cache key)
 
 
-def _new_forest(fcfg: ForestConfig, trees: list[DeltaTree],
+def _new_forest(fcfg: ForestConfig, hosts: list[DeltaTree],
                 splits) -> Forest:
     """A fresh forest laid out over the "shards" mesh its dispatch runs
-    on: each device holds its own stacked shard arenas, the small leaves
-    are replicated — the layout `update_batch` returns, so neither the
-    first read nor the second update reshards or recompiles."""
+    on, from its shards' host arenas (``deltatree.bulk_layout`` /
+    ``empty_layout``): stacked with numpy and placed in one
+    ``device_put``, so each device receives only its own shards' rows and
+    no device ever holds the whole forest; the small leaves are
+    replicated.  That is the layout `update_batch` returns, so neither
+    the first read nor the second update reshards or recompiles.  The
+    placement, waited for, is the ``index.build.place`` span."""
     mesh = R.forest_mesh(fcfg.num_shards)
-    f = Forest(trees=jax.tree.map(lambda *xs: jnp.stack(xs), *trees),
-               splits=_as_splits(fcfg, splits),
-               reads=_zero_counters(fcfg), updates=_zero_counters(fcfg),
-               epoch=jnp.int32(0))
     shard, rep = NamedSharding(mesh, P("shards")), NamedSharding(mesh, P())
-    return jax.device_put(f, f._replace(
-        trees=jax.tree.map(lambda _: shard, f.trees), splits=rep, reads=rep,
-        updates=rep, epoch=rep))
+    with OT.span("index.build.place"):
+        trees = jax.device_put(
+            jax.tree.map(lambda *xs: np.stack(xs), *hosts), shard)
+        small = jax.device_put(
+            (_as_splits(fcfg, splits), _zero_counters(fcfg),
+             _zero_counters(fcfg), jnp.int32(0)), rep)
+        f = Forest(trees, *small)
+        jax.block_until_ready(f)
+    return f
 
 
 def shard_tree(forest: Forest, s: int) -> DeltaTree:
@@ -149,13 +158,15 @@ def _zero_counters(fcfg: ForestConfig) -> jax.Array:
 
 
 def empty(fcfg: ForestConfig, splits=None) -> Forest:
-    return _new_forest(fcfg, [DT.empty(fcfg.tree)
-                              for _ in range(fcfg.num_shards)], splits)
+    host = DT.empty_layout(fcfg.tree)
+    return _new_forest(fcfg, [host] * fcfg.num_shards, splits)
 
 
 def bulk_build(fcfg: ForestConfig, values: np.ndarray,
                payloads: np.ndarray | None = None, splits=None) -> Forest:
-    """Build a forest from unique keys (host-side, like core bulk_build).
+    """Build a forest from unique keys: each shard's arena is laid out on
+    the host (``deltatree.bulk_layout``), then all are placed at once,
+    one shard's rows per device (`_new_forest`).
 
     With no explicit ``splits`` the boundaries are equi-depth over
     ``values`` — every shard starts with |values|/S keys regardless of the
@@ -173,7 +184,7 @@ def bulk_build(fcfg: ForestConfig, values: np.ndarray,
     trees = []
     for s in range(fcfg.num_shards):
         mask = sid == s
-        trees.append(DT.bulk_build(
+        trees.append(DT.bulk_layout(
             fcfg.tree, values[mask],
             payloads[mask] if payloads is not None else None))
     return _new_forest(fcfg, trees, splits)
@@ -249,7 +260,8 @@ def _maybe_cached_view(fcfg: ForestConfig, f: Forest):
         _VIEW_STATS["hits"] += 1
         _VIEW_CACHE.move_to_end(key)
         return ent[1]
-    view = _build_view(fcfg, f.trees)
+    with OT.span("index.forest.view"):
+        view = _build_view(fcfg, f.trees)
     _VIEW_STATS["builds"] += 1
     # one live view per fcfg: a rebuild means the arena moved on (update /
     # different forest), so the old epoch's view is dead weight — arena-
@@ -284,7 +296,7 @@ def search_batch(fcfg: ForestConfig, f: Forest, keys: jax.Array):
 def lookup_batch(fcfg: ForestConfig, f: Forest, keys: jax.Array):
     """Routed map-mode lookup. Returns (found[K], payload[K], hops[K]) —
     plus a trailing `ReadStats` when ``fcfg.tree.collect_stats`` is on."""
-    return _lookup_core(fcfg, f, keys, _maybe_cached_view(fcfg, f))
+    return forest_lookup(fcfg, f, keys, _maybe_cached_view(fcfg, f))
 
 
 @functools.partial(jax.jit, static_argnums=0)
@@ -298,7 +310,10 @@ def _search_core(fcfg: ForestConfig, f: Forest, keys: jax.Array, view):
 
 
 @functools.partial(jax.jit, static_argnums=0)
-def _lookup_core(fcfg: ForestConfig, f: Forest, keys: jax.Array, view):
+def forest_lookup(fcfg: ForestConfig, f: Forest, keys: jax.Array, view):
+    """The map-mode read program.  Its name is the XLA module's
+    (``jit_forest_lookup``) on every device of the mesh, which is how a
+    device trace finds the forest's walk."""
     return _lookup(fcfg, f, keys, view)
 
 
@@ -590,6 +605,16 @@ def record_reads(fcfg: ForestConfig, f: Forest, keys: jax.Array) -> Forest:
     loop opts into — the read path never grows a hidden side effect."""
     sid = R.shard_ids(f.splits, _route_keys(keys))
     return f._replace(reads=f.reads + R.lane_counts(sid, fcfg.num_shards))
+
+
+def routing(fcfg: ForestConfig, f: Forest) -> dict:
+    """What a point read's counters (``repro.obs.ring``) need of its
+    routing: the boundaries, the devices the shards spread over, and the
+    lane rows the read dispatch walks — one per device under the fused
+    frontier ((D, K) lanes), one per shard under the vmap ((S, K))."""
+    d = R.forest_mesh(fcfg.num_shards).devices.size
+    return {"splits": f.splits, "devices": d,
+            "lane_rows": d if _fused(fcfg) is not None else fcfg.num_shards}
 
 
 def shard_load(f: Forest) -> dict:

@@ -10,12 +10,14 @@ measurements:
   never touches a compiled program.  Every concrete ``Index`` call opens
   ``index.<method>`` with its sequence number ``seq``; ``make_index``
   opens ``index.build`` (children ``index.build.sort``, ``.layout``,
-  ``.upload``); the serve scheduler opens ``serve.*``.
+  ``.upload``, and a forest's ``.place``); a forest's fused-view build
+  opens ``index.forest.view``; the serve scheduler opens ``serve.*``.
   ``trace.capture(logdir)`` records an xplane of a region.
 - **Per-call counters** (`obs.ring`): each concrete ``Index`` call
   writes one row (``seq``, kind, lanes, ``hops_sum``/``hops_max``, a
   scan's ``emitted``/``truncated``, an update's ``MaintenanceStats`` and
-  ``free_top``) into an int32 ring on the device, by a small program
+  ``free_top``, a sharded point read's ``lanes_dev_max``/``lanes_walked``)
+  into an int32 ring on the device, by a small program
   dispatched after the call's own; the host waits on it only when it is
   read: ``obs.calls(seqs)`` (numpy rows, joined to the spans by
   ``seq``) and ``ring.totals()`` (running sums, also in

@@ -22,6 +22,20 @@ never holds up another thread's ``record``.
 The columns reuse the names of ``SearchStats``, ``ScanStats`` and
 ``MaintenanceStats``.  ``hops_max`` is the call's lockstep round count;
 a call whose backend returns no ``hops`` records ``lanes`` alone.
+
+Two columns count what a sharded backend's router adds to a point read
+(``search``, ``lookup``), from the boundaries and the keys the call
+passes (``splits``, ``keys``) and the layout of its dispatch:
+
+- ``lanes_dev_max``: the real lanes of the device that owns the most of
+  the call's keys (a key's shard by ``searchsorted(splits, key,
+  "right")``, its device by the mesh's even split of the shards);
+- ``lanes_walked``: the lanes the dispatch walks, ``lane_rows`` rows of
+  the call's lanes each: one row per device for the fused frontier (D x
+  K on D devices, K on one), one per shard for the vmap dispatch.
+
+Every other call, and every call of an unsharded backend, writes 0 in
+both.
 """
 
 from __future__ import annotations
@@ -37,11 +51,13 @@ KINDS = ("search", "lookup", "successor", "successor_k", "scan",
          "update", "flush")
 COLUMNS = ("seq", "kind", "lanes", "hops_sum", "hops_max", "emitted",
            "truncated", "rounds", "rebuilds", "expands", "merges",
-           "pending", "reclaimed", "free_top")
-ROWS = 4096                  # 4096 x 14 int32 = 224 KiB on the device
+           "pending", "reclaimed", "free_top", "lanes_dev_max",
+           "lanes_walked")
+ROWS = 4096                  # 4096 x 16 int32 = 256 KiB on the device
 # columns that add up over calls; pending and free_top are levels
 SUMMED = ("lanes", "hops_sum", "hops_max", "emitted", "truncated",
-          "rounds", "rebuilds", "expands", "merges", "reclaimed")
+          "rounds", "rebuilds", "expands", "merges", "reclaimed",
+          "lanes_dev_max", "lanes_walked")
 _MAINT = ("rounds", "rebuilds", "expands", "merges", "pending",
           "reclaimed")
 _SUM_AT = [COLUMNS.index(c) for c in SUMMED]
@@ -75,8 +91,9 @@ def last_seq() -> int | None:
     return getattr(_LOCAL, "seq", None)
 
 
-@functools.partial(jax.jit, static_argnums=0, donate_argnums=(1, 2))
-def _record(kind, ring, tot, seq, lanes, hops, n, more, maint, free_top):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2), donate_argnums=(3, 4))
+def _record(kind, devices, lane_rows, ring, tot, seq, lanes, hops, n, more,
+            maint, free_top, splits, keys):
     z = jnp.int32(0)
     i32 = lambda x: jnp.asarray(x).astype(jnp.int32)
     col = dict.fromkeys(COLUMNS, z)
@@ -90,6 +107,14 @@ def _record(kind, ring, tot, seq, lanes, hops, n, more, maint, free_top):
         col.update({f: i32(getattr(maint, f)) for f in _MAINT})
     if free_top is not None:
         col.update(free_top=jnp.sum(i32(free_top)))
+    if splits is not None:
+        # the boundaries widen to the key dtype, as the router's do
+        sid = jnp.searchsorted(splits.astype(keys.dtype), keys, side="right")
+        dev = sid // ((splits.shape[0] + 1) // devices)
+        col.update(
+            lanes_dev_max=jnp.max(jnp.zeros((devices,), jnp.int32).at[
+                dev].add(1)),
+            lanes_walked=jnp.int32(lane_rows * keys.shape[0]))
     row = jnp.stack([i32(col[c]) for c in COLUMNS])
     ring = ring.at[row[0] % ROWS].set(row)
     add = jnp.concatenate([jnp.ones((1,), jnp.int32), row[jnp.asarray(
@@ -104,25 +129,28 @@ def _placed(args, sharding):
     """Move the call's outputs next to the ring when they live elsewhere
     (a forest's outputs are spread over its mesh)."""
     for x in args:
-        if isinstance(x, jax.Array):
-            return args if x.sharding == sharding else jax.device_put(
-                args, sharding)
+        if isinstance(x, jax.Array) and x.sharding != sharding:
+            return jax.device_put(args, sharding)
     return args
 
 
 def record(kind: str, seq: int, lanes: int, *, hops=None, n=None,
-           more=None, maint=None, free_top=None) -> None:
+           more=None, maint=None, free_top=None, splits=None, keys=None,
+           devices: int = 1, lane_rows: int = 0) -> None:
     """Write call ``seq``'s row: dispatches one small program, waits for
-    nothing."""
+    nothing.  A sharded backend's point read also passes its
+    ``splits``, its ``keys``, the ``devices`` its shards spread over and
+    the ``lane_rows`` its dispatch walks (see the module docstring)."""
     k = KINDS.index(kind)
     with _LOCK:
         if _S.ring is None:   # uploaded from the host: no program to load
             _S.ring = jnp.asarray(np.full((ROWS, len(COLUMNS)), -1, np.int32))
             _S.tot = jnp.asarray(
                 np.zeros((len(KINDS), 1 + len(SUMMED), 2), np.int32))
-        args = _placed((hops, n, more, maint, free_top), _S.ring.sharding)
-        _S.ring, _S.tot = _record(k, _S.ring, _S.tot, np.int32(seq),
-                                  np.int32(lanes), *args)
+        args = _placed((hops, n, more, maint, free_top, splits, keys),
+                       _S.ring.sharding)
+        _S.ring, _S.tot = _record(k, devices, lane_rows, _S.ring, _S.tot,
+                                  np.int32(seq), np.int32(lanes), *args)
 
 
 @jax.jit

@@ -363,9 +363,12 @@ want = []
 logdir = tempfile.mkdtemp()
 with trace.capture(logdir):
     found, pay, hops = ix.lookup(q)
-    want.append(("index.lookup", dict(
-        lanes=q.shape[0], hops_sum=int(hops.sum()),
-        hops_max=int(hops.max()))))
+    w = dict(lanes=q.shape[0], hops_sum=int(hops.sum()),
+             hops_max=int(hops.max()))
+    if backend == "forest":
+        # one shard on one device: every key there, one row of lanes
+        w.update(lanes_dev_max=q.shape[0], lanes_walked=q.shape[0])
+    want.append(("index.lookup", w))
     _, _, n, hops, more = ix.successor_k(q[:64], 8)
     want.append(("index.successor_k", dict(
         lanes=64, hops_sum=int(hops.sum()), hops_max=int(hops.max()),

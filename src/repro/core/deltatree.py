@@ -377,22 +377,20 @@ def lookup_batch(cfg: TreeConfig, t: DeltaTree, keys: jax.Array):
 
 
 # --------------------------------------------------------------------------
-# allocation helpers
+# row access — one ΔNode row of a plane, read or written in place
 # --------------------------------------------------------------------------
 
 
-def _alloc(cfg: TreeConfig, t: DeltaTree):
-    """Pop a ΔNode id off the freelist. Returns (t, cid). Sticky-fails when
-    exhausted (cid = root is returned but alloc_fail is set; tests assert)."""
-    ok = t.free_top > 0
-    top = jnp.maximum(t.free_top - 1, 0)
-    cid = t.free_stack[top]
-    t = t._replace(
-        free_top=jnp.where(ok, top, t.free_top),
-        alive=t.alive.at[cid].set(True),
-        alloc_fail=t.alloc_fail | ~ok,
-    )
-    return t, cid
+def _row(plane, r):
+    """Row ``r`` of an (M, ...) plane (a dynamic slice)."""
+    return jax.lax.dynamic_index_in_dim(plane, r, keepdims=False)
+
+
+def _put(plane, r, row):
+    """``plane`` with row ``r`` replaced (a dynamic-update-slice: inside a
+    loop whose carry holds the plane it updates in place, with no copy)."""
+    row = jnp.asarray(row, plane.dtype)
+    return jax.lax.dynamic_update_index_in_dim(plane, row, r, 0)
 
 
 def _free(cfg: TreeConfig, t: DeltaTree, dn):
@@ -476,61 +474,38 @@ def _gather_live(cfg: TreeConfig, t: DeltaTree, dn):
     return allv, count
 
 
-def _rebalance(cfg: TreeConfig, t: DeltaTree, dn) -> DeltaTree:
-    """Paper REBALANCE: rebuild ``dn``'s (childless) tree at minimal height
-    from its live leaves + buffer; functional mirror-swap."""
-    allv, m = _gather_live(cfg, t, dn)
-    row = _rebuild_row(cfg, allv, m)
-    return t._replace(
-        value=t.value.at[dn].set(row),
-        mark=t.mark.at[dn].set(False),
-        buf=t.buf.at[dn].set(EMPTY),
-        nlive=t.nlive.at[dn].set(m),
-        bcount=t.bcount.at[dn].set(0),
-        ins_flag=t.ins_flag.at[dn].set(False),
-    )
-
-
 # --------------------------------------------------------------------------
 # single-op primitives (paper Fig. 9) — applied in batch order
 # --------------------------------------------------------------------------
 
 
-def _buf_append(cfg: TreeConfig, t: DeltaTree, dn, pv):
-    """Append packed value to dn's buffer (paper Fig. 9 line 89)."""
-    slot_free = t.buf[dn] == EMPTY
-    ok = jnp.any(slot_free)
+def _buf_append_row(brow, pv, do=True):
+    """Append packed value to a buffer row's first free slot when ``do``
+    (paper Fig. 9 line 89).  Returns (row, ok): ``ok`` is False when the
+    row is full, and the row is then unchanged."""
+    slot_free = brow == EMPTY
+    ok = do & jnp.any(slot_free)
     j = jnp.argmax(slot_free)
-    t = t._replace(
-        buf=t.buf.at[dn, j].set(jnp.where(ok, pv, t.buf[dn, j])),
-        bcount=t.bcount.at[dn].add(jnp.where(ok, jnp.int32(1), jnp.int32(0))),
-        ins_flag=t.ins_flag.at[dn].set(jnp.where(ok, True, t.ins_flag[dn])),
-    )
-    return t, ok
+    return brow.at[j].set(jnp.where(ok, pv, brow[j])), ok
 
 
-def _grow_leaf(cfg: TreeConfig, t: DeltaTree, dn, b, pv):
-    """Paper Fig. 9 lines 50..72: leaf x grows into internal(router=max) with
-    leaves (min, max). Preserves x's mark on x's new position."""
+def _grow_rows(cfg: TreeConfig, vrow, mrow, b, pv):
+    """Paper Fig. 9 lines 50..72 on one ΔNode's value and mark rows: leaf
+    x at BFS ``b`` (above the bottom row) grows into internal(router=max)
+    with leaves (min, max).  Preserves x's mark on x's new position."""
     pos = _pos(cfg)
-    x = t.value[dn, pos[b]]
-    xm = t.mark[dn, pos[b]]
+    x = vrow[pos[b]]
+    xm = mrow[pos[b]]
     v_lt = cfg.key_of(pv) < cfg.key_of(x)
     lo = jnp.where(v_lt, pv, x)
     hi = jnp.where(v_lt, x, pv)
     x_is_lo = v_lt  # x is hi iff new value is smaller
     lpos, rpos = pos[2 * b], pos[2 * b + 1]
-    t = t._replace(
-        value=t.value.at[dn, lpos].set(lo).at[dn, rpos].set(hi)
-        .at[dn, pos[b]].set(hi),
-        mark=(
-            t.mark.at[dn, lpos].set(jnp.where(x_is_lo, False, xm))
-            .at[dn, rpos].set(jnp.where(x_is_lo, xm, False))
-            .at[dn, pos[b]].set(False)
-        ),
-        nlive=t.nlive.at[dn].add(jnp.int32(1)),
-    )
-    return t
+    vrow = vrow.at[lpos].set(lo).at[rpos].set(hi).at[pos[b]].set(hi)
+    mrow = (mrow.at[lpos].set(jnp.where(x_is_lo, False, xm))
+            .at[rpos].set(jnp.where(x_is_lo, xm, False))
+            .at[pos[b]].set(False))
+    return vrow, mrow
 
 
 def _insert_op(cfg: TreeConfig, t: DeltaTree, key, payload,
@@ -570,14 +545,23 @@ def _insert_op(cfg: TreeConfig, t: DeltaTree, key, payload,
         return tt, jnp.bool_(True), jnp.bool_(False)
 
     def case_grow(t):
-        return _grow_leaf(cfg, t, dn, b, pv), jnp.bool_(True), jnp.bool_(False)
+        vrow, mrow = _grow_rows(cfg, _row(t.value, dn), _row(t.mark, dn),
+                                b, pv)
+        tt = t._replace(value=_put(t.value, dn, vrow),
+                        mark=_put(t.mark, dn, mrow),
+                        nlive=t.nlive.at[dn].add(jnp.int32(1)))
+        return tt, jnp.bool_(True), jnp.bool_(False)
 
     def case_buffer(t):
         def dup(t):
             return t, jnp.bool_(False), jnp.bool_(False)
 
         def app(t):
-            tt, ok = _buf_append(cfg, t, dn, pv)
+            brow, ok = _buf_append_row(_row(t.buf, dn), pv)
+            tt = t._replace(
+                buf=_put(t.buf, dn, brow),
+                bcount=t.bcount.at[dn].add(ok.astype(jnp.int32)),
+                ins_flag=t.ins_flag.at[dn].set(ok | t.ins_flag[dn]))
             # buffer full -> op stays pending, retried after maintenance
             return tt, ok, ~ok
 
@@ -638,108 +622,161 @@ def _delete_op(cfg: TreeConfig, t: DeltaTree, key, dn0=None, b0=None):
 # --------------------------------------------------------------------------
 
 
+# what one buffered item of an Expand pass did (``_expand_item``)
+EXP_MOVED, EXP_KEPT, EXP_DUP, EXP_PLACE, EXP_GROW, EXP_EXPAND = range(6)
+
+
+def _expand_item(cfg: TreeConfig, t: DeltaTree, dn, i):
+    """Route the item in slot ``i`` of ΔNode ``dn``'s buffer one hop toward
+    its home (paper Fig. 9 lines 92..106).  Returns (t, outcome), one of:
+
+    - ``EXP_MOVED``: it descends into a descendant ΔNode, and moves into
+      that ΔNode's buffer; ``EXP_KEPT`` when that buffer is full, and the
+      item stays in ``dn``'s;
+    - ``EXP_DUP``: a leaf of ``dn`` holds its key (revived with the item's
+      payload if marked), ``EXP_PLACE``: it fills an empty leaf,
+      ``EXP_GROW``: it grows a leaf above the bottom row;
+    - ``EXP_EXPAND``: it lands on an occupied childless bottom leaf, which
+      is EXPANDed into a fresh child ΔNode (paper Fig. 5b) seeded with the
+      leaf's live value; the item moves into the child's buffer and the
+      leaf stays as the child's marker.
+
+    The outcome is computed as small values, and the rows it may touch are
+    written back unconditionally with dynamic-update-slices, each with its
+    old content where the outcome leaves it alone: no branch carries the
+    tree, so the arena planes are updated in place, never copied.  The
+    writes keep the order of the per-outcome branches this replaces, so an
+    exhausted arena (the popped id then aliases a live row) leaves the
+    same planes.
+    """
+    pos = _pos(cfg)
+    bottom0 = cfg.bottom0
+    dbuf = _row(t.buf, dn)
+    pv = dbuf[i]
+    key = cfg.key_of(pv)
+    # drop from this buffer first; re-added below if it must stay
+    dbuf_out = dbuf.at[i].set(EMPTY)
+    tdn, b, _ = _descend(cfg, t, cfg.qpack(key), dn, 1)
+    vrow, mrow, crow = (_row(t.value, tdn), _row(t.mark, tdn),
+                        _row(t.child, tdn))
+    p = pos[b]
+    leaf_val, leaf_mark = vrow[p], mrow[p]
+    leaf_hit = (leaf_val != EMPTY) & (cfg.key_of(leaf_val) == key)
+    moved = tdn != dn
+    room = jnp.any(_row(t.buf, tdn) == EMPTY)
+    kind = jnp.where(
+        moved, jnp.where(room, EXP_MOVED, EXP_KEPT),
+        jnp.where(leaf_hit, EXP_DUP,
+                  jnp.where(leaf_val == EMPTY, EXP_PLACE,
+                            jnp.where(b < bottom0, EXP_GROW, EXP_EXPAND))))
+    dup, place = kind == EXP_DUP, kind == EXP_PLACE
+    grow, expand = kind == EXP_GROW, kind == EXP_EXPAND
+
+    # the child: popped off the free stack (sticky alloc_fail when empty;
+    # the popped id is then whatever the stack's bottom holds, maybe live)
+    ok = t.free_top > 0
+    top = jnp.maximum(t.free_top - 1, 0)
+    cid = t.free_stack[top]
+    slot = jnp.maximum(b - bottom0, 0)
+    x_live = ~leaf_mark
+    mseed = (expand & x_live).astype(jnp.int32)
+    seed_row = _rebuild_row(cfg, jnp.full(
+        (1,), jnp.where(x_live, leaf_val, cfg.route_left), cfg.vdtype),
+        mseed)
+
+    # tdn's rows: dup / place write the leaf, grow splits it, expand
+    # clears its mark and links the child (its value row stays, so the
+    # one value write is the child's seed row)
+    gv, gm = _grow_rows(cfg, vrow, mrow, jnp.minimum(b, bottom0 - 1), pv)
+    at_p = vrow.at[p].set(jnp.where(dup & ~leaf_mark, leaf_val, pv))
+    vrow = jnp.where(grow, gv, jnp.where(dup | place, at_p, vrow))
+    mrow = jnp.where(grow, gm, jnp.where(dup | place | expand,
+                                         mrow.at[p].set(False), mrow))
+    crow = jnp.where(expand, crow.at[slot].set(cid), crow)
+
+    # the item's new buffer: the descendant's, dn's when that is full, or
+    # the child's
+    dest = jnp.where(kind == EXP_MOVED, tdn, jnp.where(expand, cid, dn))
+    brow, app = _buf_append_row(
+        jnp.where(dest == dn, dbuf_out, _row(t.buf, dest)), pv,
+        moved | expand)
+
+    # Every write below reads only the planes as the item found them (a
+    # row read after a write to its plane would make the compiler copy
+    # the plane), and writes to one row land in the branchy pass's order:
+    # where two rows alias (the child popped from an exhausted arena),
+    # the later write wins as it did there.
+    app_i = app.astype(jnp.int32)
+    bc_dn = t.bcount[dn] - 1
+    nl_tdn = jnp.where(expand & (cid == tdn), mseed, t.nlive[tdn])
+    nl_tdn += jnp.where(dup, leaf_mark.astype(jnp.int32),
+                        jnp.where(place | grow, 1, -mseed))
+    t = t._replace(
+        value=_put(t.value, jnp.where(expand, cid, tdn),
+                   jnp.where(expand, seed_row, vrow)),
+        mark=_put(t.mark, tdn, mrow),
+        child=_put(t.child, tdn, crow),
+        buf=_put(_put(t.buf, dn, dbuf_out), dest, brow),
+        bcount=_put(_put(t.bcount, dn, bc_dn), dest,
+                    jnp.where(dest == dn, bc_dn, t.bcount[dest]) + app_i),
+        ins_flag=_put(t.ins_flag, dest, app | t.ins_flag[dest]),
+        nlive=_put(_put(t.nlive, cid,
+                        jnp.where(expand, mseed, t.nlive[cid])),
+                   tdn, nl_tdn),
+        nchild=_put(t.nchild, tdn, t.nchild[tdn] + expand),
+        parent=_put(t.parent, cid, jnp.where(expand, tdn, t.parent[cid])),
+        pslot=_put(t.pslot, cid, jnp.where(expand, slot, t.pslot[cid])),
+        alive=_put(t.alive, cid, expand | t.alive[cid]),
+        free_top=jnp.where(expand & ok, top, t.free_top),
+        alloc_fail=t.alloc_fail | (expand & ~ok),
+    )
+    return t, kind
+
+
 def _process_ins(cfg: TreeConfig, t: DeltaTree, dn):
     """Insert-side repair of ΔNode ``dn`` (Rebalance or Expand).  Returns
     (t, rebuilds, expands) — the int32 deltas feed ``MaintenanceStats``
-    (expands counts child ΔNodes allocated)."""
+    (expands counts child ΔNodes allocated).
+
+    The Expand pass runs `_expand_item` on the buffer's occupied slots in
+    slot order.  An item that must stay goes to the first free slot, at or
+    before its own, so the slots still ahead hold what the pass started
+    with: the pass sees exactly the items, in the order, that a pass over
+    every slot sees.  Each item writes only the rows it touches, in place;
+    the trees are bit for bit those of the per-item ``lax.cond`` /
+    ``lax.switch`` pass it replaces (each branch of which returned the
+    whole tree, and so made the compiler copy every arena plane it carried
+    for every slot — ~0.5 GB per slot at 151,024 ΔNodes).
+    """
     dn = jnp.asarray(dn, jnp.int32)
-    pos = _pos(cfg)
     total = t.nlive[dn] + t.bcount[dn]
-    childless_small = (t.nchild[dn] == 0) & (total <= cfg.half_cap)
+    rebuild = (t.nchild[dn] == 0) & (total <= cfg.half_cap)
+    # Expand: the buffer's items, none when dn is rebuilt instead
+    occ = _row(t.buf, dn) != EMPTY
+    slots = jnp.nonzero(occ, size=cfg.buf_cap, fill_value=0)[0]
+    ft0 = t.free_top
+    t = jax.lax.fori_loop(
+        0, jnp.where(rebuild, 0, jnp.sum(occ, dtype=jnp.int32)),
+        lambda j, t: _expand_item(cfg, t, dn, slots[j])[0], t)
+    # Rebalance (paper REBALANCE): dn's childless tree rebuilt at minimal
+    # height from its live leaves + buffer, a functional mirror-swap; an
+    # Expanded dn's rows are written back as they are
+    allv, m = _gather_live(cfg, t, dn)
 
-    def do_rebalance(t):
-        return _rebalance(cfg, t, dn), jnp.int32(1), jnp.int32(0)
+    def swap(plane, row):
+        return _put(plane, dn, jnp.where(rebuild, row, _row(plane, dn)))
 
-    def do_expand(t):
-        # Route every buffered value one hop toward its home: place/grow in
-        # this ΔNode, move into a child's buffer, or EXPAND a full bottom
-        # leaf into a fresh child ΔNode (paper Fig. 5b) and move into it.
-        def body(i, t):
-            pv = t.buf[dn, i]
-            key = cfg.key_of(pv)
-            qv = cfg.qpack(key)
-
-            def handle(t):
-                # drop from this buffer first; re-add below if it must stay
-                t = t._replace(
-                    buf=t.buf.at[dn, i].set(EMPTY),
-                    bcount=t.bcount.at[dn].add(-1),
-                )
-                tdn, b, _ = _descend(cfg, t, qv, dn, 1)
-                leaf_val = t.value[tdn, pos[b]]
-                leaf_mark = t.mark[tdn, pos[b]]
-                leaf_hit = (leaf_val != EMPTY) & (cfg.key_of(leaf_val) == key)
-
-                def moved(t):  # landed in a descendant ΔNode -> its buffer
-                    tt, ok = _buf_append(cfg, t, tdn, pv)
-
-                    def keep(tt):
-                        tt2, _ = _buf_append(cfg, tt, dn, pv)
-                        return tt2
-
-                    return jax.lax.cond(ok, lambda x: x, keep, tt)
-
-                def local(t):
-                    def dup(t):
-                        return t._replace(
-                            value=t.value.at[tdn, pos[b]].set(
-                                jnp.where(leaf_mark, pv, leaf_val)),
-                            mark=t.mark.at[tdn, pos[b]].set(False),
-                            nlive=t.nlive.at[tdn].add(
-                                jnp.where(leaf_mark, jnp.int32(1), jnp.int32(0))),
-                        )
-
-                    def place(t):
-                        return t._replace(
-                            value=t.value.at[tdn, pos[b]].set(pv),
-                            mark=t.mark.at[tdn, pos[b]].set(False),
-                            nlive=t.nlive.at[tdn].add(jnp.int32(1)),
-                        )
-
-                    def grow(t):
-                        return _grow_leaf(cfg, t, tdn, b, pv)
-
-                    def expand(t):
-                        # occupied childless bottom leaf: allocate child
-                        # seeded with the leaf's live value; pv moves into
-                        # the child's (empty) buffer. Leaf stays as marker.
-                        slot = b - cfg.bottom0
-                        t, cid = _alloc(cfg, t)
-                        x_live = ~leaf_mark
-                        seed = jnp.where(x_live, leaf_val, cfg.route_left)
-                        mseed = x_live.astype(jnp.int32)
-                        row = _rebuild_row(
-                            cfg, jnp.full((1,), seed, cfg.vdtype), mseed)
-                        t = t._replace(
-                            value=t.value.at[cid].set(row),
-                            nlive=t.nlive.at[cid].set(mseed).at[tdn].add(-mseed),
-                            parent=t.parent.at[cid].set(tdn),
-                            pslot=t.pslot.at[cid].set(slot),
-                            child=t.child.at[tdn, slot].set(cid),
-                            nchild=t.nchild.at[tdn].add(jnp.int32(1)),
-                            mark=t.mark.at[tdn, pos[b]].set(False),
-                        )
-                        t, _ = _buf_append(cfg, t, cid, pv)
-                        return t
-
-                    branch = jnp.where(
-                        leaf_hit, 0,
-                        jnp.where(
-                            leaf_val == EMPTY, 1,
-                            jnp.where(b < cfg.bottom0, 2, 3)),
-                    )
-                    return jax.lax.switch(branch, [dup, place, grow, expand], t)
-
-                return jax.lax.cond(tdn != dn, moved, local, t)
-
-            return jax.lax.cond(pv == EMPTY, lambda t: t, handle, t)
-
-        ft0 = t.free_top
-        t = jax.lax.fori_loop(0, cfg.buf_cap, body, t)
-        t = t._replace(ins_flag=t.ins_flag.at[dn].set(t.bcount[dn] > 0))
-        return t, jnp.int32(0), (ft0 - t.free_top).astype(jnp.int32)
-
-    return jax.lax.cond(childless_small, do_rebalance, do_expand, t)
+    bcount = jnp.where(rebuild, 0, t.bcount[dn])
+    t = t._replace(
+        value=swap(t.value, _rebuild_row(cfg, allv, m)),
+        mark=swap(t.mark, False),
+        buf=swap(t.buf, EMPTY),
+        nlive=_put(t.nlive, dn, jnp.where(rebuild, m, t.nlive[dn])),
+        bcount=_put(t.bcount, dn, bcount),
+        ins_flag=_put(t.ins_flag, dn, bcount > 0),
+    )
+    return (t, rebuild.astype(jnp.int32),
+            (ft0 - t.free_top).astype(jnp.int32))
 
 
 # --------------------------------------------------------------------------

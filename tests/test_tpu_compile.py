@@ -14,6 +14,7 @@ imported, so that only the worker that runs this file loads the TPU
 library.
 """
 
+import re
 from pathlib import Path
 
 import jax
@@ -21,6 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import deltatree as DT
 from repro.kernels import ops as OPS
 from repro.kernels import veb_search as V
 from repro.kernels.delta_paged_attention import _paged_decode_attention
@@ -117,6 +119,57 @@ def test_paged_decode_attention_compiles_at_granite_geometry(one_chip, x64):
                          ((n_pages, kvh, ps, d), jnp.bfloat16),
                          ((b, maxp), jnp.int32), ((b,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def _loop_copies(text, m):
+    """(computation, shape) of every ``copy``/``copy-start`` of an (m, ...)
+    array in a while body, or in what a while body calls (its branches,
+    nested loops, fusions), of an optimised HLO module's text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    calls, bodies = {}, []
+    for name, lines in comps.items():
+        text_c = "\n".join(lines)
+        calls[name] = set(re.findall(
+            r"(?:body|condition|to_apply|calls|true_computation|"
+            r"false_computation)=%?([\w.\-]+)", text_c))
+        for group in re.findall(r"branch_computations=\{([^}]*)\}", text_c):
+            calls[name].update(x.strip().lstrip("%") for x in group.split(","))
+        bodies += re.findall(r"body=%?([\w.\-]+)", text_c)
+    inside, todo = set(), bodies
+    while todo:
+        name = todo.pop()
+        if name in comps and name not in inside:
+            inside.add(name)
+            todo += list(calls[name])
+    copy = re.compile(r"=\s*\(?(\w+\[%d(?:,\d+)*\])[^=]*?\b(?:copy|copy-start)\("
+                      % m)
+    return [(name, hit.group(1)) for name in sorted(inside)
+            for line in comps[name] if (hit := copy.search(line))]
+
+
+@X64
+def test_expand_pass_updates_the_arena_in_place(one_chip, x64):
+    """``_process_ins`` at 4,096 ΔNodes: no arena plane is copied inside
+    its loop or the loop's branches.  A pass whose per-item branches each
+    return the whole tree copies every plane they carry on every slot
+    (34 such copies, 11.5 MB an iteration, at this size)."""
+    m = 4096
+    cfg = DT.TreeConfig(height=7, max_dnodes=m,
+                        payload_bits=32 if x64 else 0)
+    with jax.enable_x64(x64):
+        tree = [jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+                for x in DT.empty_layout(cfg)]
+        dn = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+        text = jax.jit(lambda t, d: DT._process_ins(cfg, t, d)).lower(
+            DT.DeltaTree(*tree), dn).compile().as_text()
+    assert "while" in text
+    assert _loop_copies(text, m) == []
 
 
 @pytest.mark.parametrize("env_set", [True, False], ids=["env", "repo"])

@@ -16,7 +16,7 @@ B. Pallas phase: the same ops on an arena at the scan kernel's VMEM
    ``tpu_custom_call``; results, per-query hops and the updated arena must
    equal a scalar-engine index's bit for bit.
 C. Serve: ``ServeScheduler`` on the granite smoke preset the serve
-   benchmarks use, 8 requests x 16 new tokens through the compiled paged
+   tests use, 8 requests x 16 new tokens through the compiled paged
    decode-attention kernel; every request must match a dense decode.
 F. ``--chips 4`` only: the phase-A ops on a 4-shard DeltaForest, one
    shard per chip (each shard's arena inside the Pallas budget), against
@@ -279,7 +279,7 @@ def phase_serve(seed: int) -> None:
     cfg = get_smoke_config("granite_8b")
     m = api(cfg)
     params = m.init_params(jax.random.PRNGKey(seed))
-    # benchmarks/serve_trace.py's pager
+    # a deferred-maintenance pager, as a continuous-batching server runs
     pc = PagerConfig(num_pages=1024, page_size=4, max_seqs=256,
                      max_blocks=64, tree_height=5, maintenance="deferred",
                      maint_high_water=8)

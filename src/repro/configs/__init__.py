@@ -1,8 +1,9 @@
 """Assigned architecture configs (--arch <id>).
 
 Each module defines CONFIG (the exact assigned full-scale config) and SMOKE
-(a reduced same-family config for CPU smoke tests).  Full configs are only
-ever lowered via the dry-run (ShapeDtypeStruct, no allocation).
+(a reduced same-family config for CPU smoke tests).  Full configs are never
+allocated by the tests; their shapes are checked through
+`repro.models.registry.input_specs` (ShapeDtypeStruct, no allocation).
 """
 
 from __future__ import annotations

@@ -9,7 +9,7 @@
   rebuilds the whole layout (the paper's motivating weakness).
 - SortedArray — binary search; batched updates = sort-merge rebuild.
 - HashTable   — open-addressing linear probing (not in the paper; extra
-  locality point of reference, labeled as such in benchmarks).
+  locality point of reference).
 
 Every structure exposes:
   build(values) -> state            (host)

@@ -102,8 +102,9 @@ class TreeConfig:
                   "deferred" (maintenance only on ``flush``), or
                   "budgeted:K" (at most K ΔNode repairs per batch); see
                   ``repro.maintenance``.
-    q_tile:       lockstep kernel query tile; 0 = auto (the
-                  ``REPRO_PALLAS_QTILE`` env override, else 256).
+    q_tile:       lockstep kernel query tile; 0 = auto
+                  (`kernels.ops.default_q_tile`: the
+                  ``REPRO_PALLAS_QTILE`` env override, else its table).
     collect_stats: observability flag (``repro.obs``): stats-capable
                   reads (search/lookup, forest reads) return a trailing
                   ``ReadStats`` counter pytree.  Static, so the disabled
@@ -128,7 +129,7 @@ class TreeConfig:
     parallel_updates: bool = True   # vectorized non-conflicting fast path
     engine: str = "scalar"    # read-path SearchEngine (core.engine registry)
     maintenance: str = "eager"  # scheduler policy (repro.maintenance)
-    q_tile: int = 0           # lockstep kernel tile (0 = env/autotune)
+    q_tile: int = 0           # lockstep kernel tile (0 = env/table)
     collect_stats: bool = False  # reads return ReadStats (repro.obs)
     collect_transfers: bool = False  # + measured TransferStats sub-gate
     walk_fused: bool = True   # fused single-launch walk driver
@@ -290,7 +291,7 @@ def _descend(cfg: TreeConfig, t: DeltaTree, q, dn0, b0):
 
     Returns (dn, b, hops): ``hops`` counts ΔNode boundary crossings — in the
     relaxed-CO model each hop is O(1) block transfers (Lemma 2.1), so hops is
-    the exact transfer-count statistic reported by the benchmarks.
+    the exact transfer-count statistic (`core.transfers`).
     """
     pos = _pos(cfg)
     bottom0 = cfg.bottom0

@@ -147,22 +147,20 @@ def available_engines() -> list[str]:
 
 
 # --------------------------------------------------------------------------
-# "auto" engine resolution — pick the bench-table winner per execution mode
+# "auto" engine resolution — one table entry per execution mode
 # --------------------------------------------------------------------------
 
-# Which engine won the committed engine_compare read-path rows, keyed by
-# (backend, compiled).  compiled=True = real XLA/Pallas compilation
-# (REPRO_PALLAS_INTERPRET=0 — on CPU the fused walk runs through the
-# XLA-compiled `ref_delta_walk_fused`); compiled=False = the Pallas
-# interpreter, where lockstep pays the interpreter tax and loses.  Baked
-# from the compiled BENCH_*.json at the repo root (run_compiled.sh +
-# benchmarks/run.py --compiled): forest lockstep beats scalar outright
-# (2-2.6x on the mixed read suite); single-arena deltatree is parity-
-# within-noise on compiled CPU (fused single-launch vs XLA's vmap'd
-# scalar descent) and lockstep takes the tie — it is the paper's read
-# path, runs ONE launch per dispatch (`walk_launches=1` vs the scalar
-# engine's fat gather program), and is the form that lowers to the
-# Pallas kernel on TPU.  Re-bake when new hardware rows land.
+# The engine ``"auto"`` picks, keyed by (backend, compiled).  compiled=True
+# is real XLA/Pallas compilation (REPRO_PALLAS_INTERPRET=0, and the
+# default on a TPU — on the CPU the fused walk then runs the XLA-compiled
+# `ref_delta_walk_fused`); compiled=False is the Pallas interpreter, where
+# lockstep pays the interpreter tax and a miss falls back to "scalar".
+# The entries were chosen from CPU timings.  Lockstep is the paper's read
+# path, runs one walk or scan kernel per read dispatch (two for
+# successor's chase; tests/test_read_dispatch.py), and is the form that
+# lowers to the Pallas kernel on TPU; every benchmark cell on the chip
+# resolves ``auto`` to it (PERF_LEDGER.jsonl).  No chip cell has yet
+# compared it with "scalar": the table is a named debt (ROADMAP.md 3c).
 AUTO_TABLE: dict[tuple[str, bool], str] = {
     ("deltatree", True): "lockstep",
     ("forest", True): "lockstep",

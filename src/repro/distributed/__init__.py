@@ -17,6 +17,7 @@ import warnings
 
 from repro.distributed import router, splits
 from repro.distributed.forest import Forest, ForestConfig
+from repro.distributed.router import make_forest_mesh
 
 __all__ = [
     "Forest",
@@ -28,6 +29,7 @@ __all__ = [
     "live_items",
     "live_keys",
     "lookup_batch",
+    "make_forest_mesh",
     "router",
     "search_batch",
     "shard_tree",

@@ -3,10 +3,10 @@
 The forest is the scale-out layer over `repro.core` (DESIGN.md §4): each
 shard is a full ΔTree arena owning a contiguous key range, stacked into one
 pytree with a leading (S,) axis and driven through ``jax.shard_map`` over
-the "shards" mesh (`repro.launch.mesh.make_forest_mesh`).  The shards are
-laid out in host memory and placed from there, each device receiving
-only its own shards' rows.  The public API
-is a drop-in superset of `repro.core`:
+the "shards" mesh (`repro.distributed.router.make_forest_mesh`).  The
+shards are laid out in host memory and placed from there, each device
+receiving only its own shards' rows.  The public API is a drop-in
+superset of `repro.core`:
 
     ForestConfig, Forest, empty, bulk_build,
     search_batch, lookup_batch, update_batch, successor_jit,
@@ -85,7 +85,7 @@ class ForestConfig:
     #                         (when it provides one); False pins reads to
     #                         the dense per-shard vmap dispatch — the
     #                         reference path the fused-conformance suite
-    #                         and benchmarks compare against
+    #                         compares against
 
 
 class Forest(NamedTuple):

@@ -35,10 +35,10 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import layout
-from repro.parallel import make_forest_mesh
 
 
 class Routing(NamedTuple):
@@ -103,6 +103,22 @@ def gather_batch(r: Routing, dense: jax.Array) -> jax.Array:
     picked = dense[r.sid_sorted, r.local]
     out = jnp.zeros((k,) + dense.shape[2:], dense.dtype)
     return out.at[r.order].set(picked)
+
+
+def make_forest_mesh(num_shards: int):
+    """1-D "shards" mesh for the DeltaForest.
+
+    Uses the largest divisor of ``num_shards`` that fits the available
+    device count, so the stacked (S, ...) forest arenas always split
+    evenly; leftover shards-per-device are vmapped inside the shard_map
+    body.  On a single device this degenerates to a size-1 mesh (pure
+    vmap), which keeps the forest runnable in unit tests without
+    --xla_force_host_platform_device_count.
+    """
+    nd = jax.device_count()
+    use = max(d for d in range(1, min(nd, num_shards) + 1)
+              if num_shards % d == 0)
+    return jax.sharding.Mesh(np.asarray(jax.devices()[:use]), ("shards",))
 
 
 @functools.lru_cache(maxsize=None)

@@ -71,9 +71,8 @@ def _resolve_interpret(interpret: bool | None) -> bool:
 def default_fused() -> bool:
     """Walk-driver default: the fused single-launch walk everywhere
     (bit-identical to the per-round driver; the parity suite pins it).
-    ``REPRO_PALLAS_FUSED=0`` flips the process to the per-round driver —
-    the A/B knob `benchmarks/engine_compare.py` and kernel debugging
-    use."""
+    ``REPRO_PALLAS_FUSED=0`` flips the process to the per-round driver,
+    for kernel debugging."""
     env = os.environ.get("REPRO_PALLAS_FUSED", "").strip()
     if env:
         return env.lower() not in ("0", "false", "no")
@@ -125,12 +124,24 @@ def _check_q_tile(tile: int, origin: str, lane_aligned: bool) -> int:
     return tile
 
 
+# Query tiles by (height, compiled, bits): `default_q_tile`'s table.  The
+# tiles were chosen by timing walks on the CPU; on the chip they only set
+# the batch padding of the XLA mirrors (which are tile-free otherwise),
+# and no cell has yet measured them on the Pallas grid.  Keys absent here
+# (every interpret-mode key among them) fall through to 256.
+Q_TILES: dict[tuple[int, bool, int], int] = {
+    (5, True, 32): 1024,
+    (7, True, 32): 256,
+    (9, True, 32): 512,
+    (7, True, 64): 512,
+}
+
+
 def default_q_tile(height: int | None = None,
                    payload_bits: int = 0) -> int:
     """Lockstep kernel query tile: ``REPRO_PALLAS_QTILE`` env override,
-    else the autotuned height→tile table (`kernels.autotune` — the
-    ``REPRO_PALLAS_AUTOTUNE`` cache file over the committed baked
-    winners), else 256 (two VREG lanes' worth)."""
+    else the `Q_TILES` entry for this height and execution mode, else 256
+    (two VREG lanes' worth)."""
     env = os.environ.get("REPRO_PALLAS_QTILE", "").strip()
     if env:
         try:
@@ -142,12 +153,8 @@ def default_q_tile(height: int | None = None,
         return _check_q_tile(tile, f"REPRO_PALLAS_QTILE={env!r}",
                              lane_aligned=True)
     if height is not None:
-        from repro.kernels.autotune import best_q_tile
-
-        tile = best_q_tile(height, compiled=not default_interpret(),
-                           bits=64 if payload_bits else 32)
-        if tile is not None:
-            return _check_q_tile(tile, "autotune table", lane_aligned=False)
+        key = (height, not default_interpret(), 64 if payload_bits else 32)
+        return Q_TILES.get(key, 256)
     return 256
 
 
@@ -268,8 +275,8 @@ def delta_walk(value: jax.Array, child: jax.Array, root: jax.Array,
     (env/backend changes are honored between calls); callers that trace
     this under an outer jit bake the mode at their own trace time.
     ``q_tile=None`` resolves via `default_q_tile` the same way
-    (``REPRO_PALLAS_QTILE`` env override, else the autotuned
-    height→tile table, else 256).  ``fused=None`` resolves via
+    (``REPRO_PALLAS_QTILE`` env override, else the `Q_TILES` table,
+    else 256).  ``fused=None`` resolves via
     `default_fused` (``REPRO_PALLAS_FUSED`` override, else the fused
     single-launch driver); ``max_rounds=None`` derives the round cap
     from the arena geometry (`walk_round_cap`).
@@ -305,7 +312,7 @@ def delta_walk_fused(value: jax.Array, child: jax.Array, root: jax.Array,
                      interpret: bool | None = None):
     """`delta_walk` pinned to the fused single-launch driver (ignores the
     ``REPRO_PALLAS_FUSED`` process default) — the explicit entry point for
-    parity tests and the autotuner."""
+    parity tests."""
     return delta_walk(value, child, root, queries, height=height,
                       q_tile=q_tile, max_rounds=max_rounds,
                       interpret=interpret, fused=True)

@@ -83,7 +83,7 @@ class ModelConfig:
     remat_policy: str = "nothing"  # nothing | dots (checkpoint policy)
     decode_uniform_length: bool = False  # batch-uniform decode: DUS cache update
     logits_softcap: float = 0.0
-    unroll: bool = False           # python-unroll layer scans (dry-run probes)
+    unroll: bool = False           # python-unroll layer scans (cost probes)
     ssd_vectorized: bool = False   # vectorize SSD chunks (probes: exact flops)
 
     @property

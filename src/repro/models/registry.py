@@ -1,4 +1,4 @@
-"""Model facade + assignment shapes + input_specs (dry-run contract).
+"""Model facade + assignment shapes + input_specs (ShapeDtypeStruct inputs).
 
 `api(cfg)` returns a uniform interface regardless of family:
     init_params(key) / loss_fn(params, batch) / prefill(params, ...) /
@@ -6,8 +6,7 @@
 
 `input_specs(cfg, shape_name)` returns ShapeDtypeStruct stand-ins for every
 input of the step that shape lowers (train_step / prefill_step /
-serve_step), with no device allocation — the multi-pod dry-run compiles
-against exactly these.
+serve_step), with no device allocation.
 """
 
 from __future__ import annotations

@@ -1,9 +1,8 @@
 """lax.scan with an unroll escape hatch.
 
 cost_analysis() counts a while-loop body ONCE regardless of trip count, so
-the dry-run's shallow depth probes (launch/dryrun.py) set cfg.unroll=True to
-get exact FLOP/byte counts; production configs keep lax.scan for compact
-HLO."""
+shallow depth probes set cfg.unroll=True to get exact FLOP/byte counts;
+production configs keep lax.scan for compact HLO."""
 
 from __future__ import annotations
 

@@ -3,9 +3,9 @@
 The layer stack is grouped into `period = cfg.pattern_period` slots (dense:
 1; jamba: 8 — 7 SSD + 1 attn with alternating MoE).  Params for slot j are
 stacked over the `reps = L // period` repetitions and applied with
-`lax.scan`, keeping the HLO compact enough to compile 80-layer models on a
-512-device dry-run mesh.  `cfg.remat` wraps each scan body in
-jax.checkpoint (policy: nothing saveable — §Perf iterates on this).
+`lax.scan`, keeping the HLO compact enough to compile 80-layer models.
+`cfg.remat` wraps each scan body in jax.checkpoint (policy: nothing
+saveable — §Perf iterates on this).
 
 Three entry points per the assignment's shapes:
   forward_train   (train_4k)      tokens -> logits

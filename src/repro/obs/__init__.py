@@ -37,14 +37,9 @@ measurements:
 - **Metrics export** (`obs.export`): stats pytrees and running totals →
   one named snapshot → Prometheus text exposition / JSON
   (``ServeScheduler.metrics()`` is the live producer).
-- **Benchmark reports** (`obs.report`): a stdlib-only CLI that renders
-  consolidated ``BENCH_*.json`` files as per-suite tables and diffs them::
-
-      python -m repro.obs.report BENCH_NEW.json --diff BENCH_OLD.json
-      python -m repro.obs.report BENCH_*.json --history
 """
 
-from repro.obs import export, report, ring, stats, trace, transfers
+from repro.obs import export, ring, stats, trace, transfers
 from repro.obs.stats import (
     MaintenanceStats,
     ReadStats,
@@ -67,7 +62,6 @@ __all__ = [
     "TransferStats",
     "calls",
     "export",
-    "report",
     "ring",
     "stats",
     "trace",

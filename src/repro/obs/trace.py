@@ -12,8 +12,7 @@
   span entered while JAX is tracing (under ``jit``/``vmap``) records
   nothing: there it would time the trace, not the run.
 - ``capture(logdir)`` / ``trace_run`` wrap a region in
-  ``jax.profiler.start_trace`` / ``stop_trace`` (also reachable as
-  ``benchmarks/run.py --trace-dir``).
+  ``jax.profiler.start_trace`` / ``stop_trace``.
 
 The totals table is guarded by one module lock: the serve layer's
 maintenance worker may close spans from its own thread.

@@ -1,38 +1,15 @@
-"""Distribution layer: logical-axis sharding rules, param shardings,
-sharded decode attention (split-K), collective helpers.
+"""Logical-axis sharding rules for the serve path's model code (`ax`).
 
 ``__all__`` is the single source of truth for this package's surface
-(tests/test_exports.py asserts every name imports) — it re-exports the
-actual API of ``ax`` / ``shardings`` / ``decode_attn`` instead of the
-mesh helpers alone.  The DeltaForest (repro/distributed) rides this layer
-too: its 1-D "shards" mesh is re-exported here so mesh plumbing has one
-import home.
+(tests/test_exports.py asserts every name imports).  The DeltaForest's
+"shards" mesh lives with its router (`repro.distributed.router`).
 """
 
-from repro.launch.mesh import make_forest_mesh, make_host_mesh
 from repro.parallel.ax import DEFAULT_RULES, constrain, logical_rules, spec_for
-from repro.parallel.decode_attn import split_k_decode_attention
-from repro.parallel.shardings import (
-    batch_axes,
-    batch_spec,
-    cache_specs,
-    opt_specs,
-    param_specs,
-    to_named,
-)
 
 __all__ = [
     "DEFAULT_RULES",
-    "batch_axes",
-    "batch_spec",
-    "cache_specs",
     "constrain",
     "logical_rules",
-    "make_forest_mesh",
-    "make_host_mesh",
-    "opt_specs",
-    "param_specs",
     "spec_for",
-    "split_k_decode_attention",
-    "to_named",
 ]

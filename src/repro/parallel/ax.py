@@ -1,9 +1,9 @@
 """Logical activation-axis sharding (MaxText-style logical rules).
 
 Models annotate activations with *logical* axis names; a thread-local rule
-set (installed by the launcher / dry-run under a mesh) maps them to mesh
-axes.  Outside a rules context every annotation is a no-op, so model code
-runs unchanged on a single CPU device.
+set (installed with `logical_rules` under a mesh) maps them to mesh axes.
+Outside a rules context every annotation is a no-op, so model code runs
+unchanged on a single CPU device.
 """
 
 from __future__ import annotations

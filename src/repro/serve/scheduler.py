@@ -80,7 +80,7 @@ class ServeScheduler:
     """Continuous-batching scheduler over the paged-KV DeltaPager.
 
     Compat surface (what the legacy lockstep engine exposed and the
-    tests/benchmarks consume): ``submit() -> sid``, ``step() -> {sid:
+    tests consume): ``submit() -> sid``, ``step() -> {sid:
     tok}``, ``active[sid].out``, ``pager``, ``obs``.  New surface:
     ``cancel``, ``probe``, ``queue``, ``worker``, ``run_trace``.
     """

@@ -1,6 +1,6 @@
 """Serving engines over the ΔTree-paged KV cache.
 
-``ServeEngine`` — the public name tests/benchmarks construct — is now a
+``ServeEngine`` — the public name tests and examples construct — is now a
 thin compat shim over the continuous-batching scheduler
 (`repro.serve.scheduler.ServeScheduler`): same constructor signature
 (``max_batch`` maps to the scheduler's live-lane count), same

@@ -1,8 +1,8 @@
 """Export hygiene: ``__all__`` is the single source of truth per package.
 
-Every name a package's ``__all__`` declares must resolve (catching the
-historical drift where ``repro.parallel`` advertised shardings/decode-attn
-helpers its ``__init__`` never exported), and the deprecation shims in
+Every name a package's ``__all__`` declares must resolve (catching drift
+between a package's declared surface and what its ``__init__`` imports),
+and the deprecation shims in
 ``repro.core`` / ``repro.distributed`` must keep old imports working while
 warning.
 """
@@ -16,16 +16,13 @@ PACKAGES = [
     "repro.api",
     "repro.checkpoint",
     "repro.core",
-    "repro.data",
     "repro.distributed",
     "repro.kernels",
     "repro.maintenance",
     "repro.obs",
-    "repro.optim",
     "repro.parallel",
     "repro.serve",
     "repro.serving",
-    "repro.train",
 ]
 
 

@@ -1,9 +1,7 @@
 """Fused single-launch walk driver: bit-parity vs the per-round driver
 and the scalar engine, sentinel/multi-root contracts, the derived round
-cap, the q_tile autotune table, and compiled-mode (REPRO_PALLAS_INTERPRET=0)
-subprocess legs including ``engine="auto"`` resolution."""
-
-import json
+cap, and compiled-mode (REPRO_PALLAS_INTERPRET=0) subprocess legs
+including ``engine="auto"`` resolution."""
 
 import numpy as np
 import jax.numpy as jnp
@@ -172,38 +170,6 @@ def test_make_index_auto_engine_interpret():
     np.testing.assert_array_equal(np.asarray(found), [True, False])
 
 
-def test_autotune_cache_roundtrip(tmp_path, monkeypatch):
-    """save_cache/load_cache round-trip through REPRO_PALLAS_AUTOTUNE, the
-    cache wins over BAKED in best_q_tile, default_q_tile consumes it, and
-    a corrupt cache degrades to the baked table instead of failing."""
-    from repro.kernels import autotune
-    from repro.kernels.ops import default_q_tile
-
-    path = tmp_path / "autotune.json"
-    monkeypatch.setenv(autotune.ENV_CACHE, str(path))
-    monkeypatch.delenv("REPRO_PALLAS_QTILE", raising=False)
-    assert autotune.load_cache() == {}
-
-    key = autotune._key(7, compiled=False, bits=32)
-    autotune.save_cache({key: 512})
-    assert autotune.load_cache() == {key: 512}
-    assert autotune.best_q_tile(7, compiled=False) == 512
-    # default_q_tile consults the cache for the current (interpret) mode
-    assert default_q_tile(7) == 512
-    # merge semantics: a second save keeps existing keys
-    autotune.save_cache({autotune._key(5, compiled=False, bits=32): 128})
-    assert autotune.load_cache()[key] == 512
-
-    path.write_text("not json{")
-    assert autotune.load_cache() == {}
-    assert (autotune.best_q_tile(7, compiled=True)
-            == autotune.BAKED.get((7, True, 32)))
-
-    monkeypatch.delenv(autotune.ENV_CACHE)
-    assert autotune.cache_path() is None
-    assert autotune.save_cache({key: 64}) is None  # no cache = no-op
-
-
 def test_fused_walk_map_mode_int64_subprocess():
     """Map-mode (int64 packed rows) fused walk parity — x64 subprocess:
     fused vs per-round vs the legacy search contract on packed queries."""
@@ -270,31 +236,3 @@ assert bool(np.asarray(found)[0])
 print("COMPILED_OK")
 """)
     assert "COMPILED_OK" in out
-
-
-def test_autotune_smoke_cli_subprocess(tmp_path):
-    """The autotune CLI at smoke scale: emits winner rows and writes the
-    REPRO_PALLAS_AUTOTUNE cache with mode-stamped keys."""
-    import os
-    import subprocess
-    import sys
-    from pathlib import Path
-
-    repo = Path(__file__).resolve().parent.parent
-    cache = tmp_path / "tune.json"
-    env = dict(os.environ)
-    env["PYTHONPATH"] = str(repo / "src") + os.pathsep + str(repo)
-    env.pop("XLA_FLAGS", None)
-    env["REPRO_PALLAS_AUTOTUNE"] = str(cache)
-    out = subprocess.run(
-        [sys.executable, str(repo / "benchmarks" / "autotune_qtile.py"),
-         "--smoke"],
-        env=env, capture_output=True, text=True, timeout=900)
-    assert out.returncode == 0, out.stderr
-    rows = [json.loads(ln) for ln in out.stdout.splitlines()
-            if ln.strip().startswith("{")]
-    winners = [r for r in rows if r.get("winner")]
-    assert winners and all(r["bench"] == "autotune_qtile" for r in rows)
-    table = json.loads(cache.read_text())
-    assert all("/" in k and isinstance(v, int) for k, v in table.items())
-    assert any(k.startswith("5/") for k in table)

@@ -331,10 +331,9 @@ def test_q_tile_config_and_env(monkeypatch):
     f_b, h_b = DT.search_jit(cfg_def, t, q)
     np.testing.assert_array_equal(np.asarray(f_a), np.asarray(f_b))
     np.testing.assert_array_equal(np.asarray(h_a), np.asarray(h_b))
-    from benchmarks.common import resolved_q_tile
     ix = make_index("deltatree", initial=initial, engine="lockstep",
                     height=4, max_dnodes=256, q_tile=128)
-    assert resolved_q_tile(ix) == 128
+    assert OPS._resolve_q_tile(ix.cfg.q_tile or None, 4) == 128
 
 
 # the hypothesis property legs live in tests/test_maintenance_property.py

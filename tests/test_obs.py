@@ -1,5 +1,4 @@
-"""repro.obs — counter pytrees, spans and per-call counters, report CLI
-(DESIGN.md §9).
+"""repro.obs — counter pytrees, spans and per-call counters (DESIGN.md §9).
 
 The two contracts everything else leans on:
 
@@ -26,7 +25,7 @@ from repro.core import layout
 from repro.core.deltatree import TreeConfig
 from repro.distributed import forest as D
 from repro.distributed.forest import ForestConfig
-from repro.obs import report, trace
+from repro.obs import trace
 from repro.obs.stats import (
     HOP_BINS,
     LATENCY_RESERVOIR,
@@ -606,95 +605,6 @@ def test_export_snapshot_program_totals():
     assert "# TYPE repro_index_search_calls gauge" in prom
     assert "repro_spans_index_build_total_s" in prom
     assert "index" not in export.snapshot(pager={"searches": 1})
-
-
-# ---------------------------------------------------------------- report ---
-
-
-def _bench(ops, ts="t0", extra=None):
-    rows = []
-    for backend, v in ops.items():
-        r = {"suite": "fig11", "bench": "b", "backend": backend,
-             "engine": "scalar", "update_pct": 10, "batch": 256,
-             "seed": 0, "ops_per_s": v}
-        r.update(extra or {})
-        rows.append(r)
-    return {"timestamp": ts, "args": {"smoke": True}, "rows": rows}
-
-
-def test_report_render_and_diff(tmp_path, capsys):
-    new = _bench({"deltatree": 850.0, "sorted_array": 3000.0}, "t1",
-                 extra={"dispatch": None})  # newer schema: extra ID key
-    base = _bench({"deltatree": 1000.0, "sorted_array": 1000.0}, "t0")
-    pn, pb = tmp_path / "new.json", tmp_path / "base.json"
-    pn.write_text(json.dumps(new))
-    pb.write_text(json.dumps(base))
-
-    rc = report.main([str(pn)])
-    assert rc == 0
-    out = capsys.readouterr().out
-    assert "## fig11 (2 rows)" in out and "deltatree" in out
-
-    out_md = tmp_path / "report.md"
-    rc = report.main([str(pn), "--diff", str(pb), "--out", str(out_md)])
-    assert rc == 0   # regressions flagged but not failing by default
-    text = out_md.read_text()
-    assert "2 matched" in text
-    assert "0.850x  << REGRESSION" in text   # deltatree slipped to 0.85x
-    assert "3.000x" in text                  # sorted_array sped up
-
-    rc = report.main([str(pn), "--diff", str(pb), "--threshold", "0.95",
-                      "--fail-on-regression"])
-    assert rc == 1
-    rc = report.main([str(pn), "--diff", str(pb), "--threshold", "0.5",
-                      "--fail-on-regression"])
-    assert rc == 0
-
-
-def test_report_history(tmp_path, capsys):
-    """--history renders one column per BENCH file, rows matched by
-    identity label, cells the primary metric (missing files -> '-')."""
-    b0 = _bench({"deltatree": 1000.0, "sorted_array": 900.0}, "t0")
-    b1 = _bench({"deltatree": 1500.0}, "t1")
-    p0, p1 = tmp_path / "b0.json", tmp_path / "b1.json"
-    p0.write_text(json.dumps(b0))
-    p1.write_text(json.dumps(b1))
-
-    lines = report.history([b1, b0])          # order-insensitive (sorted)
-    text = "\n".join(lines)
-    assert "# history across 2 files" in text
-    assert "t0" in text and "t1" in text
-    row = next(ln for ln in lines if "deltatree" in ln)
-    assert "1000" in row and "1500" in row
-    row = next(ln for ln in lines if "sorted_array" in ln)
-    assert "900" in row and row.rstrip().endswith("-")  # absent at t1
-
-    # duplicate timestamps still get one column each
-    lines = report.history([b0, dict(b0)])
-    assert any("t0'" in ln for ln in lines)
-
-    out_md = tmp_path / "hist.md"
-    rc = report.main([str(p0), str(p1), "--history", "--out", str(out_md)])
-    assert rc == 0
-    assert "# history across 2 files" in out_md.read_text()
-    capsys.readouterr()
-
-    with pytest.raises(SystemExit):           # many files need --history
-        report.main([str(p0), str(p1)])
-    capsys.readouterr()
-
-
-def test_report_tolerant_matching(tmp_path):
-    """A key missing on either side is a wildcard; ambiguity unmatches."""
-    new = _bench({"deltatree": 500.0}, extra={"flush_every": 0})
-    base = _bench({"deltatree": 1000.0})
-    lines, regs = report.diff(new, base)
-    assert len(regs) == 1
-    # two identical base rows for the same identity -> ambiguous -> skip
-    base2 = {"timestamp": "t", "args": {},
-             "rows": base["rows"] + [dict(base["rows"][0])]}
-    lines, regs = report.diff(new, base2)
-    assert regs == [] and any("1 unmatched" in ln for ln in lines)
 
 
 # ---------------------------------------------------------------- export ---

@@ -43,14 +43,22 @@ def _queries():
 # ------------------------------------------------- measured == model ---
 
 
+@pytest.mark.parametrize("compiled", [False, True],
+                         ids=["interpret", "compiled"])
 @pytest.mark.parametrize("height,n", [(4, 300), (5, 900), (7, 2500)])
-def test_measured_equals_model_exactly(height, n):
+def test_measured_equals_model_exactly(height, n, compiled, monkeypatch):
     """On a quiescent (bulk-built) tree the measured distinct-block
     transfers per search equal `count_block_transfers` exactly — ratio
-    1.0, not approximately — for every supported block size."""
+    1.0, not approximately — for every supported block size.  The
+    compiled leg (``REPRO_PALLAS_INTERPRET=0``) also reads the counter a
+    lockstep search carries out of its compiled read program."""
+    if compiled:
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
     rng = np.random.default_rng(height)
     keys = np.unique(rng.integers(1, 50_000, size=n).astype(np.int64))
-    cfg = TreeConfig(height=height, max_dnodes=4096, buf_cap=8,
+    # the compiled leg's buf_cap is a cfg no interpret-mode trace has used
+    cfg = TreeConfig(height=height, max_dnodes=4096,
+                     buf_cap=7 if compiled else 8,
                      collect_stats=True, collect_transfers=True)
     t = DT.bulk_build(cfg, keys)
     q = rng.integers(1, 50_000, size=256).astype(np.int64)  # hits + misses
@@ -59,6 +67,17 @@ def test_measured_equals_model_exactly(height, n):
         assert cm[b]["measured"] == pytest.approx(cm[b]["model"], abs=0), \
             (b, cm[b])
         assert cm[b]["ratio"] == 1.0
+    if compiled:
+        from repro.api import make_index
+
+        ix = make_index("deltatree", initial=keys, engine="lockstep",
+                        **{f: getattr(cfg, f) for f in (
+                            "height", "max_dnodes", "buf_cap",
+                            "collect_stats", "collect_transfers")})
+        *_, stats = ix.search(jnp.asarray(q, jnp.int32))
+        blocks = np.asarray(stats.transfers.blocks)
+        for i, b in enumerate(TRANSFER_BLOCK_SIZES):
+            assert blocks[i] / q.size == cm[b]["model"], (b, cm[b])
 
 
 def test_transfer_stats_field_consistency():
